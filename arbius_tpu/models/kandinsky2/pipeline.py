@@ -263,10 +263,11 @@ class Kandinsky2Pipeline:
         else:
             # GSPMD batch/output specs; params inherit their boot-time
             # rule-table placement (docs/multichip.md)
+            from arbius_tpu.ops.flash import on_mesh
             from arbius_tpu.parallel import meshsolve
 
             spec, _ = meshsolve.batch_specs(self.mesh, batch)
-            fn = jax.jit(run,
+            fn = jax.jit(on_mesh(run, self.mesh),
                          in_shardings=(None, spec(2), spec(1), spec(1),
                                        spec(1)),
                          out_shardings=spec(4))

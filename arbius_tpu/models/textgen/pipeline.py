@@ -151,7 +151,7 @@ class TextGenPipeline:
 
     def init_params(self, seed: int = 0, dtype=None, **_unused) -> dict:
         """Deterministic parameter init as ONE jitted program (same
-        remote-TPU dispatch rationale as SD15Pipeline.init_params)."""
+        dispatch rationale as SD15Pipeline.init_params)."""
         from arbius_tpu.utils import with_cast
 
         return jax.jit(with_cast(self._init_fn(), dtype))(

@@ -528,7 +528,8 @@ class MiningConfig:
     # device-mesh layout for the solve path (docs/multichip.md), e.g.
     # {"dp": 4, "tp": 2} or {"dp": 2, "sp": 2, "tp": 2}; null/absent =
     # the exact single-device path. dp shards the bucket batch
-    # (bit-identical to mesh-off — test-pinned); tp/sp layouts are each
+    # (bit-identical to mesh-off on CPU — test-pinned; on TPU hardware a
+    # dp size moved the CIDs, docs/multichip.md); tp/sp layouts are each
     # their OWN determinism class, pinned per (family, layout) by the
     # graphlint goldens, so a fleet mines one layout per model — the
     # same fleet-wide rule as canonical_batch. Axis names/values are
@@ -546,7 +547,10 @@ class MiningConfig:
     # bound on expretry's base**attempt backoff curve (seconds); None
     # preserves the reference's uncapped curve (utils.ts:21-39)
     retry_max_delay: float | None = 30.0
-    compile_cache_dir: str | None = ".jax_cache"  # persistent XLA cache
+    # persistent XLA cache on/off; WHERE it lives is decided by
+    # utils.enable_compile_cache alone: JAX_COMPILATION_CACHE_DIR if
+    # exported, else <checkout>/.jax_cache (docs/compile-cache.md)
+    compile_cache: bool = True
     store_dir: str | None = None     # content store root (None: don't pin)
     rpc_port: int | None = None      # control RPC + explorer + /ipfs gateway
     ipfs: IpfsConfig = IpfsConfig()  # pinning strategy

@@ -255,10 +255,10 @@ class MinerNode:
 
     # -- boot (start.ts:11-52 + index.ts:971-1020) -----------------------
     def boot(self, *, skip_self_test: bool = False) -> None:
-        if self.config.compile_cache_dir:
+        if self.config.compile_cache:
             from arbius_tpu.utils import enable_compile_cache
 
-            enable_compile_cache(self.config.compile_cache_dir)
+            enable_compile_cache()
         # solve mesh (docs/multichip.md): built and VALIDATED here — a
         # shape that doesn't fit jax.device_count() must die at boot
         # with one clear sentence, not as a deep XLA reshape failure

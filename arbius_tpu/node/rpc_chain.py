@@ -338,7 +338,7 @@ class RpcChain:
 
     # -- transactions ------------------------------------------------------
     def _send(self, fn: str, values: list) -> str:
-        # span names are snake_case (LocalChain parity — one taxonomy for
+        # span names are snake_case (LocalChain parity — one naming for
         # local and production nodes, docs/observability.md)
         op = _re.sub(r"(?<=[a-z0-9])([A-Z])", r"_\1", fn).lower()
         with span("chain." + op):

@@ -19,16 +19,27 @@ Kernel layout (pallas_guide.md patterns):
 """
 from __future__ import annotations
 
+import contextvars
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 BLOCK_Q = 128
 BLOCK_K = 128
 NEG_INF = -1e30
+# Mosaic's default scoped-VMEM budget on a v5e. Each program holds the
+# WHOLE K and V sequence as one block, double-buffered by Pallas, so the
+# budget is stated per call from the block sizes: the VAE mid-block
+# (one head, D=512, S=4096) needs 16 MiB for K/V alone and libtpu 0.0.34
+# refuses it at the default once the batch exceeds 1 (RESOURCE_EXHAUSTED
+# in vmem); S=9216 needs 36 MiB. Stating the limit moves no bits.
+_VMEM_DEFAULT = 16 * 1024 * 1024
+_VMEM_HEADROOM = 8 * 1024 * 1024   # Q/O blocks + the kernel's f32 copies
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, *, kv_len: int, scale: float):
@@ -60,9 +71,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, kv_len: int, scale: float):
     o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
 
 
+def _round_up(n: int, mult: int) -> int:
+    return -(-n // mult) * mult
+
+
 def _pad_to(x, axis, mult):
     size = x.shape[axis]
-    pad = (-size) % mult
+    pad = _round_up(size, mult) - size
     if pad == 0:
         return x
     widths = [(0, 0)] * x.ndim
@@ -104,9 +119,58 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, BLOCK_Q, d_p), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq_p, d_p), q.dtype),
+        # K and V blocks, two buffers each, lane-padded to 128 in VMEM
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=max(
+            _VMEM_DEFAULT,
+            2 * 2 * kv_p * _round_up(d_p, 128) * kf.dtype.itemsize
+            + _VMEM_HEADROOM)),
         interpret=interpret,
     )(qf, kf, vf)
     return out[:, :sq, :d].reshape(b, h, sq, d)
+
+
+# The mesh of the GSPMD program being traced, if any (see on_mesh).
+_KERNEL_MESH: contextvars.ContextVar = contextvars.ContextVar(
+    "arbius_kernel_mesh", default=None)
+
+
+def on_mesh(fn, mesh):
+    """Wrap a solve fn that is about to be jitted with GSPMD shardings
+    over `mesh`, so the kernels traced inside it know the mesh. XLA's
+    partitioner cannot split a Mosaic custom call — jax refuses at
+    lowering ("Mosaic kernels cannot be automatically partitioned") —
+    so under a mesh `attention` runs the kernel per shard in a
+    shard_map. `fn`'s name is kept: it is part of the program's name."""
+    @functools.wraps(fn)
+    def traced(*args):
+        token = _KERNEL_MESH.set(mesh)
+        try:
+            return fn(*args)
+        finally:
+            _KERNEL_MESH.reset(token)
+
+    return traced
+
+
+def _flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
+           pad_d: bool = True) -> jax.Array:
+    """flash_attention, per shard when a GSPMD mesh is being traced.
+    Every (batch row, head) is its own program of the kernel grid, so
+    splitting rows over dp and heads over tp moves no bits; an axis
+    that does not divide stays replicated (an under-filled bucket runs
+    the whole batch on every dp lane, as meshsolve.batch_specs does)."""
+    kernel = functools.partial(flash_attention, pad_d=pad_d)
+    mesh = _KERNEL_MESH.get()
+    if mesh is None:
+        return kernel(q, k, v)
+
+    def axis(name: str, size: int) -> str | None:
+        n = mesh.shape.get(name, 1)
+        return name if n > 1 and size % n == 0 else None
+
+    spec = P(axis("dp", q.shape[0]), axis("tp", q.shape[1]), None, None)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 VALID_ATTN_IMPLS = ("auto", "flash", "flash_nopad", "einsum")
@@ -179,12 +243,12 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
         return sp_attention_reference(q, k, v)
     on_tpu = jax.default_backend() == "tpu"
     if impl == "flash" and on_tpu:
-        return flash_attention(q, k, v)
+        return _flash(q, k, v)
     if impl == "flash_nopad" and on_tpu:
-        return flash_attention(q, k, v, pad_d=False)
+        return _flash(q, k, v, pad_d=False)
     # flash impls requested off-TPU fall through here: einsum is the only
     # compiled option off-TPU, so a fleet pinning "flash" still boots on
     # CPU dev hosts (the profiler only labels non-auto impls on TPU)
     if on_tpu and q.shape[2] >= 1024:
-        return flash_attention(q, k, v)
+        return _flash(q, k, v)
     return sp_attention_reference(q, k, v)

@@ -91,9 +91,12 @@ def boot_mesh(mesh_cfg: dict | None, *, registry=None):
 # non-dp axes are goldened at this size: every per-layout golden is
 # traced over abstract_mesh(MeshSpec(axis=2, ...)) — see each family's
 # trace_specs(). dp is the one size-free axis (sample-local compute,
-# layout-only gather: bytes are dp-size-invariant); a tp/sp size changes
-# the reduction order, i.e. the program, so an unshipped SIZE is an
-# unshipped determinism class exactly like an unshipped layout.
+# layout-only gather: bytes are dp-size-invariant — on CPU, test-pinned;
+# NOT on the chip: on four v5e chips dp=4 at canonical_batch 4 solved and
+# claimed but its CIDs matched neither single-chip canonical_batch 4 nor
+# 1, docs/multichip.md); a tp/sp size changes the reduction order, i.e.
+# the program, so an unshipped SIZE is an unshipped determinism class
+# exactly like an unshipped layout.
 GOLDEN_AXIS_SIZE = 2
 
 

@@ -140,8 +140,8 @@ def dequantize_tree(params):
 def quantize_params(params, mode: str):
     """Boot-time entry point (node/factory.py): ONE jitted program
     quantizing the loaded checkpoint tree on-device — eager per-leaf
-    quantizes would dispatch hundreds of ops one-by-one over a
-    remote-TPU transport (the boot-cast rationale). No donation: an
+    quantizes would dispatch hundreds of ops one-by-one (the boot-cast
+    rationale). No donation: an
     int8 output can never alias its f32 source, and XLA frees each
     full-width leaf when its last read (the absmax/divide) retires."""
     import jax

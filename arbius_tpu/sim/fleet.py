@@ -195,7 +195,7 @@ class FleetSimHarness(SimHarness):
             db_path=":memory:",  # unused: db object injected below
             models=tuple(ModelConfig(id=mid, template="anythingv3")
                          for mid in self.model_ids),
-            compile_cache_dir=None,
+            compile_cache=False,
             obs_journal_capacity=16384,
             retry_max_delay=self.result.retry_max_delay,
             pipeline=PipelineConfig(),
@@ -398,7 +398,7 @@ class FleetFloodHarness:
             cfg = MiningConfig(
                 models=(ModelConfig(id=self.model_id,
                                     template="anythingv3"),),
-                compile_cache_dir=None,
+                compile_cache=False,
                 canonical_batch=canonical_batch)
             node = MinerNode(
                 LocalChain(self.engine, a), cfg, registry,

@@ -309,7 +309,7 @@ class SimHarness:
             # every SIM1xx invariant must hold regardless
             sched=SchedConfig(enabled=True) if self.scenario.sched
             else SchedConfig(),
-            compile_cache_dir=None,
+            compile_cache=False,
             obs_journal_capacity=16384,
             retry_max_delay=self.result.retry_max_delay,
             # the staged executor runs under EVERY scenario's fault mix

@@ -18,16 +18,34 @@ import os
 import jax
 
 
-def enable_compile_cache(cache_dir: str) -> None:
-    """Idempotent; safe before or after backend init."""
-    os.makedirs(cache_dir, exist_ok=True)
-    # detlint: allow[DET106] boot-time compile-cache config — node.boot()
-    # runs this before any solve program compiles
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+# <checkout>/.jax_cache, from this file's own location: the cache path is
+# part of XLA's cache key, so a directory that moves with the working
+# directory (or a pid, a temp name, the time) never hits
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent XLA cache; returns the directory in effect.
+
+    The ONE placement rule (docs/compile-cache.md): where
+    `JAX_COMPILATION_CACHE_DIR` is exported, jax has already read it and
+    this code sets no directory; otherwise the cache is
+    DEFAULT_COMPILE_CACHE_DIR. Idempotent."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        cache_dir = jax.config.jax_compilation_cache_dir
+    else:
+        cache_dir = DEFAULT_COMPILE_CACHE_DIR
+        os.makedirs(cache_dir, exist_ok=True)
+        # detlint: allow[DET106] boot-time compile-cache config — node.boot()
+        # runs this before any solve program compiles
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     # detlint: allow[DET106] boot-time compile-cache config (see above)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # detlint: allow[DET106] boot-time compile-cache config (see above)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    return cache_dir
 
 
 def save_params(path: str, params: dict) -> None:

@@ -601,7 +601,7 @@ def _mini_world(tmp_path, *, aot_dir=None, sched_on=True):
     node = MinerNode(
         chain,
         MiningConfig(models=(ModelConfig(id=mid, template="anythingv3"),),
-                     canonical_batch=1, compile_cache_dir=None,
+                     canonical_batch=1, compile_cache=False,
                      sched=SchedConfig(enabled=sched_on)
                      if sched_on else SchedConfig(),
                      aot_cache=AotCacheConfig(enabled=True, dir=aot_dir)

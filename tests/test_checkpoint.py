@@ -1,10 +1,18 @@
 """Param checkpoint + compile-cache tests (SURVEY.md §5 checkpoint/resume)."""
 from __future__ import annotations
 
+import os
+
 import jax
 import numpy as np
+import pytest
 
-from arbius_tpu.utils import enable_compile_cache, load_params, save_params
+from arbius_tpu.utils import (
+    DEFAULT_COMPILE_CACHE_DIR,
+    enable_compile_cache,
+    load_params,
+    save_params,
+)
 
 
 def test_save_load_roundtrip(tmp_path):
@@ -28,14 +36,51 @@ def test_save_overwrites(tmp_path):
                                   np.ones(2))
 
 
-def test_enable_compile_cache(tmp_path):
-    cache = str(tmp_path / "xla")
-    enable_compile_cache(cache)
-    import os
-    assert os.path.isdir(cache)
-    # config took effect (idempotent re-set is fine too)
-    assert jax.config.jax_compilation_cache_dir == cache
-    enable_compile_cache(cache)
+@pytest.fixture
+def restore_cache_dir():
+    """enable_compile_cache edits process-wide jax config; put it back."""
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_placed_from_outside_is_left_alone(
+        monkeypatch, tmp_path, restore_cache_dir):
+    """JAX_COMPILATION_CACHE_DIR set ⇒ the code sets no directory (jax
+    read the variable itself; here the config stands in for that)."""
+    outside = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    jax.config.update("jax_compilation_cache_dir", outside)
+    assert enable_compile_cache() == outside
+    assert jax.config.jax_compilation_cache_dir == outside
+    assert not os.path.exists(outside)      # nothing created on the side
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 1.0
+
+
+def test_compile_cache_defaults_to_the_checkout_from_any_cwd(
+        monkeypatch, tmp_path, restore_cache_dir):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.chdir(tmp_path)
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(checkout, ".jax_cache")
+    assert DEFAULT_COMPILE_CACHE_DIR == want
+    assert enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert os.path.isdir(want) and os.listdir(tmp_path) == []
+    enable_compile_cache()                  # idempotent
+
+
+def test_force_cpu_devices_checks_what_jax_actually_runs_on(monkeypatch):
+    """jax ignores a late platform/XLA_FLAGS change without a word; the
+    helper must not (conftest already forced 8 CPU devices here)."""
+    from arbius_tpu.utils import force_cpu_devices
+
+    monkeypatch.setenv("XLA_FLAGS", os.environ.get("XLA_FLAGS", ""))
+    force_cpu_devices(1)        # fewer than we have: fine, count untouched
+    assert jax.device_count() == 8
+    assert "device_count=8" in os.environ["XLA_FLAGS"]
+    with pytest.raises(RuntimeError, match="before first jax use"):
+        force_cpu_devices(9)
 
 
 def test_fused_init_cast_matches_separate_cast():

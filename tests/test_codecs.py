@@ -68,6 +68,50 @@ def test_native_matches_python(data):
     assert fn(data) == deflate_fixed(data)
 
 
+@pytest.fixture
+def fresh_native(monkeypatch, tmp_path):
+    """_native with its one-shot load state reset and its build dir in
+    tmp_path (the module caches the first verdict per process)."""
+    from arbius_tpu.codecs import _native
+
+    monkeypatch.setattr(_native, "_tried", False)
+    monkeypatch.setattr(_native, "_lib", None)
+    monkeypatch.setattr(_native, "_BUILD_DIR", str(tmp_path / "build"))
+    return _native
+
+
+def test_native_library_is_named_by_its_source_hash(fresh_native, tmp_path):
+    """A binary built from another codecs.cc can never load: the name
+    carries the source digest, so a stale one is simply not looked at."""
+    import hashlib
+    import os
+
+    stale = tmp_path / "build" / "libarbius_codecs.so"
+    stale.parent.mkdir()
+    stale.write_bytes(b"not even ELF")       # the pre-hash file name
+    if fresh_native.deflate_impl() != "native":
+        pytest.skip("native codec lib unavailable (no g++?)")
+    with open(fresh_native._SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    assert sorted(os.listdir(tmp_path / "build")) == [
+        f"libarbius_codecs.{digest}.so", "libarbius_codecs.so"]
+
+
+def test_failed_native_build_is_logged_once_with_the_compiler_error(
+        fresh_native, monkeypatch, tmp_path, caplog):
+    bad = tmp_path / "codecs.cc"
+    bad.write_text("this is not C++;\n")
+    monkeypatch.setattr(fresh_native, "_SRC", str(bad))
+    with caplog.at_level("WARNING", logger="arbius.codecs"):
+        assert fresh_native.deflate_impl() == "python"
+        assert fresh_native.deflate_fixed() is None
+        assert deflate_compress(b"abc" * 100)    # python path still serves
+    logged = [r.getMessage() for r in caplog.records
+              if r.name == "arbius.codecs"]
+    assert len(logged) == 1
+    assert "codecs.cc" in logged[0] and "error" in logged[0]
+
+
 def test_zlib_container_valid():
     data = b"hello arbius" * 100
     assert zlib.decompress(zlib_compress(data)) == data

@@ -298,6 +298,41 @@ def test_sd15_real_pipeline_dp2_bitwise_equal_to_mesh_off():
 
 # -- per-layout goldens (the graphlint gate) --------------------------------
 
+@pytest.mark.parametrize("batch", [4, 2])   # dp-sharded / replicated
+def test_flash_kernel_lowers_for_tpu_inside_a_gspmd_mesh_program(
+        monkeypatch, batch):
+    """XLA cannot partition a Mosaic custom call: on the four-chip host
+    the dp mesh bucket died at lowering ("Mosaic kernels cannot be
+    automatically partitioned") — a path no CPU run reaches, because
+    `attention` only picks the kernel on TPU. Cross-lower for TPU here:
+    bare, the dispatch is refused; traced through `on_mesh` (what the
+    sd15/kandinsky2 mesh buckets do) it lowers, whether the batch
+    divides dp or stays replicated."""
+    import jax
+    import jax.numpy as jnp
+
+    from arbius_tpu.ops import flash
+    from arbius_tpu.parallel import build_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = build_mesh(MeshSpec(dp=4), devices=jax.devices()[:4])
+    spec, _ = meshsolve.batch_specs(mesh, batch)
+    qkv = (jax.ShapeDtypeStruct((batch, 2, 1024, 40), jnp.bfloat16),) * 3
+
+    def lower(fn):
+        jitted = jax.jit(fn, in_shardings=(spec(4),) * 3,
+                         out_shardings=spec(4))
+        return jitted.trace(*qkv).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    def attend(q, k, v):
+        return flash.attention(q, k, v)
+
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        lower(attend)
+    assert "tpu_custom_call" in lower(flash.on_mesh(attend, mesh))
+
+
 def test_every_shipped_family_layout_pair_has_a_golden():
     """Each family publishes its shipped layouts as data (MESH_LAYOUTS);
     every (family, layout) pair must have a golden fingerprint under

@@ -466,7 +466,7 @@ def _mini_node(tmp_path, *, perfscope=True, drift_max=0.0):
         chain,
         MiningConfig(models=(ModelConfig(id=mid, template="anythingv3"),),
                      db_path=str(tmp_path / "node.sqlite"),
-                     canonical_batch=2, compile_cache_dir=None,
+                     canonical_batch=2, compile_cache=False,
                      perfscope=PerfscopeConfig(enabled=perfscope,
                                                drift_max=drift_max)),
         registry)

@@ -223,7 +223,7 @@ def test_rvm_rejects_quantized_modes_at_boot():
                             golden={"input": {}, "seed": 0, "cid": "0x0",
                                     "probe_video": "2x16x16"}),),
         precision=PrecisionConfig(default="int8"),
-        compile_cache_dir=None)
+        compile_cache=False)
     with pytest.raises(ConfigError) as e:
         build_registry(cfg)
     assert "robust_video_matting" in str(e.value)

@@ -220,7 +220,7 @@ def test_miner_node_mines_end_to_end_over_rpc():
     chain = make_chain(dev, miner)
     cfg = MiningConfig(
         models=(ModelConfig(id=mid, template="anythingv3", tiny=True),),
-        compile_cache_dir=None)
+        compile_cache=False)
     node = MinerNode(chain, cfg, build_registry(cfg))
     node.boot(skip_self_test=True)
 

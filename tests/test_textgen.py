@@ -377,7 +377,7 @@ def _text_world(pipe, params, *, canonical_batch=2, pipeline_on=False,
         chain,
         MiningConfig(models=(ModelConfig(id=mid, template="textgen"),),
                      canonical_batch=canonical_batch,
-                     compile_cache_dir=None,
+                     compile_cache=False,
                      pipeline=PipelineConfig(enabled=pipeline_on),
                      aot_cache=AotCacheConfig(enabled=True, dir=aot_dir)
                      if aot_dir else AotCacheConfig()),
