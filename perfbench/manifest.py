@@ -1,0 +1,87 @@
+"""Finding a cell's files by the names in the manifest (`BENCHMARK.json`).
+
+Nothing here knows a cell, a configuration or a metric by name: a later
+PR adds entries to the manifest and files beside the ones that are there
+(`configs/<config>.json`, `traffic/<traffic>.json`, `metrics/<metric>.py`,
+`families/<family>.py`) and edits no file.
+"""
+from __future__ import annotations
+
+import importlib
+import importlib.util
+import json
+import os
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+DEFAULT_MANIFEST = os.path.join(ROOT, "BENCHMARK.json")
+
+
+class ManifestError(ValueError):
+    pass
+
+
+class Cell:
+    """One entry of `workloads` with everything its run needs."""
+
+    def __init__(self, manifest_path: str, name: str):
+        self.manifest_path = os.path.abspath(manifest_path)
+        self.base = os.path.dirname(self.manifest_path)
+        with open(self.manifest_path) as f:
+            self.manifest = json.load(f)
+        cells = {w["name"]: w for w in self.manifest["workloads"]}
+        if name not in cells:
+            raise ManifestError(
+                f"no workload {name!r} in {manifest_path}; it has "
+                f"{sorted(cells)}")
+        self.name = name
+        self.entry = cells[name]
+        self.chips = int(self.entry["chips"])
+        configs = {c["name"]: c for c in self.manifest["configs"]}
+        self.config_entry = configs[self.entry["config"]]
+        config_path = os.path.join(self.base, self.config_entry["file"])
+        self.config_dir = os.path.dirname(config_path)
+        with open(config_path) as f:
+            self.config = json.load(f)
+        # the traffic mix is a data file beside the manifest's first path
+        self.traffic_path = self._find("traffic", self.entry["traffic"],
+                                       ".json")
+        with open(self.traffic_path) as f:
+            self.traffic = json.load(f)
+
+    def _dirs(self, kind: str) -> list[str]:
+        return [os.path.join(self.base, p, kind)
+                for p in self.manifest["paths"]]
+
+    def _find(self, kind: str, name: str, ext: str) -> str:
+        for d in self._dirs(kind):
+            path = os.path.join(d, name + ext)
+            if os.path.exists(path):
+                return path
+        raise ManifestError(
+            f"no {kind} file {name + ext} under any of {self._dirs(kind)}")
+
+    def _applies(self, metric: dict) -> bool:
+        cells = metric.get("workloads")
+        return cells is None or self.name in cells
+
+    def end_to_end(self) -> list[dict]:
+        return [m for m in self.manifest["end_to_end"] if self._applies(m)]
+
+    def per_layer(self) -> list[dict]:
+        return [m for m in self.manifest["per_layer"] if self._applies(m)]
+
+    def reader(self, metric_name: str):
+        """The metric's reader: `metrics/<name>.py` with `read(run)`."""
+        path = self._find("metrics", metric_name, ".py")
+        spec = importlib.util.spec_from_file_location(
+            "perfbench_metric_" + metric_name.replace(".", "_")
+            .replace("-", "_"), path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod.read
+
+
+def family(name: str):
+    """The model family's builder and reference: `families/<name>.py`."""
+    return importlib.import_module(f"perfbench.families.{name}")

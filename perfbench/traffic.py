@@ -1,0 +1,69 @@
+"""The one traffic generator: a seeded task sequence from a data file.
+
+A traffic file states (all keys required unless marked):
+
+    loop         "closed": keep `outstanding` tasks on the chain, topped up
+                 each time solutions land (the only kind there is so far)
+    outstanding  tasks kept outstanding
+    cycle        [{"model": <template>, "count": n}, ...]: every run of
+                 sum(count) consecutive tasks holds exactly these, in an
+                 order shuffled by the seed — so every seed does the same
+                 set of work in another order
+    tasks        {<template>: {"input": {field: value}, "prompt_words":
+                 [lo, hi]}}: the fields a task of that model submits, and
+                 how many words its distinct, seeded prompt has
+    check        {"buckets": {<template>: n}}: how many whole buckets of
+                 each model's finished tasks, every slot of each, are
+                 compared with the plain reference (perfbench/correct.py)
+
+Nothing in here names a cell. Prompts are distinct within a run (the
+task's index is in them), so no two tasks share a task id or an image.
+"""
+from __future__ import annotations
+
+import json
+import random
+
+WORDS = ("amber basalt cedar delta ember fjord glacier harbor iris jade "
+         "kelp lantern meadow nebula orchid prairie quartz raven saffron "
+         "tundra umbra violet willow xenon yarrow zephyr miner chip tensor "
+         "lattice beacon orbit").split()
+
+
+class Traffic:
+    def __init__(self, spec: dict, seed: int):
+        if spec.get("loop") != "closed":
+            raise ValueError(f"traffic loop {spec.get('loop')!r}: only "
+                             "'closed' is implemented")
+        self.spec = spec
+        self.outstanding = int(spec["outstanding"])
+        self.cycle = [(c["model"], int(c["count"])) for c in spec["cycle"]]
+        self._rng = random.Random(f"perfbench-traffic-{seed}")
+        self._index = 0
+        self._queue: list[str] = []
+
+    def models(self) -> list[str]:
+        return [m for m, _ in self.cycle]
+
+    def _next_model(self) -> str:
+        if not self._queue:
+            block = [m for m, n in self.cycle for _ in range(n)]
+            self._rng.shuffle(block)
+            self._queue = block
+        return self._queue.pop(0)
+
+    def task(self, model: str | None = None, tag: str = "t") -> tuple[str, dict]:
+        """(template, input dict) of the next task; `model` forces one (the
+        warm-up) without consuming the cycle."""
+        if model is None:
+            model = self._next_model()
+        t = self.spec["tasks"][model]
+        lo, hi = t["prompt_words"]
+        n = self._rng.randint(lo, hi)
+        words = " ".join(self._rng.choice(WORDS) for _ in range(n))
+        self._index += 1
+        return model, {**t["input"], "prompt": f"{tag}{self._index} {words}"}
+
+
+def encode(task_input: dict) -> bytes:
+    return json.dumps(task_input, sort_keys=True).encode()
