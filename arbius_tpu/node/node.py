@@ -34,6 +34,7 @@ from arbius_tpu.node.db import Job, NodeDB
 from arbius_tpu.node.retry import RetriesExhausted, expretry
 from arbius_tpu.node.solver import ModelRegistry, solve_cid, solve_cid_batch
 from arbius_tpu.obs import Obs, span, use_obs
+from arbius_tpu.obs.trace import idle_gaps
 from arbius_tpu.templates.engine import (
     HydrationError,
     MiningFilter,
@@ -165,8 +166,14 @@ class MinerNode:
                   fn=self.db.job_count)
         self._c_idle = reg.counter(
             "arbius_chip_idle_seconds_total",
-            "Seconds the solve path spent with nothing dispatched on the "
-            "device (the host+network tail the pipeline exists to hide)")
+            "Seconds inside a solve pass with no dispatched chunk awaiting "
+            "its device result (the sum of the solve.idle spans: the "
+            "host+network tail that nothing on the chip hides)")
+        # taskid -> (perf_counter end, span id) of its task.event span:
+        # where task.queue_wait starts. In memory only (no span after a
+        # restart), bounded like the journal; RPC-thread submits write
+        # it too, so the state lock guards it
+        self._event_done: dict[str, tuple] = {}
         self.metrics = NodeMetrics(self.obs)
         self._retry_sleep = lambda s: None  # injectable; chain time is fake
         # fleet worker mode (docs/fleet.md), wired by LeaseFeed.attach:
@@ -436,10 +443,14 @@ class MinerNode:
         self._inc("tasks_seen")
         if self.registry.get(model) is None:
             return
-        with span("task.event", taskid=taskid, model=model):
+        with span("task.event", taskid=taskid, model=model) as sp:
             self.db.store_task(taskid, model, args["fee"], args["sender"],
                                self.chain.now, 0, "")
             self.db.queue_job("task", {"taskid": taskid}, concurrent=True)
+        with self.state_lock:
+            if len(self._event_done) >= self.config.obs_journal_capacity:
+                self._event_done.pop(next(iter(self._event_done)))
+            self._event_done[taskid] = (sp.t1, sp.span_id)
 
     def _sync_solution(self, taskid: str) -> None:
         sol = self.chain.get_solution(taskid)
@@ -886,7 +897,7 @@ class MinerNode:
                 taskids = [job.data["taskid"] for job, _ in b.entries]
                 with span("solve.batch", model=b.key[0], n=len(b.entries),
                           taskids=taskids):
-                    done += self._solve_bucket(m, b.entries, b.key)
+                    done += self._solve_bucket(m, b.entries, b.key, taskids)
             return done
         finally:
             self._ingest_costs()
@@ -940,8 +951,9 @@ class MinerNode:
             seconds=seconds)
 
     def _solve_bucket(self, m, entries: list[tuple[Job, dict]],
-                      key: tuple) -> int:
+                      key: tuple, taskids: list[str]) -> int:
         t_start = self.chain.now
+        busy: list = []   # (dispatch start, ready, chunk) per chunk
         # detlint: allow[DET101] obs stage timing; never reaches solve bytes
         w_start = time.perf_counter()
         try:
@@ -949,7 +961,8 @@ class MinerNode:
                 results = solve_cid_batch(
                     m, [(h, h["seed"]) for _, h in entries],
                     evilmode=self.config.evilmode,
-                    canonical_batch=self.config.canonical_batch)
+                    canonical_batch=self.config.canonical_batch,
+                    taskids=taskids, busy=busy)
         except Exception as e:  # noqa: BLE001 — whole bucket failed
             log.warning("bucket solve failed: %r", e)
             for job, _ in entries:
@@ -959,16 +972,22 @@ class MinerNode:
         # warm-preference signal (docs/scheduler.md)
         with self.state_lock:
             self._sched.mark_warm(key)
+        # detlint: allow[DET101] obs stage timing; never reaches solve bytes
+        w_solved = time.perf_counter()
         # tagged with the cost key so the learned model can attribute
         # the bucket's wall seconds to (model, bucket, layout, n) —
         # and the perfscope card, when installed, binds on the same key
-        # detlint: allow[DET101] obs stage timing; never reaches solve bytes
-        self._observe_infer(key, len(entries),
-                            time.perf_counter() - w_start,
+        self._observe_infer(key, len(entries), w_solved - w_start,
                             hydrated=entries[0][1])
+        # a runner without the dispatch/finalize pair computes inside
+        # its call: the whole solve was the chip's, from its start
+        busy = busy or [(w_start, w_solved, None)]
+        dispatched = {idx: t for t, _, idx in busy}
+        cb = max(1, self.config.canonical_batch)
+        for i, taskid in enumerate(taskids):
+            self._record_queue_wait(
+                taskid, dispatched.get(i // cb, w_start))
         done = 0
-        # detlint: allow[DET101] obs stage timing; never reaches solve bytes
-        w_commit = time.perf_counter()
         for (job, _), (cid, files) in zip(entries, results):
             try:
                 with span("solve.task", taskid=job.data["taskid"], cid=cid):
@@ -983,13 +1002,37 @@ class MinerNode:
                 log.warning("solve commit failed: %r", e)
                 self._fail_job(job, e)
         # detlint: allow[DET101] obs stage timing; never reaches solve bytes
-        commit_seconds = time.perf_counter() - w_commit
-        self._h_stage.observe(commit_seconds, stage="commit")
-        # on the synchronous path the whole pin/commit tail runs with
-        # nothing dispatched on the device — that window IS chip idle
-        # (the pipeline's A/B comparison baseline, docs/pipeline.md)
-        self._c_idle.inc(commit_seconds)
+        w_end = time.perf_counter()
+        self._h_stage.observe(w_end - w_solved, stage="commit")
+        # on the synchronous path the last chunk's encode and the whole
+        # pin/commit tail run with nothing on the device (the pipeline's
+        # A/B comparison baseline, docs/pipeline.md)
+        self._account_idle(w_start, w_end, busy)
         return done
+
+    def _account_idle(self, t0: float, t1: float, busy: list) -> None:
+        """Close one solve pass's idle accounting, on the tick thread
+        inside its solve.pipeline / solve.batch span: every stretch of
+        [t0, t1] of a millisecond or more in which no chunk was
+        dispatched-and-not-yet-ready (`busy`: [(dispatch start, ready,
+        chunk index)], `time.perf_counter` stamps) becomes a
+        `solve.idle` span, and `arbius_chip_idle_seconds_total` is
+        their sum — with obs off the spans go unjournaled and the
+        counter still counts."""
+        for a, b, after in idle_gaps(t0, t1, busy):
+            self.obs.tracer.record("solve.idle", a, b, after_chunk=after)
+            self._c_idle.inc(b - a)
+
+    def _record_queue_wait(self, taskid: str, t_dispatch: float) -> None:
+        """`task.queue_wait`: from the end of the task's `task.event`
+        to the start of its chunk's dispatch, as a child of that
+        event's span. A task taken in by an earlier life has no stamp
+        and gets no span."""
+        with self.state_lock:
+            stamp = self._event_done.pop(taskid, None)
+        if stamp is not None:
+            self.obs.tracer.record("task.queue_wait", stamp[0], t_dispatch,
+                                   parent=stamp[1], taskid=taskid)
 
     def _store_solution(self, taskid: str, cid: str, files: dict) -> None:
         """Pin solution bytes under their CID (data availability: the
@@ -1058,21 +1101,30 @@ class MinerNode:
                      max_delay=self.config.retry_max_delay,
                      sleep=self._retry_sleep, op="pin_blob")
 
-    def _maybe_profile(self):
-        """jax.profiler trace around every Nth solve dispatch when the
-        operator sets profile_dir (SURVEY.md §5: the reference has no
-        miner-side tracing at all)."""
-        import contextlib
-
+    def _profile_due(self) -> bool:
+        """True at every Nth solve dispatch when the operator sets
+        profile_dir (SURVEY.md §5: the reference has no miner-side
+        tracing at all)."""
         cfg = self.config
         if not cfg.profile_dir or cfg.profile_every <= 0:
-            return contextlib.nullcontext()
+            return False
         self._profile_counter = getattr(self, "_profile_counter", 0) + 1
-        if self._profile_counter % cfg.profile_every:
+        return self._profile_counter % cfg.profile_every == 0
+
+    def _maybe_profile(self):
+        """jax.profiler trace around the serial path's bucket solve
+        (dispatch through the last finalize, so the device's work is
+        inside) when one is due. The staged executor opens and closes
+        its own, from a chunk's dispatch to its consumed result
+        (pipeline.py). Either trace holds the program's spans as host
+        annotations (obs/trace.py)."""
+        import contextlib
+
+        if not self._profile_due():
             return contextlib.nullcontext()
         import jax
 
-        return jax.profiler.trace(cfg.profile_dir)
+        return jax.profiler.trace(self.config.profile_dir)
 
     def _commit_reveal(self, taskid: str, cid: str, t_start: int, *,
                        progress=None, skip_commit: bool = False) -> None:

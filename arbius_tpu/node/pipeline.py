@@ -9,10 +9,11 @@ hot loop into stages with bounded hand-off buffers:
            async dispatch: the call queues the program on the chip and
            returns immediately; generalizes solver.py's old one-deep
            overlap to a configurable prefetch window)
-  encode   transfer + codec + CID per chunk on a pool of
-           `encode_workers` threads (0 = inline on the tick thread);
-           per-chunk work is a pure function of the device result, so
-           worker count and completion order can never change bytes
+  encode   wait for the chunk's device result, then transfer + codec
+           + CID per chunk, on a pool of `encode_workers` threads (0 =
+           inline on the tick thread); per-chunk work is a pure
+           function of the device result, so worker count and
+           completion order can never change bytes
   network  pin → commit → reveal per task, on the tick thread, drained
            while later chunks are already on the chip; the backlog is
            bounded by `max_inflight_pins`
@@ -26,6 +27,17 @@ monotonicity) and persisted to the sqlite checkpoint (`pipeline_state`
 rows, written only AFTER the stage's side effect landed), so a
 crash-restart resumes mid-pipeline: a re-solved task whose recorded CID
 matches skips the pin/commit work that already happened.
+
+The path times itself (docs/observability.md): each chunk journals
+`solve.dispatch` on the tick thread and, as its children on whichever
+thread finalizes, `solve.device_wait` → `solve.encode` → `solve.cid`
+(the workers run under the node's obs). The moment a chunk's result is
+ready rides back with its encode result; from the chunks' [dispatch
+start, ready] intervals the tick thread reckons when nothing was on the
+chip, journals those stretches as `solve.idle` and adds them to
+`arbius_chip_idle_seconds_total`. A ready stamp is taken when a thread
+gets to wait on the result, so with fewer free workers than chunks in
+flight it can be late: idle is then under-counted, never over-counted.
 
 Every stage buffer is bounded — CONC302 is enforced for this file: an
 unbounded queue would hide a slow consumer instead of exerting
@@ -48,9 +60,8 @@ import threading
 import time
 from dataclasses import dataclass
 
-from arbius_tpu.l0.cid import cid_hex, cid_of_solution_files
-from arbius_tpu.node.solver import _check_declared, chunk_items
-from arbius_tpu.obs import span
+from arbius_tpu.node.solver import chunk_items, device_wait, encode_chunk
+from arbius_tpu.obs import span, use_obs
 
 log = logging.getLogger("arbius.pipeline")
 
@@ -68,24 +79,30 @@ class _Chunk:
     items: list             # [(hydrated, seed)] padded to canonical_batch
     real: int
     t_start: int = 0        # chain time at dispatch (latency metric)
-    dev_seconds: float = 0.0
-    payload: tuple | None = None   # inline mode: device result held here
+    t_dispatch: float = 0.0        # perf_counter at dispatch: busy from
+    t_ready: float | None = None   # ... until the result was ready
+    payload: tuple | None = None   # inline mode: _finish_chunk's arguments
 
 
-def _encode_chunk(model, payload, real: int) -> list[tuple[str, dict]]:
-    """Encode-stage body: device result → [(cid_hex, files)] per real
-    item. Pure in (model, payload) — safe on any worker thread, and
-    byte-identical to the serial path's finalize→CID sequence."""
-    kind, value = payload
-    if kind == "dev":
-        files_list = model.runner.finalize(value, real)
-    else:
-        files_list = value[:real]
-    out = []
-    for files in files_list:
-        files = _check_declared(model, files)
-        out.append((cid_hex(cid_of_solution_files(files)), files))
-    return out
+def _finish_chunk(model, payload, real: int, chunk: list,
+                  parent: int | None, t_ready: float | None) -> tuple:
+    """Encode-stage body, on a worker or inline: wait for the device
+    result, then `solver.encode_chunk`. Returns (encode seconds from
+    the ready stamp on, ready stamp, [(cid_hex, files)] or the
+    Exception the stage raised). `t_ready` comes in set for a payload
+    that needs no wait (the device stage computed it)."""
+    try:
+        if payload[0] == "dev":
+            t_ready = device_wait(payload[1], chunk=chunk, parent=parent)
+        out = encode_chunk(model, payload, real, chunk=chunk, parent=parent)
+    except Exception as e:  # noqa: BLE001 — reported per chunk
+        out = e
+    # detlint: allow[DET101] obs stage timing; never reaches solve bytes
+    t_end = time.perf_counter()
+    if t_ready is None:
+        # the wait itself failed: the chip counts as busy until it did
+        t_ready = t_end
+    return t_end - t_ready, t_ready, out
 
 
 class SolvePipeline:
@@ -99,7 +116,6 @@ class SolvePipeline:
         self.node = node
         self.cfg = cfg
         reg = node.obs.registry
-        self._c_idle = node._c_idle   # shared with the serial path's A/B
         self._c_stalls = reg.counter(
             "arbius_pipeline_stalls_total",
             "Times a pipeline stage blocked its producer, by stage",
@@ -107,7 +123,8 @@ class SolvePipeline:
         self._h_stage = reg.histogram(
             "arbius_pipeline_stage_seconds",
             "Wall seconds per pipeline stage unit (device=dispatch call "
-            "per chunk, encode=transfer+codec+CID per chunk, network="
+            "per chunk, encode=transfer+codec+CID per chunk from the "
+            "moment its device result is ready, network="
             "pin+commit+reveal per task)", labelnames=("stage",))
         self._g_depth = reg.gauge(
             "arbius_pipeline_queue_depth",
@@ -122,12 +139,17 @@ class SolvePipeline:
         self._bucket_h0: dict = {}
         self._bucket_n: dict = {}
         self._cv = threading.Condition()
-        # (generation, chunk idx) -> (elapsed, result); guarded by
-        # self._cv. The generation token fences off results a worker
-        # finishes AFTER a crash aborted its run — without it, the next
-        # run's chunk 0 could consume the dead run's bytes.
+        # (generation, chunk idx) -> (encode seconds, ready stamp,
+        # result); guarded by self._cv. The generation token fences off
+        # results a worker finishes AFTER a crash aborted its run —
+        # without it, the next run's chunk 0 could consume the dead
+        # run's bytes.
         self._results: dict[tuple, object] = {}
         self._gen = 0
+        # index of the chunk an operator's device profile is open over
+        # (node._profile_due): started at its dispatch, stopped when its
+        # result is consumed, so the trace holds the bucket program
+        self._profiled: int | None = None
         # device→encode hand-off, bounded at depth: a stalled encode
         # pool must block the dispatcher, not buffer device results
         self._encode_q: queue.Queue = queue.Queue(maxsize=max(1, cfg.depth))
@@ -149,26 +171,25 @@ class SolvePipeline:
 
     # -- encode pool (worker threads) -------------------------------------
     def _encode_worker(self) -> None:
-        while True:
-            item = self._encode_q.get()
-            if item is None:
-                return
-            key, model, payload, real = item
-            # detlint: allow[DET101] obs stage timing; never reaches solve bytes
-            t0 = time.perf_counter()
-            try:
-                out = _encode_chunk(model, payload, real)
-            except BaseException as e:  # noqa: BLE001 — a worker that
-                # dies WITHOUT posting a result would wedge the tick
-                # thread in _consume's cv.wait forever; every death,
-                # kill-class included, must surface as a chunk failure
-                out = e if isinstance(e, Exception) else RuntimeError(
-                    f"encode worker died: {type(e).__name__}: {e}")
-            # detlint: allow[DET101] obs stage timing; never reaches solve bytes
-            elapsed = time.perf_counter() - t0
-            with self._cv:
-                self._results[key] = (elapsed, out)
-                self._cv.notify_all()
+        with use_obs(self.node.obs):
+            while True:
+                item = self._encode_q.get()
+                if item is None:
+                    return
+                key, args = item
+                try:
+                    res = _finish_chunk(*args)
+                except BaseException as e:  # noqa: BLE001 — a worker
+                    # that dies WITHOUT posting a result would wedge the
+                    # tick thread in _consume's cv.wait forever; every
+                    # death, kill-class included, must surface as a
+                    # chunk failure
+                    # detlint: allow[DET101] obs stage timing; never reaches solve bytes
+                    res = (0.0, time.perf_counter(), RuntimeError(
+                        f"encode worker died: {type(e).__name__}: {e}"))
+                with self._cv:
+                    self._results[key] = res
+                    self._cv.notify_all()
 
     # -- the driver (tick thread) -----------------------------------------
     def run(self, buckets: list) -> int:
@@ -179,6 +200,8 @@ class SolvePipeline:
         completed."""
         chunks = self._plan(buckets)
         self._gen += 1
+        # detlint: allow[DET101] obs idle accounting only
+        t_open = time.perf_counter()
         with self._cv:
             # purge anything a dead run's workers finished late
             self._results.clear()
@@ -242,16 +265,16 @@ class SolvePipeline:
                         self._c_stalls.inc(stage="network")
                         done += self._network_stage(backlog.pop(0))
                 elif backlog:
-                    # nothing on the chip and nothing left to dispatch:
-                    # this tail drain is true chip idle time
-                    # detlint: allow[DET101] obs idle accounting only
-                    t0 = time.perf_counter()
+                    # nothing on the chip and nothing left to dispatch
                     while backlog:
                         done += self._network_stage(backlog.pop(0))
-                    # detlint: allow[DET101] obs idle accounting only
-                    self._c_idle.inc(time.perf_counter() - t0)
         finally:
             self._set_depths(0, 0)
+            self._stop_profile()
+        # detlint: allow[DET101] obs idle accounting only
+        self.node._account_idle(t_open, time.perf_counter(), [
+            (ch.t_dispatch, ch.t_ready, ch.idx) for ch in chunks
+            if ch.t_ready is not None])
         return done
 
     def _plan(self, buckets: list) -> list[_Chunk]:
@@ -279,13 +302,21 @@ class SolvePipeline:
         queue the XLA program and return; plain runners compute here.
         Returns False when the chunk failed (its jobs quarantined)."""
         ch.t_start = self.node.chain.now
-        # detlint: allow[DET101] obs stage timing; never reaches solve bytes
-        t0 = time.perf_counter()
-        self._infer_start.setdefault(ch.bucket, t0)
         runner = ch.model.runner
+        taskids = [job.data["taskid"] for job, _ in ch.entries]
+        if self._profiled is None and self.node._profile_due():
+            import jax
+
+            jax.profiler.start_trace(self.node.config.profile_dir)
+            self._profiled = ch.idx
+        # detlint: allow[DET101] obs stage timing; never reaches solve bytes
+        ch.t_dispatch = time.perf_counter()
+        self._infer_start.setdefault(ch.bucket, ch.t_dispatch)
         try:
-            with self.node._maybe_profile(), \
-                    span("solve.dispatch", n=ch.real, batch=len(ch.items)):
+            with self.node.obs.span(
+                    "solve.dispatch", n=ch.real, batch=len(ch.items),
+                    chunk=[self._gen, ch.idx], model=ch.model.id,
+                    taskids=taskids) as dsp:
                 dispatch = getattr(runner, "dispatch", None)
                 finalize = getattr(runner, "finalize", None)
                 if dispatch is not None and finalize is not None:
@@ -299,11 +330,15 @@ class SolvePipeline:
                                              for h, s in ch.items[:ch.real]])
         except Exception as e:  # noqa: BLE001 — chunk-level quarantine
             log.warning("pipeline device stage failed: %r", e)
+            if self._profiled == ch.idx:
+                self._stop_profile()
             self._fail_chunk(ch, e)
             return False
         # detlint: allow[DET101] obs stage timing; never reaches solve bytes
-        ch.dev_seconds = time.perf_counter() - t0
-        self._h_stage.observe(ch.dev_seconds, stage="device")
+        t_done = time.perf_counter()
+        self._h_stage.observe(t_done - ch.t_dispatch, stage="device")
+        for taskid in taskids:
+            self.node._record_queue_wait(taskid, ch.t_dispatch)
         # dispatch succeeded ⇒ the bucket's executable is compiled —
         # feed the packer's warm-preference set (docs/scheduler.md);
         # state lock: a /debug snapshot may iterate the warm set
@@ -311,11 +346,13 @@ class SolvePipeline:
             self.node._sched.mark_warm(self._bucket_keys[ch.bucket])
         for job, _ in ch.entries:
             self._stage_event(job.data["taskid"], "solve", job.id)
+        # a plain runner computed in the call: ready when it returned
+        args = (ch.model, payload, ch.real, [self._gen, ch.idx],
+                dsp.span_id, None if payload[0] == "dev" else t_done)
         if self._workers:
-            self._encode_q.put(((self._gen, ch.idx), ch.model, payload,
-                                ch.real))
+            self._encode_q.put(((self._gen, ch.idx), args))
         else:
-            ch.payload = payload
+            ch.payload = args
         return True
 
     def _consume(self, ch: _Chunk):
@@ -324,14 +361,8 @@ class SolvePipeline:
         feeds `arbius_stage_seconds{infer}` so the profitability gate
         and NodeMetrics see the same cost signal as the serial path."""
         if not self._workers:
-            # detlint: allow[DET101] obs stage timing; never reaches solve bytes
-            t0 = time.perf_counter()
-            try:
-                out = _encode_chunk(ch.model, ch.payload, ch.real)
-            except Exception as e:  # noqa: BLE001 — reported per chunk
-                out = e
-            # detlint: allow[DET101] obs stage timing; never reaches solve bytes
-            elapsed = time.perf_counter() - t0
+            elapsed, ch.t_ready, out = _finish_chunk(*ch.payload)
+            ch.payload = None
         else:
             key = (self._gen, ch.idx)
             with self._cv:
@@ -339,10 +370,19 @@ class SolvePipeline:
                     self._c_stalls.inc(stage="encode")
                 while key not in self._results:
                     self._cv.wait()
-                elapsed, out = self._results.pop(key)
+                elapsed, ch.t_ready, out = self._results.pop(key)
+        if self._profiled == ch.idx:
+            self._stop_profile()
         self._h_stage.observe(elapsed, stage="encode")
         self._bucket_chunk_done(ch.bucket, ok=not isinstance(out, Exception))
         return out
+
+    def _stop_profile(self) -> None:
+        if self._profiled is not None:
+            import jax
+
+            self._profiled = None
+            jax.profiler.stop_trace()
 
     def _network_stage(self, item: tuple) -> int:
         """Pin → commit → reveal one task on the tick thread, resuming

@@ -28,7 +28,7 @@ import numpy as np
 
 from arbius_tpu.codecs import encode_png
 from arbius_tpu.l0.cid import cid_hex, cid_of_solution_files
-from arbius_tpu.obs import span
+from arbius_tpu.obs import span, under
 from arbius_tpu.templates.engine import Template, load_template
 
 Runner = Callable[[dict, int], dict]
@@ -113,24 +113,6 @@ def solve_files(model: RegisteredModel, hydrated: dict, seed: int) -> dict:
     return _check_declared(model, model.runner(hydrated, seed))
 
 
-def solve_files_batch(model: RegisteredModel, items: list[tuple[dict, int]],
-                      *, canonical_batch: int = 1) -> list[dict]:
-    """Batched inference over one shape bucket, ALWAYS at the canonical
-    batch size.
-
-    Batch size is part of the compiled XLA program, and different programs
-    are different determinism classes — if miners ran whatever batch their
-    queue happened to hold, two honest nodes could emit different bytes
-    for the same task and contest each other. So every dispatch is padded
-    to exactly `canonical_batch` samples (repeating the last real item)
-    and one bucket ⇒ one program ⇒ one determinism class. Runners without
-    `run_batch` are the canonical_batch=1 case by construction.
-    """
-    with span("solve.infer", n=len(items), batch=canonical_batch):
-        return _solve_files_batch(model, items,
-                                  canonical_batch=canonical_batch)
-
-
 def chunk_items(items: list[tuple[dict, int]],
                 canonical_batch: int) -> list[tuple[list, int]]:
     """Split a bucket's items into canonical_batch-sized chunks, padding
@@ -147,34 +129,73 @@ def chunk_items(items: list[tuple[dict, int]],
     return chunks
 
 
-def _solve_files_batch(model: RegisteredModel, items: list[tuple[dict, int]],
-                       *, canonical_batch: int = 1) -> list[dict]:
-    run_batch = getattr(model.runner, "run_batch", None)
-    if run_batch is None or canonical_batch <= 1:
-        return [solve_files(model, h, s) for h, s in items]
-    chunks = chunk_items(items, canonical_batch)
-    out: list[dict] = []
-    dispatch = getattr(model.runner, "dispatch", None)
-    finalize = getattr(model.runner, "finalize", None)
-    if dispatch is not None and finalize is not None and len(chunks) > 1:
-        # one-deep pipeline: queue chunk i+1's XLA dispatch BEFORE
-        # transferring/encoding chunk i, so the host PNG encode (~64 ms/
-        # image, the dominant host cost) overlaps the chip's compute (JAX
-        # async dispatch); CID hashing (~1 ms/solve) stays serial in
-        # solve_cid_batch. Output order and bytes are identical to the
-        # serial path — only the schedule changes.
-        pending = None  # (device result, real count)
-        for chunk, real in chunks:
-            dev = dispatch(chunk)
-            if pending is not None:
-                out.extend(_check_declared(model, f)
-                           for f in finalize(*pending))
-            pending = (dev, real)
-        out.extend(_check_declared(model, f) for f in finalize(*pending))
-        return out
-    for chunk, real in chunks:
-        files = run_batch(chunk)
-        out.extend(_check_declared(model, f) for f in files[:real])
+# -- one chunk's span chain (docs/observability.md) -------------------------
+#
+# Both schedules journal the same chain per dispatched chunk:
+# solve.dispatch, then solve.device_wait, solve.encode (opened by the
+# runner's finalize) and solve.cid as its children, on whichever thread
+# finalizes. This file reads no clock (DET101 is enforced here): the
+# stamps the schedules reckon chip idle from are the spans' own.
+
+def device_wait(value, *, chunk, parent: int | None) -> float | None:
+    """Block until a dispatched chunk's device result is ready — the
+    ONE place either schedule waits on the chip, so `solve.encode`
+    times transfer + codec only. Returns the moment it was ready
+    (`time.perf_counter`, the span's end), None with no ambient obs."""
+    import jax
+
+    with span("solve.device_wait", parent=parent, chunk=chunk) as sp:
+        jax.block_until_ready(value)
+    return sp.t1 if sp is not None else None
+
+
+def encode_chunk(model: RegisteredModel, payload: tuple, real: int, *,
+                 chunk, parent: int | None) -> list[tuple[str, dict]]:
+    """Ready device result → [(cid_hex, files)] per real item. Pure in
+    (model, payload) — safe on any worker thread, and the same
+    finalize→CID sequence on both schedules."""
+    kind, value = payload
+    if kind == "dev":
+        # the runner opens solve.encode itself, from the ambient stack
+        with under(parent):
+            files_list = model.runner.finalize(value, real)
+    else:
+        files_list = value[:real]
+    with span("solve.cid", parent=parent, n=real, chunk=chunk):
+        return [(cid_hex(cid_of_solution_files(_check_declared(model, f))),
+                 f) for f in files_list]
+
+
+def _solve_chunked(model: RegisteredModel, chunks: list, *, gen: int,
+                   taskids, busy) -> list[tuple[str, dict]]:
+    """One-deep pipeline: queue chunk i+1's XLA dispatch BEFORE
+    transferring/encoding chunk i, so the host PNG encode (~64 ms/
+    image, the dominant host cost) overlaps the chip's compute (JAX
+    async dispatch). Output order and bytes are those of
+    finalize(dispatch(chunk)) chunk by chunk — only the schedule
+    changes. `busy` collects (dispatch start, ready, chunk index)."""
+    runner = model.runner
+    b = len(chunks[0][0])
+    out: list[tuple[str, dict]] = []
+
+    def finish(idx, dev, real, dsp):
+        parent = dsp.span_id if dsp is not None else None
+        ready = device_wait(dev, chunk=[gen, idx], parent=parent)
+        if busy is not None and ready is not None:
+            busy.append((dsp.t0, ready, idx))
+        out.extend(encode_chunk(model, ("dev", dev), real,
+                                chunk=[gen, idx], parent=parent))
+
+    pending = None
+    for idx, (chunk, real) in enumerate(chunks):
+        with span("solve.dispatch", n=real, batch=len(chunk),
+                  chunk=[gen, idx], model=model.id,
+                  taskids=(taskids or [])[idx * b:idx * b + real]) as dsp:
+            dev = runner.dispatch(chunk)
+        if pending is not None:
+            finish(*pending)
+        pending = (idx, dev, real, dsp)
+    finish(*pending)
     return out
 
 
@@ -195,13 +216,46 @@ def solve_cid(model: RegisteredModel, hydrated: dict, seed: int,
 
 
 def solve_cid_batch(model: RegisteredModel, items: list[tuple[dict, int]],
-                    *, evilmode: bool = False,
-                    canonical_batch: int = 1) -> list[tuple[str, dict]]:
-    """Batched solve_cid over one shape bucket."""
+                    *, evilmode: bool = False, canonical_batch: int = 1,
+                    taskids: list[str] | None = None,
+                    busy: list | None = None) -> list[tuple[str, dict]]:
+    """Batched solve_cid over one shape bucket, ALWAYS at the canonical
+    batch size.
+
+    Batch size is part of the compiled XLA program, and different programs
+    are different determinism classes — if miners ran whatever batch their
+    queue happened to hold, two honest nodes could emit different bytes
+    for the same task and contest each other. So every dispatch is padded
+    to exactly `canonical_batch` samples (repeating the last real item)
+    and one bucket ⇒ one program ⇒ one determinism class. Runners without
+    `run_batch` are the canonical_batch=1 case by construction.
+
+    Runners with the dispatch/finalize pair are chunk-pipelined
+    (`_solve_chunked`; `run_batch` IS finalize(dispatch(...)) in every
+    one of them, so a single chunk takes that branch too) and journal
+    the per-chunk span chain; `taskids` (item order) names the tasks on
+    it, and `busy`, when given, collects each chunk's (dispatch start,
+    ready, index) stamps for the caller's idle accounting."""
     if evilmode:
         return [(EVIL_CID, {})] * len(items)
-    files_list = solve_files_batch(model, items,
-                                   canonical_batch=canonical_batch)
+    runner = model.runner
+    run_batch = getattr(runner, "run_batch", None)
+    with span("solve.infer", n=len(items), batch=canonical_batch) as infer:
+        if run_batch is None or canonical_batch <= 1:
+            files_list = [solve_files(model, h, s) for h, s in items]
+        elif getattr(runner, "dispatch", None) is not None \
+                and getattr(runner, "finalize", None) is not None:
+            # chunk ids are (generation, index): the staged executor's
+            # generation is its run counter, here it is this span's id
+            return _solve_chunked(
+                model, chunk_items(items, canonical_batch),
+                gen=infer.span_id if infer is not None else 0,
+                taskids=taskids, busy=busy)
+        else:
+            files_list = []
+            for chunk, real in chunk_items(items, canonical_batch):
+                files_list.extend(_check_declared(model, f)
+                                  for f in run_batch(chunk)[:real])
     with span("solve.cid", n=len(files_list)):
         return [(cid_hex(cid_of_solution_files(files)), files)
                 for files in files_list]
@@ -401,7 +455,7 @@ class SD15Runner:
 
     def dispatch(self, items: list[tuple[dict, int]]):
         """Queue the bucket's XLA dispatch and return WITHOUT waiting
-        (JAX async dispatch): the chunk-pipelining in solve_files_batch
+        (JAX async dispatch): the chunk-pipelining in solve_cid_batch
         encodes chunk i's PNGs on the host while the chip crunches chunk
         i+1 — the host codec work disappears from the critical path."""
         first = items[0][0]

@@ -7,8 +7,11 @@ none). Three pieces behind one facade:
     Prometheus text exposition (`ControlRPC` serves it at GET /metrics)
     and bounded recent-sample windows for exact rolling percentiles.
   - `Tracer`: `span(name, **attrs)` context managers with parent/child
-    nesting, wall-time + chain-time stamps, completed spans recorded
-    into the journal and `arbius_span_seconds{name}`.
+    nesting (a cause on another thread named explicitly), wall-time,
+    monotonic and chain-time stamps, completed spans recorded into the
+    journal and `arbius_span_seconds{name}`, and entered as
+    `jax.profiler.TraceAnnotation`s so a profile shows them beside the
+    device's operations.
   - `EventJournal`: bounded ring buffer of span completions and
     retry/failure events, queryable by taskid (GET /debug/trace) and
     dumpable (`tools/obs_dump.py`).
@@ -40,9 +43,9 @@ from arbius_tpu.obs.trace import Span, Tracer, task_trace
 class Obs:
     """One node's observability bundle: registry + journal + tracer.
 
-    `enabled=False` turns off tracing and journaling (the hot-path
-    per-span cost) while the registry keeps counting — the metrics
-    surface stays truthful either way.
+    `enabled=False` turns off journaling (the hot-path per-span cost:
+    spans are still stamped, nothing is recorded) while the registry
+    keeps counting — the metrics surface stays truthful either way.
     """
 
     def __init__(self, *, journal_capacity: int = 4096, now_fn=None,
@@ -73,8 +76,6 @@ class Obs:
         self.perfscope = None
 
     def span(self, name: str, **attrs):
-        if not self.enabled:
-            return nullcontext()
         return self.tracer.span(name, **attrs)
 
     def event(self, kind: str, **fields) -> None:
@@ -109,9 +110,18 @@ def span(name: str, **attrs):
     """Ambient span: traces into the active Obs, no-op (a shared
     reusable nullcontext — no allocation) when none is active."""
     obs = _ACTIVE.get()
-    if obs is None or not obs.enabled:
+    if obs is None:
         return _NULL_CM
     return obs.tracer.span(name, **attrs)
+
+
+def under(parent: int | None):
+    """Ambient `Tracer.under`: spans the block opens on this thread
+    become children of span `parent` (no-op when nothing is active)."""
+    obs = _ACTIVE.get()
+    if obs is None:
+        return _NULL_CM
+    return obs.tracer.under(parent)
 
 
 # -- jit-cache observability (docs/scheduler.md, docs/observability.md) -----
@@ -274,5 +284,5 @@ __all__ = [
     "DEFAULT_BUCKETS", "Counter", "EventJournal", "Gauge", "Histogram",
     "MetricsRegistry", "Obs", "Span", "Tracer", "compile_timer",
     "current_obs", "jit_cache_get", "span", "task_trace",
-    "timed_dispatch", "use_obs",
+    "timed_dispatch", "under", "use_obs",
 ]

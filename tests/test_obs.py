@@ -382,3 +382,117 @@ def test_config_obs_knobs_validate():
         MiningConfig(obs_journal_capacity=0)
     with pytest.raises(ConfigError):
         MiningConfig(retry_max_delay=-1.0)
+
+
+# -- tracer: causes on other threads, after-the-fact spans, one clock -------
+
+def test_span_names_an_explicit_parent_across_threads():
+    import threading
+
+    obs = Obs(journal_capacity=16)
+    with obs.span("cause") as cause:
+        pass
+
+    def worker():
+        with use_obs(obs):
+            with span("effect", parent=cause.span_id):
+                with span("nested"):
+                    pass
+
+    t = threading.Thread(target=worker)
+    t.start()
+    t.join(timeout=5)
+    assert not t.is_alive()
+    by_name = {e["name"]: e for e in obs.journal.events(kind="span")}
+    assert by_name["effect"]["parent_id"] == by_name["cause"]["span_id"]
+    assert by_name["nested"]["parent_id"] == by_name["effect"]["span_id"]
+    # an explicit parent wins over the thread's own stack too
+    with obs.span("outer"):
+        with obs.span("adopted", parent=cause.span_id):
+            pass
+    adopted = obs.journal.events(kind="span")[-2]
+    assert adopted["name"] == "adopted"
+    assert adopted["parent_id"] == cause.span_id
+
+
+def test_under_lends_a_parent_to_ambient_spans_and_records_nothing():
+    from arbius_tpu.obs import under
+
+    obs = Obs(journal_capacity=16)
+    with use_obs(obs):
+        with span("cause") as cause:
+            pass
+        with under(cause.span_id):
+            with span("library.span"):
+                pass
+        with under(None):
+            with span("orphan"):
+                pass
+    evs = obs.journal.events(kind="span")
+    assert [e["name"] for e in evs] == ["cause", "library.span", "orphan"]
+    assert evs[1]["parent_id"] == cause.span_id
+    assert evs[2]["parent_id"] is None
+    with under(cause.span_id):   # nothing active: a no-op
+        pass
+
+
+def test_record_journals_an_interval_after_the_fact():
+    import time
+
+    obs = Obs(journal_capacity=16)
+    t0 = time.perf_counter() - 2.0
+    with obs.span("root") as root:
+        obs.tracer.record("solve.idle", t0, t0 + 0.5, after_chunk=1)
+    obs.tracer.record("task.queue_wait", t0, t0 + 1.5, parent=root.span_id,
+                      taskid="0x9")
+    idle, _, wait = obs.journal.events(kind="span")
+    # the same fields as any span's: perfbench's add_journal and
+    # task_trace take these
+    assert idle["kind"] == "span" and idle["status"] == "ok"
+    assert idle["parent_id"] == root.span_id       # the open span
+    assert idle["wall_s"] == 0.5 and idle["mono_start"] == t0
+    assert idle["attrs"] == {"after_chunk": 1}
+    assert idle["wall_start"] == pytest.approx(time.time() - 2.0, abs=0.05)
+    assert wait["parent_id"] == root.span_id and wait["taskid"] == "0x9"
+    assert wait["span_id"] not in (idle["span_id"], root.span_id)
+    assert obs.registry.histogram("arbius_span_seconds",
+                                  labelnames=("name",)).count(
+        name="solve.idle") == 1
+    # task_trace: the recorded span under its parent, like any other
+    (tree,) = obs.task_trace("0x9")
+    assert tree["name"] == "root"
+    assert [n["name"] for n in tree["children"]] == ["task.queue_wait"]
+
+
+def test_spans_carry_both_clocks_and_stamp_even_when_disabled():
+    import time
+
+    obs = Obs(journal_capacity=16)
+    m0, w0 = time.perf_counter(), time.time()
+    with obs.span("stamped") as sp:
+        time.sleep(0.01)
+    (ev,) = obs.journal.events(kind="span")
+    assert ev["mono_start"] == sp.t0 and m0 <= sp.t0 <= sp.t1
+    assert ev["wall_s"] == pytest.approx(sp.t1 - sp.t0, abs=1e-6)
+    assert ev["wall_start"] - w0 == pytest.approx(sp.t0 - m0, abs=0.01)
+    off = Obs(journal_capacity=16, enabled=False)
+    with off.span("quiet") as sp:
+        pass
+    off.tracer.record("quiet.too", sp.t0, sp.t1)
+    assert sp.t1 >= sp.t0 > 0 and len(off.journal) == 0
+
+
+def test_idle_gaps_are_what_no_busy_interval_covers():
+    from arbius_tpu.obs.trace import idle_gaps
+
+    busy = [(1.0, 3.0, 0), (2.0, 5.0, 1), (5.0005, 6.0, 2), (8.0, 9.0, 3)]
+    assert idle_gaps(0.0, 10.0, busy) == [
+        (0.0, 1.0, None), (6.0, 8.0, 2), (9.0, 10.0, 3)]
+    # overlapping chunks count once; a gap under a millisecond is none
+    assert idle_gaps(1.0, 6.0, busy) == []
+    assert idle_gaps(0.0, 2.0, []) == [(0.0, 2.0, None)]
+    # a chunk still busy at the window's end leaves no tail
+    assert idle_gaps(0.0, 4.0, [(1.0, 7.0, 0)]) == [(0.0, 1.0, None)]
+    # ... and one nested in a longer one does not cut it short
+    assert idle_gaps(0.0, 9.0, [(1.0, 8.0, 0), (2.0, 3.0, 1)]) == [
+        (0.0, 1.0, None), (8.0, 9.0, 0)]

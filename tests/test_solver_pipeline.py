@@ -1,8 +1,8 @@
-"""Chunk-pipelined solve path: solve_files_batch must overlap host
+"""Chunk-pipelined solve path: solve_cid_batch must overlap host
 encode with the next dispatch WITHOUT changing output order or bytes."""
 from __future__ import annotations
 
-from arbius_tpu.node.solver import RegisteredModel, solve_files_batch
+from arbius_tpu.node.solver import RegisteredModel, solve_cid_batch
 
 
 class _Template:
@@ -38,9 +38,9 @@ def _model(log):
 def test_pipeline_overlaps_and_preserves_order():
     log = []
     items = [({"prompt": f"p{i}"}, i) for i in range(7)]
-    out = solve_files_batch(_model(log), items, canonical_batch=2)
+    out = solve_cid_batch(_model(log), items, canonical_batch=2)
     # bytes + order identical to the serial path
-    assert [f["out-1.png"] for f in out] == [f"img{i}".encode()
+    assert [f["out-1.png"] for _, f in out] == [f"img{i}".encode()
                                             for i in range(7)]
     # schedule actually overlaps: chunk 2's dispatch precedes chunk 1's
     # finalize (one-deep pipeline), incl. the padded last chunk
@@ -54,6 +54,6 @@ def test_pipeline_overlaps_and_preserves_order():
 def test_single_chunk_stays_serial():
     log = []
     items = [({"prompt": "p"}, 1), ({"prompt": "q"}, 2)]
-    out = solve_files_batch(_model(log), items, canonical_batch=2)
-    assert [f["out-1.png"] for f in out] == [b"img1", b"img2"]
+    out = solve_cid_batch(_model(log), items, canonical_batch=2)
+    assert [f["out-1.png"] for _, f in out] == [b"img1", b"img2"]
     assert [k for k, _ in log] == ["dispatch", "finalize"]
