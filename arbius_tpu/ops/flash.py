@@ -1,21 +1,48 @@
 """Pallas flash attention — blockwise softmax attention in VMEM.
 
-Why: the video UNet's spatial attention at zeroscope shape (1024×576 →
-latent 128×72 = 9216 tokens) materializes a 9216² f32 score matrix per
-head through the XLA einsum path (~340 MB/head-batch) — HBM-bound. The
-flash form never materializes scores: K/V stream through VMEM in blocks
-while running max/normalizer/accumulator stats (the same online-softmax
-math as ops/ring.py, one level down the memory hierarchy).
+Why: self-attention over S=9216 tokens (anythingv3's UNet at 768x768,
+its VAE and Kandinsky's MOVQ mid-block at 96x96 latents, the video
+UNet's spatial attention) materializes a 9216 x 9216 float32 score
+matrix per head through the XLA einsum path (~340 MB a head) — HBM-bound.
+The flash form never materializes scores: K/V pass through VMEM in
+blocks under a running max / normalizer / accumulator (the same
+online-softmax mathematics as ops/ring.py, one level down the memory
+hierarchy). It is the first device operation of the benchmark's
+`mix-768-backlog` cell (PERF.md section 5); `flash_roofline_pct` there
+is this kernel's share of its roofline.
 
-Kernel layout (pallas_guide.md patterns):
-  grid = (batch*heads, Sq/BLOCK_Q); each program owns one Q block in
-  VMEM, loops over K/V blocks with fori_loop, f32 accumulators, MXU
-  matmuls via jnp.dot(preferred_element_type=f32). Shapes are padded to
-  the (8, 128) f32 tile grid; padded K positions are masked with -inf
-  before the softmax stats, so padding never changes the math.
+Precision, the policy `sp_attention_reference` states for the same
+model: q, k and v go to the MXU in the type they arrive in (bf16 in
+both cells; float32 inputs stay float32), both products accumulate in
+float32, scores / max / normalizer / accumulator and the exponent are
+float32, the scale multiplies the float32 scores, and the probabilities
+are cast to v's type for the second product.
+
+Layout: grid = (batch*heads, Sq/block_q). A program owns one Q block
+and sees the whole K and V of its head as one VMEM block (fetched once a
+head: the block index does not move with the Q block), and walks it in
+K blocks of block_k. D is padded to 128 lanes and the sequences to their
+tiles; only a K block that can hold a padded key is masked — statically
+none when block_k divides the keys, else the last one.
+
+Tiles come from the call's static shape (`_tiles`), not from a name.
+What a trip over one K block costs on a v5e is its chain — q @ k.T, two
+lane reductions, the exponent, p @ v, each waiting on the last — far
+more than its arithmetic: at 128 x 128 tiles a trip took 374 ns against
+43 ns of MXU time, and neither bf16 operands nor dropping the mask moved
+that (PERF.md section 6, PR 26). So the K tile is as long as the keys
+allow up to 1024 rows, the Q tile is what a 1 MiB float32 score tile
+and a 1 MiB float32 accumulator then leave (256 rows beside 1024 keys,
+1024 rows beside the 77 keys of cross-attention, at most 512 at D=512),
+and `_UNROLL` K blocks share a trip of the loop so that one block's
+products overlap its neighbour's softmax. VMEM per program is reckoned
+in `_vmem_bytes` and stated as the call's limit: K/V whole and double-
+buffered are 9 MiB at S=9216, D=40 (lane-padded) and 36 MiB at D=512
+in bf16, 72 MiB in float32, beside at most 10 MiB of work arrays.
 
 `flash_attention` is a drop-in for `sp_attention_reference` ([B, H, S, D]
-→ [B, H, S, D]); `interpret=True` runs it on CPU for tests.
+→ [B, H, S, D]); `interpret=True` runs it on CPU for tests
+(tests/test_flash_kernel.py in tier-1).
 """
 from __future__ import annotations
 
@@ -29,50 +56,104 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-BLOCK_Q = 128
-BLOCK_K = 128
 NEG_INF = -1e30
-# Mosaic's default scoped-VMEM budget on a v5e. Each program holds the
-# WHOLE K and V sequence as one block, double-buffered by Pallas, so the
-# budget is stated per call from the block sizes: the VAE mid-block
-# (one head, D=512, S=4096) needs 16 MiB for K/V alone and libtpu 0.0.34
-# refuses it at the default once the batch exceeds 1 (RESOURCE_EXHAUSTED
-# in vmem); S=9216 needs 36 MiB. Stating the limit moves no bits.
+_NT = (((1,), (1,)), ((), ()))     # q @ k.T without a transpose op
+_LANES = 128
+# Two float32 arrays of a program are shaped by the tiles, the score
+# tile [block_q, block_k] and the accumulator [block_q, D]; each is held
+# to 1 MiB (256 vregs an elementwise op, which Mosaic unrolls), and no
+# tile is longer than 1024 rows.
+_WORK_BYTES = 1024 * 1024
+_MAX_TILE = 1024
+# K blocks a trip of the loop takes: consecutive blocks depend on each
+# other only through (m, l, acc), so the next block's q @ k.T runs on
+# the MXU while this block's softmax runs on the VPU. Written out by
+# hand: Mosaic lowers `fori_loop(unroll=)` only for 1 and for all.
+_UNROLL = 3
+# Mosaic's default scoped-VMEM budget on a v5e; the limit is stated per
+# call from the block sizes (_vmem_bytes), which moves no bits.
 _VMEM_DEFAULT = 16 * 1024 * 1024
-_VMEM_HEADROOM = 8 * 1024 * 1024   # Q/O blocks + the kernel's f32 copies
-
-
-def _kernel(q_ref, k_ref, v_ref, o_ref, *, kv_len: int, scale: float):
-    q = q_ref[0].astype(jnp.float32)                  # [BLOCK_Q, D]
-    n_kv = k_ref.shape[1] // BLOCK_K
-
-    m0 = jnp.full((BLOCK_Q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((BLOCK_Q,), jnp.float32)
-    acc0 = jnp.zeros((BLOCK_Q, q.shape[-1]), jnp.float32)
-
-    def body(j, carry):
-        m, l, acc = carry
-        k = k_ref[0, pl.ds(j * BLOCK_K, BLOCK_K), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * BLOCK_K, BLOCK_K), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        # mask K padding (positions >= kv_len)
-        kpos = j * BLOCK_K + jax.lax.broadcasted_iota(jnp.int32,
-                                                      (1, BLOCK_K), 1)
-        s = jnp.where(kpos < kv_len, s, NEG_INF)
-        mb = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - mb[:, None])
-        alpha = jnp.exp(m - mb)
-        l = l * alpha + p.sum(axis=-1)
-        acc = acc * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        return mb, l, acc
-
-    m, l, acc = jax.lax.fori_loop(0, n_kv, body, (m0, l0, acc0))
-    o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
+_VMEM_HEADROOM = 8 * 1024 * 1024   # Mosaic's own scratch and spills
 
 
 def _round_up(n: int, mult: int) -> int:
     return -(-n // mult) * mult
+
+
+def _tile(n: int, cap: int) -> int:
+    """The tile for a sequence of n rows: of the multiples of 128 up to
+    the cap, the one that pads n least; of equals, the largest."""
+    cap = max(_LANES, min(_MAX_TILE, cap) // _LANES * _LANES)
+    return min(range(cap, 0, -_LANES), key=lambda t: _round_up(n, t))
+
+
+def _tiles(sq: int, kv_len: int, d: int) -> tuple[int, int]:
+    """(block_q, block_k) from the call's static shape: the K tile as
+    long as the keys allow, the Q tile from what the two float32 work
+    arrays then leave."""
+    block_k = _tile(kv_len, _MAX_TILE)
+    rows = _WORK_BYTES // (4 * max(block_k, _round_up(d, _LANES)))
+    return _tile(sq, rows), block_k
+
+
+def _vmem_bytes(block_q: int, block_k: int, kv_p: int, d_p: int,
+                itemsize: int) -> int:
+    """What one program of the grid holds in VMEM, lanes padded to 128:
+    the whole K and V sequence and the Q and O blocks, two buffers each
+    (Pallas pipelines them); per K block in flight the scores, the
+    probabilities and the probabilities in v's type; the accumulator and
+    the product added to it."""
+    lanes = _round_up(d_p, _LANES)
+    resident = 2 * 2 * kv_p * lanes * itemsize
+    blocks = 2 * 2 * block_q * lanes * itemsize
+    work = _UNROLL * 3 * block_q * block_k * 4 + 2 * block_q * lanes * 4
+    return resident + blocks + work
+
+
+def _kernel(q_ref, k_ref, v_ref, o_ref, *, kv_len: int, scale: float,
+            block_k: int):
+    q = q_ref[0]                                   # [block_q, D], as handed
+    # whole K blocks hold no padded key; `ragged` real keys are left over
+    n_whole, ragged = divmod(kv_len, block_k)
+
+    def step(start, carry, valid=block_k):
+        m, l, acc = carry
+        k = k_ref[0, pl.ds(start, block_k), :]     # [block_k, D]
+        v = v_ref[0, pl.ds(start, block_k), :]
+        s = jax.lax.dot_general(
+            q, k, _NT, preferred_element_type=jnp.float32) * scale
+        if valid < block_k:                        # static: padded keys here
+            kpos = jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+            s = jnp.where(kpos < valid, s, NEG_INF)
+        mb = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - mb)
+        alpha = jnp.exp(m - mb)
+        l = l * alpha + p.sum(axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.dot(p.astype(v.dtype), v,
+                                    preferred_element_type=jnp.float32)
+        return mb, l, acc
+
+    def trip(j, carry):
+        for u in range(_UNROLL):
+            carry = step(
+                pl.multiple_of((j * _UNROLL + u) * block_k, block_k), carry)
+        return carry
+
+    block_q, d = q.shape
+    carry = (jnp.full((block_q, 1), NEG_INF, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32),
+             jnp.zeros((block_q, d), jnp.float32))
+    # a loop only where it has two trips or more; what it leaves, and the
+    # block with padded keys, at static offsets after it
+    trips = n_whole // _UNROLL if n_whole >= 2 * _UNROLL else 0
+    if trips:
+        carry = jax.lax.fori_loop(0, trips, trip, carry)
+    for j in range(trips * _UNROLL, n_whole):
+        carry = step(j * block_k, carry)
+    if ragged:
+        carry = step(n_whole * block_k, carry, ragged)
+    _, l, acc = carry
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 def _pad_to(x, axis, mult):
@@ -96,33 +177,36 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     but the jnp.pad round-trips through HBM (a 3.2× inflation of Q/K/V
     traffic at D=40) disappear. MXU pass count is the same either way
     (contraction/lane dims ≤128 occupy one pass regardless), so this
-    targets HBM bandwidth, not FLOPs — measured per-impl by
-    tools/tpu_profile.py before it becomes the default."""
+    targets HBM bandwidth, not FLOPs. On the chip the two read the same
+    to within a run's noise, pad, kernel and slice together: 9.75 and
+    9.70 ms at (32, 9216, 9216, 40), 1.35 and 1.33 ms at (32, 2304, 2304,
+    80) (PERF.md section 6, PR 26) — the calls are MXU-bound."""
     b, h, sq, d = q.shape
     kv_len = k.shape[2]
     scale = 1.0 / np.sqrt(d)
 
-    d_mult = 128 if pad_d else 1
-    qf = _pad_to(_pad_to(q.reshape(b * h, sq, d), 1, BLOCK_Q), 2, d_mult)
-    kf = _pad_to(_pad_to(k.reshape(b * h, kv_len, d), 1, BLOCK_K), 2, d_mult)
-    vf = _pad_to(_pad_to(v.reshape(b * h, kv_len, d), 1, BLOCK_K), 2, d_mult)
+    d_mult = _LANES if pad_d else 1
+    block_q, block_k = _tiles(sq, kv_len, d)
+    qf = _pad_to(_pad_to(q.reshape(b * h, sq, d), 1, block_q), 2, d_mult)
+    kf = _pad_to(_pad_to(k.reshape(b * h, kv_len, d), 1, block_k), 2, d_mult)
+    vf = _pad_to(_pad_to(v.reshape(b * h, kv_len, d), 1, block_k), 2, d_mult)
     bh, sq_p, d_p = qf.shape
     kv_p = kf.shape[1]
 
     out = pl.pallas_call(
-        functools.partial(_kernel, kv_len=kv_len, scale=scale),
-        grid=(bh, sq_p // BLOCK_Q),
+        functools.partial(_kernel, kv_len=kv_len, scale=scale,
+                          block_k=block_k),
+        grid=(bh, sq_p // block_q),
         in_specs=[
-            pl.BlockSpec((1, BLOCK_Q, d_p), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, d_p), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, kv_p, d_p), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, kv_p, d_p), lambda i, j: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, BLOCK_Q, d_p), lambda i, j: (i, j, 0)),
+        out_specs=pl.BlockSpec((1, block_q, d_p), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq_p, d_p), q.dtype),
-        # K and V blocks, two buffers each, lane-padded to 128 in VMEM
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=max(
             _VMEM_DEFAULT,
-            2 * 2 * kv_p * _round_up(d_p, 128) * kf.dtype.itemsize
+            _vmem_bytes(block_q, block_k, kv_p, d_p, kf.dtype.itemsize)
             + _VMEM_HEADROOM)),
         interpret=interpret,
     )(qf, kf, vf)
