@@ -2,10 +2,16 @@
 configuration file's `arch`, and the plain reference beside it."""
 from __future__ import annotations
 
-from perfbench.reference import kandinsky2 as reference  # noqa: F401
+import functools
+
+from perfbench.families import _image
+from perfbench.reference import kandinsky2 as reference
 
 TEMPLATE = "kandinsky2"
 OUT_NAME = "out-1.png"
+COMPARED = _image.COMPARED
+decode = _image.decode
+compare = functools.partial(_image.compare, reference)
 
 
 def build(arch: dict, precision: str):

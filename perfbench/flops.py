@@ -26,7 +26,9 @@ def _abstract(params_shapes):
 def count_parts(reference, arch: dict, task: dict, params_shapes,
                 batch: int = 1) -> dict:
     """{part: {"flops": per call, "calls": per solution,
-               "attn_calls": [(b,h,sq,sk,d)]}} for `batch` tasks at once."""
+               "attn_calls": [(b,h,sq,sk,d)],
+               "masked_attn_calls": [(b,h,sq,sk,d,pairs)],
+               "other": {kind: flops}}} for `batch` tasks at once."""
     fns = reference.parts(arch)
     p = _abstract(params_shapes)
     out = {}
@@ -35,7 +37,9 @@ def count_parts(reference, arch: dict, task: dict, params_shapes,
             jax.eval_shape(fns[part], p, *args)
         out[part] = {"flops": c.total, "calls": calls, "dense": c.dense,
                      "conv": c.conv, "attn": c.attn,
-                     "attn_calls": list(c.attn_calls)}
+                     "attn_calls": list(c.attn_calls),
+                     "masked_attn_calls": list(c.masked_attn_calls),
+                     "other": dict(c.other)}
     return out
 
 
@@ -48,10 +52,13 @@ def solution_flops(reference, arch: dict, task: dict, params_shapes) -> float:
 
 
 def attention_floor_seconds(b, h, sq, sk, d, peaks: dict,
-                            itemsize: int = 2) -> tuple[float, str]:
+                            itemsize: int = 2,
+                            pairs: int | None = None) -> tuple[float, str]:
     """The least time the chip could take for exact attention at these
-    shapes, and which bound binds."""
-    t_flops = ops.attention_flops(b, h, sq, sk, d) / peaks["bf16_flops"]
+    shapes (over the `pairs` a mask leaves, where stated), and which
+    bound binds."""
+    t_flops = ops.attention_flops(b, h, sq, sk, d, pairs) \
+        / peaks["bf16_flops"]
     t_bytes = ops.attention_bytes(b, h, sq, sk, d, itemsize) \
         / peaks["hbm_bytes_per_s"]
     return (t_flops, "flops") if t_flops >= t_bytes else (t_bytes, "bytes")

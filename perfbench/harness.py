@@ -55,7 +55,7 @@ def parse(argv):
                          "tiny rehearsal)")
     ap.add_argument("--control", default=None, choices=("fp8",),
                     help="the control that has to come out as not correct: "
-                         "in the served image's place, the reference "
+                         "in the served answer's place, the reference "
                          "computed in fp8")
     return ap.parse_args(argv)
 
@@ -155,7 +155,7 @@ def run_cell(args, t_start: float) -> tuple[int, dict | None]:
     run.cell = cell
     run.peaks = peaks.peaks_for(device["kind"]) if on_chip else None
     sysm = system.System(cell.config, args.seed, note=note,
-                         config_dir=cell.config_dir)
+                         config_dir=cell.config_dir, family=cell.family)
     run.system = sysm
     trace_dir = None
     try:
@@ -212,7 +212,7 @@ def run_cell(args, t_start: float) -> tuple[int, dict | None]:
         run.seconds = t_end - win["t0"]
 
         failed_jobs = sysm.failed_jobs()
-        bad, images = correct.chain_checks(sysm, solved, system.MINER)
+        bad, served = correct.chain_checks(sysm, solved, system.MINER)
         bad += len(win["unsolved"]) + len(failed_jobs)
         per_model = dict(cell.traffic["check"]["buckets"])
         chosen = correct.sample(win["tasks"], per_model,
@@ -222,9 +222,13 @@ def run_cell(args, t_start: float) -> tuple[int, dict | None]:
             # model FLOPs per solution and per bucket, from shapes (nothing
             # is computed); only the traced run's readers want them
             for m in sysm.models:
-                if m.template not in cell.traffic["tasks"]:
+                # at the shapes of the window's first task of the model
+                # (a mix keeps each model's tasks to one shape)
+                first = next((t["input"] for t in win["tasks"]
+                              if t["model"] == m.template), None)
+                if first is None:
                     continue
-                task = m.hydrated(cell.traffic["tasks"][m.template]["input"])
+                task = m.hydrated(first)
                 run.parts[m.template] = {
                     b: flops.count_parts(m.family.reference, m.arch, task,
                                          m.params, batch=b)
@@ -261,22 +265,28 @@ def run_cell(args, t_start: float) -> tuple[int, dict | None]:
         sysm.free_program()
         compared = {"chain_mismatch": {"value": bad, "limit": 0}}
         t_ref = time.perf_counter()
-        worst: dict[str, float] = {}
+        worst: dict[str, dict] = {}
         for rec in chosen:
-            stats = {"mean": 255.0}  # nothing decodable was served
-            if rec["taskid"] in images:
-                stats = correct.image_stats(sysm, rec, images[rec["taskid"]],
-                                            control=args.control)
-            mad = stats["mean"]
-            worst[rec["model"]] = max(worst.get(rec["model"], 0.0), mad)
+            if rec["taskid"] not in served:
+                continue  # nothing decodable: a chain_mismatch already
+            m = sysm.model(rec["model"])
+            stats = m.family.compare(m, rec, served[rec["taskid"]],
+                                     control=args.control)
+            mine = worst.setdefault(rec["model"], {})
+            for name in m.family.COMPARED:
+                value = stats[name]["value"]
+                mine[name] = max(mine.get(name, value), value)
             note(f"reference: {rec['model']} task "
-                 f"0x{rec['taskid'].hex()[:8]} image_mad {mad:.4f} "
-                 f"({time.perf_counter() - t_ref:.0f}s in) "
+                 f"0x{rec['taskid'].hex()[:8]} "
+                 + " ".join(f"{n} {v['value']:.4f}"
+                            for n, v in stats.items())
+                 + f" ({time.perf_counter() - t_ref:.0f}s in) "
                  f"{json.dumps(stats)}")
-        for model, value in sorted(worst.items()):
-            compared[f"image_mad.{model}"] = {
-                "value": value,
-                "limit": sysm.model(model).entry["limits"]["image_mad"]}
+        for model, mine in sorted(worst.items()):
+            limits = sysm.model(model).entry["limits"]
+            for name, value in mine.items():
+                compared[f"{name}.{model}"] = {"value": value,
+                                               "limit": limits[name]}
         missing = [m for m in per_model if m not in worst and per_model[m]]
         ok = not missing and all(
             c["value"] <= c["limit"] for c in compared.values())

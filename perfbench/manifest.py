@@ -1,16 +1,40 @@
 """Finding a cell's files by the names in the manifest (`BENCHMARK.json`).
 
-Nothing here knows a cell, a configuration or a metric by name: a later
-PR adds entries to the manifest and files beside the ones that are there
-(`configs/<config>.json`, `traffic/<traffic>.json`, `metrics/<metric>.py`,
-`families/<family>.py`) and edits no file.
+Nothing here knows a cell, a configuration, a metric or a family by name:
+a later PR adds entries to the manifest and files beside the ones that
+are there (`configs/<config>.json`, `traffic/<traffic>.json`,
+`metrics/<metric>.py`, `families/<family>.py`), each under any of the
+manifest's `paths`, and edits no file.
+
+What a family file defines (a configuration's model names its family):
+
+    TEMPLATE      the program's template the family serves
+    OUT_NAME      the solution's file name, as the template states it
+    COMPARED      the names of the numbers `compare` gives, each the
+                  worse the larger; a model's `limits` in the
+                  configuration file holds exactly these
+    build(arch, precision) -> (the program's pipeline, its runner class)
+    reference     the plain reference: `parts(arch)` and
+                  `forward_shapes(arch, task, batch)` for the FLOP count
+                  (perfbench/flops.py), and whatever `compare` calls
+    decode(data, hydrated) -> what was served, from the pinned bytes of a
+                  task with these hydrated fields; raises where the bytes
+                  are no such answer (perfbench/correct.py)
+    compare(model, rec, served, control=None) ->
+                  {name: {"value": x, ...}}: what was served for the
+                  task `rec` against the reference on `model.params`;
+                  with `control`, the reference in that lower precision
+                  stands in the served answer's place
+    kernel_calls(attn_calls) -> those of the reference's attention calls
+                  that the program serves with its kernel
 """
 from __future__ import annotations
 
-import importlib
+import functools
 import importlib.util
 import json
 import os
+import re
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -73,15 +97,30 @@ class Cell:
 
     def reader(self, metric_name: str):
         """The metric's reader: `metrics/<name>.py` with `read(run)`."""
-        path = self._find("metrics", metric_name, ".py")
-        spec = importlib.util.spec_from_file_location(
-            "perfbench_metric_" + metric_name.replace(".", "_")
-            .replace("-", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load_py(self._find("metrics", metric_name, ".py")).read
+
+    def family(self, name: str):
+        """The model family, `families/<name>.py`, found as a metric is."""
+        return load_py(self._find("families", name, ".py"))
+
+
+@functools.lru_cache(maxsize=None)
+def _load_real(path: str):
+    stem = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(
+        "perfbench_file_" + re.sub(r"\W", "_", stem), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_py(path: str):
+    """The module in the file at `path`, once a process however the path
+    is spelt (a family keeps its jitted reference)."""
+    return _load_real(os.path.realpath(path))
 
 
 def family(name: str):
-    """The model family's builder and reference: `families/<name>.py`."""
-    return importlib.import_module(f"perfbench.families.{name}")
+    """A family of the benchmark's own directory, for callers with no
+    cell at hand; a run goes through `Cell.family`."""
+    return load_py(os.path.join(HERE, "families", name + ".py"))

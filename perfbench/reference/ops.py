@@ -8,7 +8,8 @@ matrix never has to exist at once.
 
 The FLOP count is of this algorithm, not of the program under test: each
 of `dense`, `conv` and `attend` adds its multiply-adds (×2) to the active
-`FlopCount` when one is open. Running a reference forward under
+`FlopCount` when one is open, and `count(kind, flops)` adds work that is
+none of the three. Running a reference forward under
 `jax.eval_shape` inside `count_flops()` therefore counts one forward from
 shapes alone, without computing anything. Elementwise work (norms,
 activations, softmax) is not counted, as is usual for model FLOP/s.
@@ -30,17 +31,21 @@ _WEIGHTS: list = []   # the control's weight precision while a trace is open
 
 
 class FlopCount:
-    """Matmul FLOPs by kind, and every attention call's shape."""
+    """Matmul FLOPs by kind, and every attention call's shape: whole
+    calls as (b, h, sq, sk, d), and apart from them the calls whose mask
+    leaves `pairs` of the sq·sk (query, key) pairs, with that count."""
 
     def __init__(self):
         self.dense = 0.0
         self.conv = 0.0
         self.attn = 0.0
         self.attn_calls: list[tuple[int, int, int, int, int]] = []
+        self.masked_attn_calls: list[tuple[int, int, int, int, int, int]] = []
+        self.other: dict[str, float] = {}
 
     @property
     def total(self) -> float:
-        return self.dense + self.conv + self.attn
+        return self.dense + self.conv + self.attn + sum(self.other.values())
 
 
 @contextlib.contextmanager
@@ -53,6 +58,13 @@ def count_flops():
         _COUNT.pop()
 
 
+def count(kind: str, flops: float) -> None:
+    """Work that is none of dense, conv or attend, under a name of its
+    own (routed experts: only the tokens sent to the experts held here)."""
+    if _COUNT:
+        _COUNT[-1].other[kind] = _COUNT[-1].other.get(kind, 0.0) + flops
+
+
 def dense_flops(rows: int, k: int, n: int) -> float:
     return 2.0 * rows * k * n
 
@@ -62,9 +74,11 @@ def conv_flops(batch: int, out_h: int, out_w: int, kh: int, kw: int,
     return 2.0 * batch * out_h * out_w * kh * kw * cin * cout
 
 
-def attention_flops(b: int, h: int, sq: int, sk: int, d: int) -> float:
-    """QK^T and PV of exact attention on [B,H,S,D]: 2 matmuls."""
-    return 4.0 * b * h * sq * sk * d
+def attention_flops(b: int, h: int, sq: int, sk: int, d: int,
+                    pairs: int | None = None) -> float:
+    """QK^T and PV of exact attention on [B,H,S,D]: 2 matmuls, over the
+    `pairs` (query, key) pairs a mask leaves of each head's sq·sk."""
+    return 4.0 * b * h * (sq * sk if pairs is None else pairs) * d
 
 
 def attention_bytes(b: int, h: int, sq: int, sk: int, d: int,
@@ -203,14 +217,19 @@ def sinusoidal(t, dim: int, max_period: float = 10000.0):
     return emb
 
 
-def attend(q, k, v, mask=None):
+def attend(q, k, v, mask=None, pairs=None):
     """Exact softmax attention on [B,H,S,D]; `mask` is additive and
-    broadcastable to [B,H,Sq,Sk]."""
+    broadcastable to [B,H,Sq,Sk]. `pairs`: how many of a head's Sq·Sk
+    (query, key) pairs the mask leaves (causal: S(S+1)/2), where the
+    caller wants only those counted; the result is the same."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     if _COUNT:
-        _COUNT[-1].attn += attention_flops(b, h, sq, sk, d)
-        _COUNT[-1].attn_calls.append((b, h, sq, sk, d))
+        _COUNT[-1].attn += attention_flops(b, h, sq, sk, d, pairs)
+        if pairs is None:
+            _COUNT[-1].attn_calls.append((b, h, sq, sk, d))
+        else:
+            _COUNT[-1].masked_attn_calls.append((b, h, sq, sk, d, pairs))
     scale = 1.0 / np.sqrt(d)
 
     def rows(qb):

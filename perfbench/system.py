@@ -25,10 +25,16 @@ USER = "0x" + "01" * 20
 class Model:
     """One registered model: its family, files and weights."""
 
-    def __init__(self, entry: dict):
+    def __init__(self, entry: dict, family=manifest.family):
         self.entry = entry
         self.template = entry["template"]
-        self.family = manifest.family(entry["family"])
+        self.family = family(entry["family"])
+        if set(entry["limits"]) != set(self.family.COMPARED):
+            raise manifest.ManifestError(
+                f"model {self.template!r}: its limits are for "
+                f"{sorted(entry['limits'])}, its family "
+                f"{entry['family']!r} compares "
+                f"{sorted(self.family.COMPARED)}")
         self.arch = entry["arch"]
         self.defaults = entry["defaults"]
         self.id_bytes: bytes = b""
@@ -43,12 +49,13 @@ class Model:
 
 class System:
     def __init__(self, config: dict, seed: int, *, note=lambda m: None,
-                 config_dir: str = "."):
+                 config_dir: str = ".", family=manifest.family):
+        """`family` finds a family's file by its name (`Cell.family`)."""
         self.config = config
         self.config_dir = config_dir
         self.seed = seed
         self.note = note
-        self.models = [Model(m) for m in config["models"]]
+        self.models = [Model(m, family) for m in config["models"]]
         self.workdir = tempfile.mkdtemp(prefix="perfbench-")
         self.cache_events = {"hits": 0, "misses": 0}
         self.timings: dict[str, float] = {}

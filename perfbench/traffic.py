@@ -11,13 +11,18 @@ A traffic file states (all keys required unless marked):
                  set of work in another order
     tasks        {<template>: {"input": {field: value}, "prompt_words":
                  [lo, hi]}}: the fields a task of that model submits, and
-                 how many words its distinct, seeded prompt has
+                 how many words its distinct, seeded prompt has. In place
+                 of `prompt_words` (exactly one of the two), `prompt_bytes`
+                 [lo, hi] states the length in the unit a byte-tokenised
+                 model works in: the same word stream, cut to a length in
+                 bytes drawn uniformly from the range (lo at least
+                 MIN_PROMPT_BYTES, so the task's index is never cut)
     check        {"buckets": {<template>: n}}: how many whole buckets of
                  each model's finished tasks, every slot of each, are
                  compared with the plain reference (perfbench/correct.py)
 
 Nothing in here names a cell. Prompts are distinct within a run (the
-task's index is in them), so no two tasks share a task id or an image.
+task's index is in them), so no two tasks share a task id or an answer.
 """
 from __future__ import annotations
 
@@ -28,6 +33,7 @@ WORDS = ("amber basalt cedar delta ember fjord glacier harbor iris jade "
          "kelp lantern meadow nebula orchid prairie quartz raven saffron "
          "tundra umbra violet willow xenon yarrow zephyr miner chip tensor "
          "lattice beacon orbit").split()
+MIN_PROMPT_BYTES = 16
 
 
 class Traffic:
@@ -38,6 +44,14 @@ class Traffic:
         self.spec = spec
         self.outstanding = int(spec["outstanding"])
         self.cycle = [(c["model"], int(c["count"])) for c in spec["cycle"]]
+        for model, t in spec["tasks"].items():
+            if ("prompt_words" in t) == ("prompt_bytes" in t):
+                raise ValueError(f"traffic tasks.{model}: exactly one of "
+                                 "prompt_words and prompt_bytes")
+            if "prompt_bytes" in t \
+                    and t["prompt_bytes"][0] < MIN_PROMPT_BYTES:
+                raise ValueError(f"traffic tasks.{model}: prompt_bytes "
+                                 f"starts under {MIN_PROMPT_BYTES}")
         self._rng = random.Random(f"perfbench-traffic-{seed}")
         self._index = 0
         self._queue: list[str] = []
@@ -58,11 +72,19 @@ class Traffic:
         if model is None:
             model = self._next_model()
         t = self.spec["tasks"][model]
-        lo, hi = t["prompt_words"]
-        n = self._rng.randint(lo, hi)
-        words = " ".join(self._rng.choice(WORDS) for _ in range(n))
-        self._index += 1
-        return model, {**t["input"], "prompt": f"{tag}{self._index} {words}"}
+        if "prompt_words" in t:
+            n = self._rng.randint(*t["prompt_words"])
+            words = " ".join(self._rng.choice(WORDS) for _ in range(n))
+            self._index += 1
+            prompt = f"{tag}{self._index} {words}"
+        else:
+            n = self._rng.randint(*t["prompt_bytes"])
+            self._index += 1
+            prompt = f"{tag}{self._index}"
+            while len(prompt) < n:      # WORDS are ASCII: a byte a letter
+                prompt += " " + self._rng.choice(WORDS)
+            prompt = prompt[:n]
+        return model, {**t["input"], "prompt": prompt}
 
 
 def encode(task_input: dict) -> bytes:

@@ -1,4 +1,5 @@
-"""The flash kernel at the cells' shapes, compiled for a described v5e
+"""The flash kernel at the cells' shapes, and the weight draw of a stacked
+expert layer, compiled for a described v5e
 chip (no chip attached): what the TPU's compiler refuses here would cost
 chip time there. One file, topology inside a fixture
 (`on-chip-measurement` section 2)."""
@@ -63,3 +64,34 @@ def test_flash_kernel_compiles_at_the_cells_shapes(one_chip,
     compiled = lowered.compile()
     assert compiled.memory_analysis().output_size_in_bytes \
         == b * h * sq * d * 2
+
+
+def test_a_stacked_expert_layers_draw_fits_one_chip(one_chip,
+                                                    no_persistent_cache):
+    """Twelve kernels of [32 experts held, 3072, 3072] share a shape and a
+    rule: as one float32 draw they are 14.5 GB and the chip's compiler
+    refuses the program; under the ceiling they are drawn leaf by leaf
+    beside their 7.2 GB in bfloat16."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench import weights
+
+    sds = jax.ShapeDtypeStruct
+    shapes = {f"layer_{i}": {n: {"kernel": sds((32, 3072, 3072),
+                                               jnp.bfloat16)}
+                             for n in ("gate", "up", "down")}
+              for i in range(4)}
+    rules = [{"match": "/kernel$", "dist": "fan_in", "fan_in_axes": [1]}]
+    (group, idx), = weights.plan(shapes, rules)[0]
+    assert len(idx) * 32 * 3072 * 3072 > 6 * weights.CEILING
+    assert abs(group[4] - 3072 ** -0.5) < 1e-9       # fan-in d, not E*d
+    key = jax.random.fold_in(jax.random.key(np.uint32(5), impl="rbg"),
+                             np.uint32(0))
+    key = sds(key.shape, key.dtype, sharding=one_chip)
+    compiled = jax.jit(weights.builder(shapes, rules),
+                       out_shardings=one_chip).lower(key).compile()
+    mem = compiled.memory_analysis()
+    assert 0 <= mem.output_size_in_bytes - 12 * 32 * 3072 * 3072 * 2 < 4096
+    assert mem.temp_size_in_bytes < 4 * 2 ** 30

@@ -48,6 +48,77 @@ def test_one_attention_by_hand():
     assert ops.dense_flops(77, 768, 3072) == 2 * 77 * 768 * 3072
 
 
+def test_a_masked_attention_counts_the_pairs_its_mask_leaves():
+    """The door for a causal or windowed reference: `pairs` of a head's
+    Sq·Sk (query, key) pairs are counted, such calls are recorded apart
+    from the whole ones the families' `kernel_calls` index, and work that
+    is none of dense, conv or attend is counted under a name of its own."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench import flops, peaks
+    from perfbench.reference import ops
+
+    s, d = 1024, 64
+    q = jax.ShapeDtypeStruct((2, 4, s, d), jnp.float32)
+    causal = jnp.where(jnp.tril(jnp.ones((s, s), bool)), 0.0, -jnp.inf)
+    with ops.count_flops() as whole:
+        jax.eval_shape(lambda a: ops.attend(a, a, a, mask=causal), q)
+    with ops.count_flops() as c:
+        jax.eval_shape(lambda a: ops.attend(a, a, a, mask=causal,
+                                            pairs=s * s // 2), q)
+        jax.eval_shape(lambda a: ops.attend(a, a, a, mask=causal,
+                                            pairs=s * (s + 1) // 2), q)
+    assert whole.attn == 4 * 2 * 4 * s * s * d
+    assert whole.attn_calls == [(2, 4, s, s, d)]
+    assert whole.masked_attn_calls == []
+    assert c.attn == whole.attn / 2 + whole.attn * (s + 1) / (2 * s)
+    assert c.attn_calls == []
+    assert c.masked_attn_calls == [(2, 4, s, s, d, s * s // 2),
+                                   (2, 4, s, s, d, s * (s + 1) // 2)]
+    # the result is the masked attention either way
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 2, 8, 4), jnp.float32)
+    m = jnp.where(jnp.tril(jnp.ones((8, 8), bool)), 0.0, -jnp.inf)
+    assert np.array_equal(ops.attend(x, x, x, mask=m),
+                          ops.attend(x, x, x, mask=m, pairs=36))
+    with ops.count_flops() as c:
+        ops.count("experts", 3e9)
+        ops.count("experts", 1e9)
+        jax.eval_shape(lambda a: ops.attend(a, a, a), q)
+    assert c.other == {"experts": 4e9} and c.total == 4e9 + c.attn
+    ops.count("experts", 1.0)                 # no count open: no effect
+    pk = peaks.peaks_for("TPU v5 lite")
+    full, _ = flops.attention_floor_seconds(2, 4, s, s, d, pk)
+    half, bound = flops.attention_floor_seconds(2, 4, s, s, d, pk,
+                                                pairs=s * s // 2)
+    assert bound == "flops" and half == full / 2
+
+
+@pytest.mark.parametrize("config", ["kandinsky2", "anythingv3-kandinsky2"])
+def test_no_group_of_an_accepted_configuration_is_over_the_ceiling(config):
+    """So both trees are drawn group by group, exactly as before there was
+    a ceiling (and `rules` that name no `fan_in_axes`, as before)."""
+    import math
+
+    import jax
+
+    from perfbench import manifest, weights
+
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           config + ".json")) as f:
+        cfg = json.load(f)
+    assert not any("fan_in_axes" in r for r in cfg["weights"]["init"])
+    for m in cfg["models"]:
+        pipe, _ = manifest.family(m["family"]).build(m["arch"], "bf16")
+        shapes = jax.eval_shape(
+            lambda: pipe.init_params(seed=0, dtype="bfloat16"))
+        draws = [len(idx) * math.prod(g[0]) for g, idx
+                 in weights.plan(shapes, cfg["weights"]["init"])[0]]
+        assert sum(draws) == weights.count(shapes)
+        assert 2 ** 26 < max(draws) <= weights.CEILING, max(draws)
+
+
 def test_attention_in_row_blocks_is_exact_attention():
     import jax
     import jax.numpy as jnp

@@ -1,5 +1,6 @@
 """The harness end to end at the tiny presets on the CPU, as the driver
-calls it: both configurations, both traffic files, a well-formed last
+calls it: every configuration and traffic file of the tests' manifest (two
+whose solutions are pictures, one whose solution is text), a well-formed last
 line, no device metric off the chip, and a non-zero exit with no result
 at full size without a TPU or without the program."""
 from __future__ import annotations
@@ -26,7 +27,8 @@ def _run(args, cwd=ROOT, run_py=None):
 @pytest.fixture(scope="module")
 def lines():
     out = {}
-    for cell, trace in (("tiny-k2-backlog", 0), ("tiny-mix-backlog", 1)):
+    for cell, trace in (("tiny-k2-backlog", 0), ("tiny-mix-backlog", 1),
+                        ("tiny-text-backlog", 1)):
         p = _run(["--manifest", TINY_MANIFEST, "--workload", cell, "--seed",
                   "2147483999", "--seconds", "1", "--trace", str(trace)])
         assert p.returncode == 0, p.stderr[-3000:]
@@ -34,7 +36,12 @@ def lines():
     return out
 
 
-@pytest.mark.parametrize("cell", ["tiny-k2-backlog", "tiny-mix-backlog"])
+CELLS = {"tiny-k2-backlog": {"image_mad.kandinsky2"},
+         "tiny-mix-backlog": {"image_mad.kandinsky2", "image_mad.anythingv3"},
+         "tiny-text-backlog": {"logit_gap.textgen"}}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
 def test_last_line_is_well_formed(lines, cell):
     line, _ = lines[cell]
     for key in ("correct", "attempted", "failed", "metrics", "device"):
@@ -46,15 +53,14 @@ def test_last_line_is_well_formed(lines, cell):
     assert line["attempted"] == line["solved"] > 0 and line["failed"] == 0
 
 
-@pytest.mark.parametrize("cell", ["tiny-k2-backlog", "tiny-mix-backlog"])
+@pytest.mark.parametrize("cell", sorted(CELLS))
 def test_tiny_cells_come_out_correct(lines, cell):
     line, err = lines[cell]
     assert line["correct"] is True, err[-2000:]
     assert line["compared"]["chain_mismatch"] == {"value": 0, "limit": 0}
-    models = {"tiny-k2-backlog": {"kandinsky2"},
-              "tiny-mix-backlog": {"kandinsky2", "anythingv3"}}[cell]
-    assert {k.split(".", 1)[1] for k in line["compared"]
-            if k.startswith("image_mad.")} == models
+    # what each model's family compares, and nothing the harness adds
+    assert set(line["compared"]) == CELLS[cell] | {"chain_mismatch"}
+    assert all(c["value"] <= c["limit"] for c in line["compared"].values())
     # every number compared is on the last lines of stderr beside its limit
     tail = err.strip().splitlines()[-len(line["compared"]):]
     assert all(t.startswith("compared ") and "limit" in t for t in tail)
@@ -66,9 +72,11 @@ def test_no_device_metric_is_printed_off_the_chip(lines):
     counts = {m["name"] for m in manifest["per_layer"]
               if m["source"] == "program_counter"}
     assert lines["tiny-k2-backlog"][0]["metrics"] == {}      # --trace 0
+    for cell in ("tiny-mix-backlog", "tiny-text-backlog"):
+        traced = lines[cell][0]
+        assert set(traced["metrics"]) == counts
+        assert "busy_s" not in traced["device"] and "breakdown" not in traced
     traced = lines["tiny-mix-backlog"][0]
-    assert set(traced["metrics"]) <= counts
-    assert "busy_s" not in traced["device"] and "breakdown" not in traced
     # the mix's under-filled buckets show in the one count there is
     assert traced["metrics"]["padded_slot_pct"]["value"] == 0.0 \
         or traced["metrics"]["padded_slot_pct"]["value"] > 0

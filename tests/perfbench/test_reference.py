@@ -10,10 +10,10 @@ import os
 import numpy as np
 import pytest
 
-from pb_paths import ROOT
+from pb_paths import ROOT, TINY_MANIFEST
 
-TINY = os.path.join(ROOT, "tests", "perfbench", "tiny", "configs",
-                    "tiny-anythingv3-kandinsky2.json")
+TINY_DIR = os.path.join(ROOT, "tests", "perfbench", "tiny")
+TINY = os.path.join(TINY_DIR, "configs", "tiny-anythingv3-kandinsky2.json")
 
 
 def _f32(arch):
@@ -61,6 +61,146 @@ def test_reference_agrees_with_the_pipeline_in_float32(family, steps):
     # and another seed is another picture
     other = fam.reference.image(params, m["arch"], task, seeds[0] + 1)
     assert np.abs(other.astype(int) - ref.astype(int)).mean() > 5
+
+
+def test_text_reference_agrees_with_the_pipeline_in_float32():
+    """The full forward pass, no cache, against the program's prefill and
+    cached decode loop: every id the float32 program serves is the
+    reference's first choice, and another prompt's ids are not."""
+    import jax
+
+    from perfbench import manifest, system, weights
+
+    with open(os.path.join(TINY_DIR, "configs", "tiny-textgen.json")) as f:
+        cfg = json.load(f)
+    entry = cfg["models"][0]
+    cell = manifest.Cell(TINY_MANIFEST, "tiny-text-backlog")
+    model = system.Model(entry, cell.family)
+    arch = copy.deepcopy(entry["arch"])
+    arch["model"]["dtype"] = "float32"
+    pipe, _ = model.family.build(arch, "bf16")
+    shapes = jax.eval_shape(lambda: pipe.init_params(seed=0))
+    model.params = weights.make(shapes, 2**31 + 23, cfg["weights"]["init"])
+    prompts = ["a miner asks the chip for a line of text, please",
+               "zephyr yarrow xenon willow violet umbra tundra sa"]
+    got = pipe.generate(model.params, prompts, [11, 2**40 + 5],
+                        prompt_bucket=64, decode_bucket=32)
+    assert got.shape == (2, 32) and got.max() < 256
+    assert not np.array_equal(got[0], got[1])
+    recs = [{"input": {"prompt": p, "max_new_tokens": 32}} for p in prompts]
+    for rec, ids in zip(recs, got):
+        out = model.family.compare(model, rec, ids)["logit_gap"]
+        assert out["value"] == 0.0 and out["positions"] == 32
+        assert 0.5 < out["spread"] < 2
+    crossed = model.family.compare(model, recs[0], got[1])["logit_gap"]
+    assert crossed["value"] > 3 * entry["limits"]["logit_gap"]
+    assert crossed["not_first"] >= 2
+    # the text inverts to the ids, a byte each
+    text = bytes(int(t) for t in got[0])
+    assert np.array_equal(
+        model.family.decode(text, {"max_new_tokens": 32}), got[0])
+    with pytest.raises(ValueError):
+        model.family.decode(text[:-1], {"max_new_tokens": 32})
+
+
+# the trees of the tests' configurations at seed 2147484001, model by model,
+# as the parent of PR 27 drew them (sha256 over each leaf's path, dtype and
+# bytes): the draw of a group under the ceiling is what it was
+DIGESTS = {
+    ("tiny-kandinsky2", "kandinsky2"):
+        "d719810ec9081d87c42e542d788f113be4e2cc3a0305e179250ed66d9ef21f02",
+    ("tiny-anythingv3-kandinsky2", "anythingv3"):
+        "231fb7d2ccc24986a065ec47837328a4129d2c3c538de90c40e10c9ae35f47e7",
+    ("tiny-anythingv3-kandinsky2", "kandinsky2"):
+        "c509b0eb51c50daf28a114c7e9daad2d91aa74c2259880e4009edce5902c9d17",
+}
+
+
+@pytest.mark.parametrize("config,template", sorted(DIGESTS))
+def test_trees_under_the_ceiling_are_the_parents(config, template):
+    import hashlib
+
+    import jax
+
+    from perfbench import manifest, weights
+
+    with open(os.path.join(TINY_DIR, "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    i, m = next((i, m) for i, m in enumerate(cfg["models"])
+                if m["template"] == template)
+    pipe, _ = manifest.family(m["family"]).build(m["arch"], "bf16")
+    dtype = cfg["weights"]["dtype"]
+    shapes = jax.eval_shape(lambda: pipe.init_params(seed=0, dtype=dtype))
+    params = weights.make(shapes, 2147484001 * 16 + i,
+                          cfg["weights"]["init"])
+    h = hashlib.sha256()
+    for keys, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        h.update(weights._path(keys).encode())
+        h.update(str(leaf.dtype).encode())
+        h.update(np.asarray(leaf).view(np.uint16).tobytes())
+    assert h.hexdigest() == DIGESTS[config, template]
+
+
+def test_a_group_over_the_ceiling_is_drawn_in_pieces():
+    """Leaf by leaf, and a single leaf over it in slices of its first axis:
+    no array of the group's size exists in the program; seeded; by the
+    rules."""
+    import jax
+    import jax.numpy as jnp
+
+    from perfbench import weights
+
+    sds = jax.ShapeDtypeStruct
+    shapes = {f"layer_{i}": {n: {"kernel": sds((4, 32, 48), jnp.bfloat16)}
+                             for n in ("gate", "up", "down")}
+              for i in range(2)}
+    shapes["head"] = {"kernel": sds((100, 64), jnp.bfloat16),
+                      "bias": sds((100,), jnp.bfloat16)}
+    rules = [{"match": "layer_.*/kernel$", "dist": "fan_in",
+              "fan_in_axes": [1]},
+             {"match": "/kernel$", "dist": "fan_in"},
+             {"match": "/bias$", "dist": "rows", "std": 0.001,
+              "mean": [float(i) for i in range(100)]}]
+    group = 6 * 4 * 32 * 48
+    ceiling = 2048          # under a stacked leaf (6144), over the bias
+
+    def largest(c):
+        key = jax.random.key(0, impl="rbg")
+        jaxpr = jax.make_jaxpr(weights.builder(shapes, rules, c))(key)
+        return max(v.aval.size for e in jaxpr.jaxpr.eqns
+                   for v in e.outvars if hasattr(v.aval, "size"))
+
+    assert largest(weights.CEILING) == group
+    assert largest(ceiling) == 6400 < group  # the head, whole once joined
+
+    def make(seed, c, shapes=shapes):
+        key = jax.random.fold_in(jax.random.key(seed, impl="rbg"), 0)
+        return jax.jit(weights.builder(shapes, rules, c))(key)
+
+    a, b, other = make(7, ceiling), make(7, ceiling), make(8, ceiling)
+    flat = jax.tree_util.tree_leaves
+    assert all(jnp.array_equal(x, y) for x, y in zip(flat(a), flat(b)))
+    assert not any(jnp.array_equal(x, y)
+                   for x, y in zip(flat(a), flat(other)))
+    kernels = [np.asarray(a[f"layer_{i}"][n]["kernel"], np.float32)
+               for i in range(2) for n in ("gate", "up", "down")]
+    assert all(k.shape == (4, 32, 48) and k.dtype == np.float32
+               for k in kernels)
+    assert len({k.tobytes() for k in kernels}) == 6     # no leaf twice
+    assert len({k[e].tobytes() for k in kernels for e in range(4)}) == 24
+    std = np.concatenate([k.ravel() for k in kernels]).std()
+    assert abs(std - 32 ** -0.5) < 0.01      # fan-in 32, not 4 x 32
+    head = np.asarray(a["head"]["kernel"], np.float32)
+    assert abs(head.std() - 0.1) < 0.01      # fan-in 100, as without axes
+    # sliced rows keep their own constants, under either ceiling
+    bias_only = {"head": {"bias": shapes["head"]["bias"]}}
+    for tree in (a, make(7, 30, bias_only)):
+        bias = np.asarray(tree["head"]["bias"], np.float32)
+        assert np.abs(bias - np.arange(100)).max() < 0.3
+    # and under the ceiling nothing changed: the group is one draw
+    whole = make(7, weights.CEILING)
+    assert not jnp.array_equal(whole["layer_0"]["gate"]["kernel"],
+                               a["layer_0"]["gate"]["kernel"])
 
 
 def test_weights_follow_the_seed_and_the_rules():
