@@ -70,6 +70,10 @@ class TextGenPipeline:
 
     BOS_ID = BOS_ID
     EOS_ID = EOS_ID
+    # the bucket tag's prefix; a family built on this pipeline (the
+    # bucket policy, decode loop, samplers and seed chain are shared —
+    # models/trinity) names its own
+    FAMILY = "textgen"
 
     def __init__(self, config: TextGenConfig | None = None, mesh=None,
                  precision: str = "bf16",
@@ -100,11 +104,37 @@ class TextGenPipeline:
         if not 1 <= self.top_k <= self.config.vocab_size:
             raise ValueError(
                 f"top_k ({self.top_k}) must be in [1, vocab_size]")
-        self.model = TextGenModel(self.config)
+        self.model = self._make_model()
         # per-instance executable cache (same rationale as sd15)
         self._buckets: dict[tuple, object] = {}
         self._coll_est: dict[tuple, dict] = {}
         self._tokenizers: dict[int, ByteTokenizer] = {}
+
+    # -- the model behind the loop (what a family overrides) ---------------
+    def _make_model(self):
+        return TextGenModel(self.config)
+
+    def _prefill(self, params, ids, total: int):
+        """(params, ids[B, P], total) → (logits[B, V] f32 at the last
+        prompt position, the decode loop's carry)."""
+        return self.model.apply({"params": params}, ids, total,
+                                method=TextGenModel.prefill)
+
+    def _decode(self, params, tok, carry, pos):
+        """One step at `pos` → (next-position logits, carry)."""
+        return self.model.apply({"params": params}, tok, carry, pos,
+                                method=TextGenModel.decode)
+
+    def _outputs(self, tokens, carry):
+        """What a bucket program returns, from the tokens [B, T] and
+        the loop's final carry: the tokens alone here."""
+        return tokens
+
+    def kv_rows(self, prompt_bucket: int, decode_bucket: int) -> tuple:
+        """(cache rows the carry holds for one sequence, summed over
+        layers; what it would hold with every layer at full length)."""
+        rows = self.config.layers * (prompt_bucket + decode_bucket)
+        return rows, rows
 
     # -- bucket policy ---------------------------------------------------
     def prompt_bucket_for(self, prompt: str) -> int:
@@ -200,7 +230,7 @@ class TextGenPipeline:
         modes suffix it exactly like the image families."""
         from arbius_tpu.quant import mode_tag
 
-        return "textgen." + ".".join(
+        return self.FAMILY + "." + ".".join(
             str(k) for k in (batch, prompt_bucket, decode_bucket,
                              sampler)) + mode_tag(self.precision)
 
@@ -249,16 +279,14 @@ class TextGenPipeline:
         def loop(params, kv, t0, keys):
             def body(carry, i):
                 kv, tok = carry
-                logits, kv = self.model.apply(
-                    {"params": params}, tok, kv, p + i - 1,
-                    method=TextGenModel.decode)
+                logits, kv = self._decode(params, tok, kv, p + i - 1)
                 nxt = sample(logits, keys, i)
                 return (kv, nxt), nxt
 
-            (_, _), rest = jax.lax.scan(body, (kv, t0),
-                                        jnp.arange(1, t))
-            return jnp.concatenate(
-                [t0[:, None], jnp.moveaxis(rest, 0, 1)], axis=1)
+            (kv, _), rest = jax.lax.scan(body, (kv, t0),
+                                         jnp.arange(1, t))
+            return self._outputs(jnp.concatenate(
+                [t0[:, None], jnp.moveaxis(rest, 0, 1)], axis=1), kv)
 
         return loop
 
@@ -270,8 +298,7 @@ class TextGenPipeline:
         total = prompt_bucket + decode_bucket
 
         def pre(params, ids):
-            return self.model.apply({"params": params}, ids, total,
-                                    method=TextGenModel.prefill)
+            return self._prefill(params, ids, total)
 
         return jax.jit(pre)
 
@@ -306,9 +333,7 @@ class TextGenPipeline:
                 # byte-identical to a never-quantized build
                 params = dequantize_tree(params)
             keys = _fold_keys(seeds_lo, seeds_hi)
-            logits0, kv = self.model.apply(
-                {"params": params}, ids, total,
-                method=TextGenModel.prefill)
+            logits0, kv = self._prefill(params, ids, total)
             t0 = sample(logits0, keys, 0)
             return loop(params, kv, t0, keys)
 
@@ -344,7 +369,8 @@ class TextGenPipeline:
         sampler: str = "greedy",
         as_device: bool = False,
     ):
-        """Run a sequence bucket; returns int32 token ids [B, T].
+        """Run a sequence bucket; returns int32 token ids [B, T] (or
+        whatever the family's `_outputs` makes of them).
 
         `as_device=True` keeps the jax.Array un-transferred so the
         solver can overlap the next dispatch with detokenize/CID work,
@@ -388,7 +414,7 @@ class TextGenPipeline:
                 if self.precision != "bf16" else None, tag=tag)
         if as_device:
             return tokens
-        return np.asarray(tokens)
+        return jax.tree_util.tree_map(np.asarray, tokens)
 
 
 # dp-only for now: tokens scale bit-identically over the batch axis;

@@ -99,11 +99,13 @@ def all_trace_specs() -> list[TraceSpec]:
     from arbius_tpu.models.rvm import pipeline as rvm_pipeline
     from arbius_tpu.models.sd15 import pipeline as sd15_pipeline
     from arbius_tpu.models.textgen import pipeline as textgen_pipeline
+    from arbius_tpu.models.trinity import pipeline as trinity_pipeline
     from arbius_tpu.models.video import pipeline as video_pipeline
     from arbius_tpu.parallel import meshsolve
 
     specs: list[TraceSpec] = []
     for mod in (sd15_pipeline, kandinsky2_pipeline, rvm_pipeline,
-                video_pipeline, textgen_pipeline, meshsolve):
+                video_pipeline, textgen_pipeline, trinity_pipeline,
+                meshsolve):
         specs.extend(mod.trace_specs())
     return validate_specs(specs)

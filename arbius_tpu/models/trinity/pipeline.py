@@ -1,0 +1,152 @@
+"""trinity pipeline — TextGenPipeline's bucket policy, decode loop,
+samplers and seed chain over the Trinity (`afmoe`) model.
+
+Nothing of the serving discipline is copied: a bucket is still (batch,
+prompt edge, decode edge, sampler), ONE jitted program of prefill then
+the `lax.scan` decode loop with the caches as carry, prompts padded to
+the edge with eos and no padding mask, the host truncating to each
+task's budget. What this family brings is the model behind the loop
+(models/trinity/model.py): two kinds of cache in the carry, routed
+experts told which they hold, and a bucket program that returns, beside
+the tokens, its routers' int32 assignment counts.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from arbius_tpu.models.textgen.pipeline import TextGenPipeline
+from arbius_tpu.models.trinity import model as trinity
+from arbius_tpu.models.trinity.model import TrinityConfig
+
+
+class TrinityPipeline(TextGenPipeline):
+    FAMILY = "trinity"
+    # ids the byte tokenizer turns into text, one byte each. No
+    # vocabulary file of the model's is in the tree, so an id past them
+    # is no text: the samplers see the byte ids' logits alone, and a
+    # task always spends its whole budget (no eos is ever sampled).
+    BYTE_IDS = 256
+
+    def __init__(self, config: TrinityConfig | None = None, mesh=None,
+                 precision: str = "bf16",
+                 prompt_buckets: tuple = (8192,),
+                 decode_buckets: tuple = (256,), top_k: int = 8):
+        if mesh is not None:
+            raise ValueError(
+                "trinity ships no mesh layout: its expert axis across "
+                "chips is a determinism class of its own (ROADMAP R4)")
+        if precision != "bf16":
+            raise ValueError(
+                f"precision mode {precision!r} is not shipped for the "
+                "trinity family — it serves bf16 only")
+        super().__init__(config or TrinityConfig.published(), mesh=None,
+                         precision=precision,
+                         prompt_buckets=prompt_buckets,
+                         decode_buckets=decode_buckets, top_k=top_k)
+        lo, hi = self.config.vocab_rows
+        if lo != 0 or hi <= self.EOS_ID:
+            raise ValueError(
+                f"vocab_rows {self.config.vocab_rows} must hold the byte "
+                f"tokenizer's ids 0..{self.EOS_ID}: the chip that samples "
+                "holds the slice the text lives in")
+        if self.top_k > self.BYTE_IDS:
+            raise ValueError(f"top_k ({self.top_k}) exceeds the "
+                             f"{self.BYTE_IDS} byte ids sampled over")
+
+    # -- the model behind the loop -----------------------------------------
+    def _make_model(self):
+        return None     # pure functions of the param tree, no module
+
+    def _prefill(self, params, ids, total: int):
+        return trinity.prefill(params, ids, total, self.config)
+
+    def _decode(self, params, tok, carry, pos):
+        return trinity.decode(params, tok, carry, pos, self.config)
+
+    def _sampler_fn(self, sampler: str):
+        """The shared samplers over the byte ids alone: every other
+        logit of the slice is computed (the head is this chip's share
+        of the deployment's) and masked to the least float32."""
+        sample = super()._sampler_fn(sampler)
+        n = self.BYTE_IDS
+
+        def over_bytes(logits, keys, step):
+            text = jnp.arange(logits.shape[-1]) < n
+            return sample(jnp.where(text, logits,
+                                    jnp.finfo(logits.dtype).min),
+                          keys, step)
+
+        return over_bytes
+
+    def _outputs(self, tokens, carry):
+        """(tokens[B, T], routed int32 [assignments made, on held
+        experts]) — the counts are part of the goldened program."""
+        return tokens, carry[1]
+
+    def kv_rows(self, prompt_bucket: int, decode_bucket: int) -> tuple:
+        return self.config.kv_rows(prompt_bucket + decode_bucket)
+
+    def _init_fn(self):
+        return lambda key: trinity.init_params(self.config, key)
+
+
+MESH_LAYOUTS: tuple[tuple[str, ...], ...] = ()
+
+
+def trace_specs():
+    """graphlint trace specs at the tiny whole-model config: prefill,
+    the decode loop (greedy and seeded top-k) and the composed bucket
+    program — the prompt edge longer than the tiny window, so the ring
+    fill and the ring's write rule are in the goldened graphs."""
+    from arbius_tpu.models.trace_specs import TraceSpec
+
+    P, T = 12, 4
+
+    def make_pipe():
+        return TrinityPipeline(TrinityConfig.tiny(), prompt_buckets=(P,),
+                               decode_buckets=(T,), top_k=4)
+
+    def abstract(pipe, batch):
+        shapes = jax.eval_shape(
+            lambda: pipe.init_params(seed=0, dtype="bfloat16"))
+        sds = jax.ShapeDtypeStruct
+        return (shapes, sds((batch, P), jnp.int32),
+                sds((batch,), jnp.uint32), sds((batch,), jnp.uint32))
+
+    def build_prefill():
+        pipe = make_pipe()
+        shapes, ids, _, _ = abstract(pipe, 2)
+        return pipe.prefill_program(2, P, T), (shapes, ids)
+
+    def build_decode(sampler):
+        def build():
+            pipe = make_pipe()
+            shapes, ids, lo, hi = abstract(pipe, 2)
+            _, carry = jax.eval_shape(pipe.prefill_program(2, P, T),
+                                      shapes, ids)
+            t0 = jax.ShapeDtypeStruct((2,), jnp.int32)
+            return (pipe.decode_program(2, P, T, sampler),
+                    (shapes, carry, t0, lo, hi))
+
+        return build
+
+    def build_generate():
+        pipe = make_pipe()
+        return (pipe.compiled_bucket(2, P, T, "greedy"),
+                abstract(pipe, 2))
+
+    bucket = f"b2.p{P}.t{T}"
+    return [
+        TraceSpec(model="trinity", entry="prefill", bucket=bucket,
+                  mesh="single", dtype="bfloat16", build=build_prefill),
+        TraceSpec(model="trinity", entry="decode",
+                  bucket=f"{bucket}.greedy", mesh="single",
+                  dtype="bfloat16", build=build_decode("greedy")),
+        TraceSpec(model="trinity", entry="decode",
+                  bucket=f"{bucket}.top_k", mesh="single",
+                  dtype="bfloat16", build=build_decode("top_k")),
+        TraceSpec(model="trinity", entry="generate",
+                  bucket=f"{bucket}.greedy", mesh="single",
+                  dtype="bfloat16", build=build_generate),
+    ]

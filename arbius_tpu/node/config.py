@@ -11,7 +11,7 @@ unknown keys and wrong types at boot instead of failing mid-mine).
 from __future__ import annotations
 
 import json
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 
 
 class ConfigError(ValueError):
@@ -335,8 +335,46 @@ class TextgenConfig:
     # the k of seeded top-k sampling — part of the compiled program,
     # fleet-wide like the bucket edges
     top_k: int = 8
+    # per text template, what differs from the block above — a model
+    # that reads documents needs other edges than the tiny LM:
+    # {"trinity": {"prompt_buckets": [8192], "decode_buckets": [256],
+    # "max_new_tokens": 256}}. Fleet-wide like everything here.
+    templates: dict = field(default_factory=dict)
+    # the chip's share of a model divided over chips (trinity only:
+    # `experts_held`, `vocab_rows`, `layers` — models/trinity
+    # TrinityConfig); empty = the whole published model
+    share: dict = field(default_factory=dict)
+
+    def for_template(self, template: str) -> "TextgenConfig":
+        """The policy one text template serves under: this block with
+        the template's own entries laid over it."""
+        over = self.templates.get(template)
+        if not over:
+            return self
+        return replace(self, templates={}, **{
+            k: tuple(v) if isinstance(v, list) else v
+            for k, v in over.items()})
 
     def __post_init__(self):
+        if not isinstance(self.templates, dict) \
+                or not isinstance(self.share, dict):
+            raise ConfigError("textgen.templates and textgen.share must "
+                              "be objects")
+        unknown = set(self.share) - {"experts_held", "vocab_rows", "layers"}
+        if unknown:
+            raise ConfigError(
+                f"textgen.share: unknown key(s) {sorted(unknown)}; a share "
+                "states experts_held, vocab_rows and layers, nothing else")
+        for template, over in self.templates.items():
+            if not isinstance(over, dict) or "templates" in over:
+                raise ConfigError(
+                    f"textgen.templates[{template!r}] must be an object "
+                    "of this block's own keys")
+            try:
+                self.for_template(template)   # validates the merged block
+            except TypeError as e:
+                raise ConfigError(
+                    f"textgen.templates[{template!r}]: {e}") from None
         for name, edges in (("prompt_buckets", self.prompt_buckets),
                             ("decode_buckets", self.decode_buckets)):
             if not isinstance(edges, (tuple, list)) or not edges:

@@ -11,6 +11,7 @@ FLOPs, no weights download).
 from __future__ import annotations
 
 import logging
+from dataclasses import replace
 
 from arbius_tpu.node.config import ConfigError, MiningConfig, ModelConfig
 from arbius_tpu.node.solver import (
@@ -240,7 +241,7 @@ def probe_golden_input(shape: str):
 def _textgen(m: ModelConfig, mesh, mode: str, tg):
     """textgen builder — takes the fleet-wide sequence-bucket policy
     (cfg.textgen) on top of the common (model, mesh, mode) triple, so
-    it is special-cased in build_registry rather than in _BUILDERS."""
+    it sits in _TEXT_BUILDERS rather than in _BUILDERS."""
     from arbius_tpu.models.textgen import TextGenConfig, TextGenPipeline
     from arbius_tpu.node.solver import TextGenRunner
 
@@ -250,6 +251,31 @@ def _textgen(m: ModelConfig, mesh, mode: str, tg):
                            decode_buckets=tuple(tg.decode_buckets),
                            top_k=tg.top_k)
     return TextGenRunner(pipe, _params_for(pipe, m))
+
+
+def _trinity(m: ModelConfig, mesh, mode: str, tg):
+    """trinity builder — TextGenRunner over the Trinity pipeline; the
+    bucket policy is the template's own entry of cfg.textgen, and
+    `share` says which experts, vocabulary rows and layers this chip
+    holds (nothing: the whole published model)."""
+    from arbius_tpu.models.trinity import TrinityConfig, TrinityPipeline
+    from arbius_tpu.node.solver import TextGenRunner
+
+    try:
+        cfg = TrinityConfig.tiny(**tg.share) if m.tiny \
+            else replace(TrinityConfig.published(), **tg.share)
+    except ValueError as e:
+        raise ConfigError(f"textgen.share: {e}") from None
+    pipe = TrinityPipeline(cfg, mesh=mesh, precision=mode,
+                           prompt_buckets=tuple(tg.prompt_buckets),
+                           decode_buckets=tuple(tg.decode_buckets),
+                           top_k=tg.top_k)
+    return TextGenRunner(pipe, _params_for(pipe, m))
+
+
+# text templates: builders that take the template's sequence-bucket
+# policy (cfg.textgen.for_template) on top of the common triple
+_TEXT_BUILDERS = {"textgen": _textgen, "trinity": _trinity}
 
 
 def _rvm(m: ModelConfig, mesh, resolve_file):
@@ -338,10 +364,12 @@ def build_registry(cfg: MiningConfig, *, mesh=None,
                             "skipping", m.id)
                 continue
             runner = _rvm(m, mesh, resolve_file)
-        elif m.template == "textgen":
-            # carries the fleet-wide sequence-bucket policy on top of
-            # the common builder triple (docs/text-serving.md)
-            runner = _textgen(m, mesh, mode, cfg.textgen)
+        elif m.template in _TEXT_BUILDERS:
+            # carries the fleet-wide sequence-bucket policy, per text
+            # template, on top of the common builder triple
+            # (docs/text-serving.md)
+            runner = _TEXT_BUILDERS[m.template](
+                m, mesh, mode, cfg.textgen.for_template(m.template))
         elif m.template in _BUILDERS:
             runner = _BUILDERS[m.template](m, mesh, mode)
         else:

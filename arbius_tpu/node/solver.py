@@ -497,6 +497,17 @@ class SD15Runner:
             hydrated.get("scheduler", "DDIM"))
 
 
+def _counter(name: str, help_text: str, labelnames: tuple = ()):
+    """The ambient obs' counter of that name, or None with no obs active
+    (library code stays node-free, like `span`)."""
+    from arbius_tpu.obs import current_obs
+
+    obs = current_obs()
+    if obs is None:
+        return None
+    return obs.registry.counter(name, help_text, labelnames=labelnames)
+
+
 def count_decode_stall(n: int = 1) -> None:
     """Bump `arbius_decode_stalls_total` — a text solve whose decode
     produced ZERO output bytes (immediate eos / nothing representable).
@@ -505,18 +516,40 @@ def count_decode_stall(n: int = 1) -> None:
     production finalize path and the simnet fault plane so the metric
     carries one help string (docs/observability.md; the healthwatch
     `decode_stall` rule watches this counter)."""
-    from arbius_tpu.obs import current_obs
+    c = _counter("arbius_decode_stalls_total",
+                 "text solves whose decode produced zero output bytes")
+    if c is not None:
+        c.inc(n)
 
-    obs = current_obs()
-    if obs is not None:
-        obs.registry.counter(
-            "arbius_decode_stalls_total",
-            "text solves whose decode produced zero output bytes",
-        ).inc(n)
+
+def count_text_tokens(prefill: int, decode: int) -> None:
+    """Bump `arbius_text_tokens_total{phase}` by the positions a text
+    bucket's program processes: batch × prompt edge of prefill, batch ×
+    decode edge of decode steps — counted at dispatch from the bucket's
+    shape (padding slots and padded positions are program work too)."""
+    c = _counter("arbius_text_tokens_total",
+                 "positions text bucket programs processed, by phase",
+                 labelnames=("phase",))
+    if c is not None:
+        c.inc(prefill, phase="prefill")
+        c.inc(decode, phase="decode")
+
+
+def count_moe_assignments(made: int, held: int) -> None:
+    """Bump `arbius_moe_assignments_total{held}`: the (token, choice)
+    assignments a bucket program's routers made, split by whether the
+    chosen expert is held on this chip — the program's own int32 sums
+    (docs/text-serving.md)."""
+    c = _counter("arbius_moe_assignments_total",
+                 "router (token, choice) assignments, by whether the "
+                 "chosen expert is held here", labelnames=("held",))
+    if c is not None:
+        c.inc(held, held="yes")
+        c.inc(made - held, held="no")
 
 
 class TextGenRunner:
-    """textgen-template runner: decoder-only LM → deterministic UTF-8.
+    """text-template runner (textgen, trinity): decoder-only LM → deterministic UTF-8.
 
     Template variables (templates/textgen.json): prompt,
     max_new_tokens, sampler (enum); output out-1.txt. The sequence
@@ -568,24 +601,39 @@ class TextGenRunner:
         (docs/text-serving.md)."""
         first = items[0][0]
         pb, db = self._buckets_of(first)
-        tokens = self.pipeline.generate(
-            self.params,
-            prompts=[str(h.get("prompt", "")) for h, _ in items],
-            seeds=[s for _, s in items],
-            prompt_bucket=pb, decode_bucket=db,
-            sampler=first.get("sampler") or "greedy",
-            as_device=True,
-        )
-        return tokens, [int(h.get("max_new_tokens") or 16)
-                        for h, _ in items]
+        batch = len(items)
+        held, full = self.pipeline.kv_rows(pb, db)
+        count_text_tokens(prefill=batch * pb, decode=batch * db)
+        with span("text.bucket", model=self.pipeline.FAMILY,
+                  prompt_bucket=pb, decode_bucket=db, batch=batch,
+                  kv_rows=held, kv_rows_full=full):
+            out = self.pipeline.generate(
+                self.params,
+                prompts=[str(h.get("prompt", "")) for h, _ in items],
+                seeds=[s for _, s in items],
+                prompt_bucket=pb, decode_bucket=db,
+                sampler=first.get("sampler") or "greedy",
+                as_device=True,
+            )
+        return out, [int(h.get("max_new_tokens") or 16)
+                     for h, _ in items]
 
     def finalize(self, dev, n_real: int) -> list[dict]:
         from arbius_tpu.models.textgen import tokens_to_bytes
         from arbius_tpu.parallel.meshsolve import gather_canonical
 
-        tokens, budgets = dev
+        out, budgets = dev
+        # a family with expert layers returns its routers' counts
+        # beside the tokens (models/trinity)
+        tokens, routed = out if isinstance(out, tuple) else (out, None)
         with span("solve.encode", n=n_real, codec="text"):
             tokens = gather_canonical(tokens)
+            if routed is not None:
+                made, held = (int(x) for x in np.asarray(routed))
+                count_moe_assignments(made, held)
+                with span("text.routed", model=self.pipeline.FAMILY,
+                          assignments=made, held=held):
+                    pass
             out = []
             stalls = 0
             for i in range(n_real):

@@ -346,7 +346,7 @@ def test_decode_stall_fault_is_in_the_coverage_map():
 # -- e2e: the CID equality matrix through a real MinerNode ------------------
 
 def _text_world(pipe, params, *, canonical_batch=2, pipeline_on=False,
-                aot_dir=None):
+                aot_dir=None, template="textgen"):
     from arbius_tpu.chain import WAD, Engine, TokenLedger
     from arbius_tpu.node import (
         LocalChain,
@@ -369,13 +369,13 @@ def _text_world(pipe, params, *, canonical_batch=2, pipeline_on=False,
     mid = "0x" + eng.register_model(user, user, 0, b'{"f":"T"}').hex()
     registry = ModelRegistry()
     registry.register(RegisteredModel(
-        id=mid, template=load_template("textgen"),
+        id=mid, template=load_template(template),
         runner=TextGenRunner(pipe, params)))
     chain = LocalChain(eng, miner)
     chain.validator_deposit(100 * WAD)
     node = MinerNode(
         chain,
-        MiningConfig(models=(ModelConfig(id=mid, template="textgen"),),
+        MiningConfig(models=(ModelConfig(id=mid, template=template),),
                      canonical_batch=canonical_batch,
                      compile_cache=False,
                      pipeline=PipelineConfig(enabled=pipeline_on),
