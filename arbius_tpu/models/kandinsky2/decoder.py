@@ -25,6 +25,11 @@ SD's transformer blocks:
 
 TPU execution profile: bucketed static shapes, bf16 MXU convs/attention,
 one jitted program per shape bucket — identical discipline to SD-1.5.
+The added-KV attention goes through `ops.flash.attention`, the door
+`models.common.Attention` uses: from 1024 query rows on a TPU the flash
+kernel (the 48x48 level at 768x768: 2304 queries over 10 + 2304 keys,
+whose float32 scores XLA's einsum wrote to HBM and read back, 2 GB a call
+at batch 8), below that and off the TPU `sp_attention_reference`.
 Conversion source: the diffusers-format kandinsky decoder checkpoint —
 see kandinsky2/convert.py (`kandinsky_unet_key_for`).
 """
@@ -33,9 +38,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
-import numpy as np
 
 from arbius_tpu.models.common import (
     GroupNorm32,
@@ -69,7 +72,9 @@ class DecoderConfig:
 class AttnAddedKV(nn.Module):
     """unCLIP-family attention: group-normed spatial queries over
     [context ‖ spatial] keys/values, all projections biased, residual
-    inside. Softmax in float32 (determinism + stability policy)."""
+    inside. The attention itself is `ops.flash.attention`'s: scores and
+    softmax in float32, probabilities in v's type (determinism +
+    stability policy), kernel or einsum by the call's shape and backend."""
     num_heads: int
     head_dim: int
     context_dim: int
@@ -96,11 +101,11 @@ class AttnAddedKV(nn.Module):
             return t.reshape(t.shape[0], t.shape[1], self.num_heads,
                              self.head_dim).transpose(0, 2, 1, 3)
 
-        q, k, v = split(q), split(k), split(v)
-        scale = 1.0 / np.sqrt(self.head_dim)
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
-        probs = jax.nn.softmax(logits, axis=-1).astype(self.dtype)
-        out = jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+        # the pallas flash kernel on TPU from 1024 query rows (the 48x48
+        # level at 768x768), XLA einsum otherwise — same math either way
+        from arbius_tpu.ops.flash import attention as fused_attention
+
+        out = fused_attention(split(q), split(k), split(v))
         out = out.transpose(0, 2, 1, 3).reshape(b, hh * ww, inner)
         out = nn.Dense(c, dtype=self.dtype, name="to_out")(out)
         return residual + out.reshape(b, hh, ww, c)

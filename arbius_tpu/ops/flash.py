@@ -4,6 +4,9 @@ Why: self-attention over S=9216 tokens (anythingv3's UNet at 768x768,
 its VAE and Kandinsky's MOVQ mid-block at 96x96 latents, the video
 UNet's spatial attention) materializes a 9216 x 9216 float32 score
 matrix per head through the XLA einsum path (~340 MB a head) — HBM-bound.
+So does the added-KV attention of Kandinsky's decoder UNet at its 48x48
+level (2304 queries over 10 context + 2304 spatial keys, 12 heads of 64:
+2 GB of scores a call at batch 8, seven calls a forward).
 The flash form never materializes scores: K/V pass through VMEM in
 blocks under a running max / normalizer / accumulator (the same
 online-softmax mathematics as ops/ring.py, one level down the memory
@@ -35,7 +38,16 @@ allow up to 1024 rows, the Q tile is what a 1 MiB float32 score tile
 and a 1 MiB float32 accumulator then leave (256 rows beside 1024 keys,
 1024 rows beside the 77 keys of cross-attention, at most 512 at D=512),
 and `_UNROLL` K blocks share a trip of the loop so that one block's
-products overlap its neighbour's softmax. VMEM per program is reckoned
+products overlap its neighbour's softmax. "Pads least" alone is not the
+rule for the K tile: the 2314 keys of Kandinsky's added-KV attention
+(2304 + 10 context tokens) are 18 x 128 and ten more, so the tile that
+pads least is 128 — 19 K blocks a Q block, 3.94 ms a call at (8, 12,
+2304, 2314, 64), pads and slice included, against 2.22-2.82 ms with any
+K tile of 512-1024 and 2,560-3,072 padded keys; in the bucket program
+the kernel alone took 3.49 ms at (768, 128) and takes 1.91 ms at (384,
+640) (PERF.md section 6, PR 29). A K tile is therefore at least 512 rows
+wherever the keys are; every shape whose tile was 512 or more keeps it,
+and fewer keys than 512 take one block as before. VMEM per program is reckoned
 in `_vmem_bytes` and stated as the call's limit: K/V whole and double-
 buffered are 9 MiB at S=9216, D=40 (lane-padded) and 36 MiB at D=512
 in bf16, 72 MiB in float32, beside at most 10 MiB of work arrays.
@@ -65,6 +77,11 @@ _LANES = 128
 # tile is longer than 1024 rows.
 _WORK_BYTES = 1024 * 1024
 _MAX_TILE = 1024
+# Padding is weighed against the K blocks a Q block walks: a score costs
+# about the same from 512 keys a block up and 1.5 to 2 times that below
+# (module docstring), more than any padding a tile of 512-1024 can add
+# to keys that reach 512. Fewer keys than that take one block.
+_MIN_K_TILE = 512
 # K blocks a trip of the loop takes: consecutive blocks depend on each
 # other only through (m, l, acc), so the next block's q @ k.T runs on
 # the MXU while this block's softmax runs on the VPU. Written out by
@@ -80,18 +97,21 @@ def _round_up(n: int, mult: int) -> int:
     return -(-n // mult) * mult
 
 
-def _tile(n: int, cap: int) -> int:
-    """The tile for a sequence of n rows: of the multiples of 128 up to
-    the cap, the one that pads n least; of equals, the largest."""
+def _tile(n: int, cap: int, floor: int = _LANES) -> int:
+    """The tile for a sequence of n rows: of the multiples of 128 from
+    the floor (or from n rounded up, if that is less) up to the cap, the
+    one that pads n least; of equals, the largest."""
     cap = max(_LANES, min(_MAX_TILE, cap) // _LANES * _LANES)
-    return min(range(cap, 0, -_LANES), key=lambda t: _round_up(n, t))
+    floor = min(floor, cap, _round_up(n, _LANES))
+    return min(range(cap, floor - 1, -_LANES), key=lambda t: _round_up(n, t))
 
 
 def _tiles(sq: int, kv_len: int, d: int) -> tuple[int, int]:
     """(block_q, block_k) from the call's static shape: the K tile as
-    long as the keys allow, the Q tile from what the two float32 work
-    arrays then leave."""
-    block_k = _tile(kv_len, _MAX_TILE)
+    long as the keys allow and no shorter than `_MIN_K_TILE` where they
+    reach it, the Q tile from what the two float32 work arrays then
+    leave."""
+    block_k = _tile(kv_len, _MAX_TILE, _MIN_K_TILE)
     rows = _WORK_BYTES // (4 * max(block_k, _round_up(d, _LANES)))
     return _tile(sq, rows), block_k
 

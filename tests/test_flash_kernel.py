@@ -48,9 +48,14 @@ CASES = [
     (1, 1, 896, 128, 40, (896, 128)),
     (1, 1, 1024, 256, 40, (1024, 256)),
     (1, 1, 256, 896, 40, (256, 896)),
-    (1, 2, 128, 1400, 40, (128, 128)),    # 3 trips of 3, 1 more, 120 keys
-    (1, 1, 200, 1100, 40, (256, 384)),    # no loop: 2 whole blocks, 332 keys
-    (2, 1, 128, 1408, 128, (128, 128)),   # 3 trips, 2 more, nothing padded
+    # keys past 512 take a K tile of 512 or more (PR 29), whatever pads least
+    (1, 2, 128, 1400, 40, (128, 768)),    # no loop: 1 whole block, 632 keys
+    (1, 1, 200, 1100, 40, (256, 640)),    # ... 1 whole block, 460 keys
+    (1, 2, 128, 5384, 40, (128, 512)),    # 3 trips of 3, 1 more, 264 keys
+    (2, 1, 128, 5632, 128, (128, 512)),   # 3 trips, 2 more, nothing padded
+    # Kandinsky's added-KV attention: 10 context keys before the spatial ones
+    (1, 2, 256, 266, 64, (256, 384)),     # ... cut down: one masked block
+    (1, 1, 384, 2314, 64, (384, 640)),    # level 1's keys: 3 whole, 394 more
 ]
 
 
@@ -75,6 +80,11 @@ def test_every_tile_of_the_rule_is_exercised():
     assert {flash._tile(n, flash._MAX_TILE) for n in range(1, 4096)} == every
     assert {c[5][0] for c in CASES} == every    # Q tiles
     assert {c[5][1] for c in CASES} == every    # K tiles
+    # the K side's floor: 512 where the keys reach it, else one block
+    for n in range(1, 4096):
+        t = flash._tile(n, flash._MAX_TILE, flash._MIN_K_TILE)
+        assert t >= flash._MIN_K_TILE if n > flash._MIN_K_TILE \
+            else t == -(-n // 128) * 128
 
 
 @pytest.mark.parametrize("sq,sk,d,tiles", [
@@ -90,6 +100,24 @@ def test_tiles_of_the_cells_shapes_pad_nothing_but_the_77_keys(sq, sk, d,
     assert flash._tiles(sq, sk, d) == tiles
     assert sq % tiles[0] == 0 and (sk % tiles[1] == 0 or sk == 77)
     # each float32 work array of a program stays within its budget
+    assert 4 * tiles[0] * max(tiles[1], d) <= flash._WORK_BYTES
+
+
+@pytest.mark.parametrize("sq,sk,d,tiles", [
+    (2304, 2314, 64, (384, 640)),     # kandinsky2 level 1 at 768x768
+    (4096, 4106, 64, (256, 896)),     # ... at 1024x1024
+    (1024, 1034, 64, (256, 640)),     # level 2 at 1024x1024
+])
+def test_tiles_of_the_added_kv_shapes_walk_few_k_blocks(sq, sk, d, tiles):
+    """Ten context keys push the keys just past a multiple of 128: the
+    tile that pads least is then the shortest, 19 K blocks a Q block at
+    2314 keys and 3.94 ms a call on the chip against 2.48 at (384, 640),
+    pads and slice included
+    (PERF.md section 6, PR 29)."""
+    assert flash._tiles(sq, sk, d) == tiles
+    padded = -(-sk // tiles[1]) * tiles[1]
+    assert sq % tiles[0] == 0 and padded // tiles[1] <= 5
+    assert padded < 1.25 * sk
     assert 4 * tiles[0] * max(tiles[1], d) <= flash._WORK_BYTES
 
 
@@ -112,7 +140,7 @@ def _kernel_eqns(sq, sk, d, dtype):
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_operands_reach_both_products_in_the_type_they_arrive_in(dtype):
-    dots = [e for e in _kernel_eqns(256, 1400, 40, dtype)
+    dots = [e for e in _kernel_eqns(256, 5384, 40, dtype)
             if e.primitive.name == "dot_general"]
     assert len(dots) == 10
     for e in dots:
@@ -122,9 +150,9 @@ def test_operands_reach_both_products_in_the_type_they_arrive_in(dtype):
 
 @pytest.mark.parametrize("sk,blocks,masks,loops", [
     (1024, 1, 0, 0),   # an exact multiple: statically no mask anywhere
-    (1408, 5, 0, 1),   # 11 blocks of 128: 3 in the loop's body, 2 after it
-    (1400, 5, 1, 1),   # 10 whole blocks unmasked, the last one masked
-    (1100, 3, 1, 0),   # too few blocks for a loop
+    (5632, 5, 0, 1),   # 11 blocks of 512: 3 in the loop's body, 2 after it
+    (5384, 5, 1, 1),   # 10 whole blocks unmasked, the last one masked
+    (2314, 4, 1, 0),   # too few blocks for a loop
     (77, 1, 1, 0),     # one block, masked
 ])
 def test_only_a_block_that_can_hold_a_padded_key_is_masked(sk, blocks, masks,
