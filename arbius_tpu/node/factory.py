@@ -154,7 +154,7 @@ def _sd15(m: ModelConfig, mesh, mode: str = "bf16"):
 
 def tiny_byte_tokenizer(text_cfg):
     """Byte tokenizer whose special ids fit a reduced-vocab text tower —
-    the one way to build a tiny-config tokenizer (bench.py uses it too)."""
+    the one way to build a tiny-config tokenizer."""
     from arbius_tpu.models.sd15 import ByteTokenizer
 
     return ByteTokenizer(max_length=text_cfg.max_length,

@@ -335,7 +335,6 @@ class MinerNode:
             raise BootError(
                 f"chain version {self.chain.version()} > miner {MINER_VERSION}"
                 " — update the node (index.ts:960-969)")
-        self._check_attention_impl(skip_self_test=skip_self_test)
         if not skip_self_test:
             self._boot_self_test()
         delegated = getattr(self.chain, "validator_address", self.chain.address)
@@ -358,32 +357,6 @@ class MinerNode:
         self.chain.subscribe(self._on_event)
         log.info("node booted: %d models, address %s",
                  len(self.registry.ids()), self.chain.address)
-
-    def _check_attention_impl(self, *, skip_self_test: bool) -> None:
-        """A non-default attention impl is a different reduction order —
-        a different determinism class — so it may only mine if the boot
-        self-test proves it still reproduces the recorded goldens
-        (ops/flash.py pins the impl once at import; runtime toggles are
-        impossible by construction)."""
-        from arbius_tpu.ops.flash import attention_impl
-
-        impl = attention_impl()
-        if impl == "auto":
-            return
-        has_golden = any(self.registry.get(mid).golden is not None
-                         for mid in self.registry.ids())
-        if not has_golden:
-            log.warning(
-                "ARBIUS_ATTN_IMPL=%s with no golden vectors registered — "
-                "nothing proves this impl matches the fleet's determinism "
-                "class; record goldens before mining for real", impl)
-            return
-        if skip_self_test:
-            raise BootError(
-                f"ARBIUS_ATTN_IMPL={impl}: a non-default attention impl "
-                "must pass the boot self-test against the recorded goldens "
-                "(its reduction order defines the determinism class) — do "
-                "not skip the self-test, or unset the override")
 
     def _boot_self_test(self) -> None:
         """Golden-CID reproducibility check before mining anything

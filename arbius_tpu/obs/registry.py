@@ -1,9 +1,9 @@
 """Metrics registry — counters, gauges, fixed-bucket histograms.
 
 One process-local registry backs every surface that reports numbers:
-the node's `NodeMetrics` view, the JSON `/api/metrics` endpoint, the
-Prometheus `GET /metrics` exposition, and bench.py's per-stage BENCH
-snapshots. The reference miner has no metrics at all (SURVEY.md §5);
+the node's `NodeMetrics` view, the JSON `/api/metrics` endpoint and the
+Prometheus `GET /metrics` exposition.
+The reference miner has no metrics at all (SURVEY.md §5);
 the shape here follows the Prometheus client-library data model —
 monotonic counters, settable gauges (optionally collect-time callbacks),
 and histograms with fixed cumulative buckets — because that is what a

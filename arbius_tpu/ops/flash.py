@@ -186,30 +186,26 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, widths)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "pad_d"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    interpret: bool = False, pad_d: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """Exact attention, flash-style. q/k/v: [B, H, S, D] → [B, H, Sq, D].
 
-    `pad_d=False` skips the explicit head-dim pad to 128 lanes and hands
-    the native D (40/80/160 at SD-1.5 levels) straight to the kernel —
-    Mosaic lane-pads blocks in VMEM internally, so the math is identical,
-    but the jnp.pad round-trips through HBM (a 3.2× inflation of Q/K/V
-    traffic at D=40) disappear. MXU pass count is the same either way
-    (contraction/lane dims ≤128 occupy one pass regardless), so this
-    targets HBM bandwidth, not FLOPs. On the chip the two read the same
-    to within a run's noise, pad, kernel and slice together: 9.75 and
-    9.70 ms at (32, 9216, 9216, 40), 1.35 and 1.33 ms at (32, 2304, 2304,
-    80) (PERF.md section 6, PR 26) — the calls are MXU-bound."""
+    D is padded to 128 lanes in HBM before the call. Handing the kernel
+    the native D (Mosaic lane-pads blocks in VMEM itself) read the same
+    on the chip, pad, kernel and slice together: 9.75 and 9.70 ms at
+    (32, 9216, 9216, 40), 1.35 and 1.33 ms at (32, 2304, 2304, 80)
+    (PERF.md section 6, PR 26) — the calls are MXU-bound, so there is
+    one path. A shape at which the unpadded form wins is chosen here,
+    from the static shape, not by a keyword."""
     b, h, sq, d = q.shape
     kv_len = k.shape[2]
     scale = 1.0 / np.sqrt(d)
 
-    d_mult = _LANES if pad_d else 1
     block_q, block_k = _tiles(sq, kv_len, d)
-    qf = _pad_to(_pad_to(q.reshape(b * h, sq, d), 1, block_q), 2, d_mult)
-    kf = _pad_to(_pad_to(k.reshape(b * h, kv_len, d), 1, block_k), 2, d_mult)
-    vf = _pad_to(_pad_to(v.reshape(b * h, kv_len, d), 1, block_k), 2, d_mult)
+    qf = _pad_to(_pad_to(q.reshape(b * h, sq, d), 1, block_q), 2, _LANES)
+    kf = _pad_to(_pad_to(k.reshape(b * h, kv_len, d), 1, block_k), 2, _LANES)
+    vf = _pad_to(_pad_to(v.reshape(b * h, kv_len, d), 1, block_k), 2, _LANES)
     bh, sq_p, d_p = qf.shape
     kv_p = kf.shape[1]
 
@@ -256,103 +252,47 @@ def on_mesh(fn, mesh):
     return traced
 
 
-def _flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
-           pad_d: bool = True) -> jax.Array:
+def _flash(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     """flash_attention, per shard when a GSPMD mesh is being traced.
     Every (batch row, head) is its own program of the kernel grid, so
     splitting rows over dp and heads over tp moves no bits; an axis
     that does not divide stays replicated (an under-filled bucket runs
     the whole batch on every dp lane, as meshsolve.batch_specs does)."""
-    kernel = functools.partial(flash_attention, pad_d=pad_d)
     mesh = _KERNEL_MESH.get()
     if mesh is None:
-        return kernel(q, k, v)
+        return flash_attention(q, k, v)
 
     def axis(name: str, size: int) -> str | None:
         n = mesh.shape.get(name, 1)
         return name if n > 1 and size % n == 0 else None
 
     spec = P(axis("dp", q.shape[0]), axis("tp", q.shape[1]), None, None)
-    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(flash_attention, mesh=mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(q, k, v)
 
 
-VALID_ATTN_IMPLS = ("auto", "flash", "flash_nopad", "einsum")
-
-
-def _read_attn_impl() -> str:
-    import os
-
-    impl = os.environ.get("ARBIUS_ATTN_IMPL", "auto")
-    if impl not in VALID_ATTN_IMPLS:
-        # a typo must not silently measure/run a different impl than the
-        # label claims — the A/B exists to decide the production dispatch
-        raise ValueError(f"ARBIUS_ATTN_IMPL={impl!r} not in "
-                         + "|".join(VALID_ATTN_IMPLS))
-    return impl
-
-
-# Pinned ONCE at import. Reading the env var at trace time looked like a
-# runtime toggle but wasn't one: jitted callers only re-read it on a
-# retrace, so flipping it after a shape bucket compiled silently kept
-# the old impl — and a flip that DID land would change reduction order,
-# i.e. the golden CIDs' determinism class. The node boots against this
-# pinned value (MinerNode._check_attention_impl) and the profiler
-# threads its A/B through set_attention_impl(), re-jitting per impl.
-_ATTN_IMPL = _read_attn_impl()
-
-
-def attention_impl() -> str:
-    """The attention dispatch pinned for this process."""
-    return _ATTN_IMPL
-
-
-def set_attention_impl(impl: str | None) -> str:
-    """Explicitly re-pin the dispatch (A/B measurement only — callers
-    own the retrace; tools/tpu_profile.py builds a fresh jit per impl).
-    `None` restores the env-pinned import-time value. Returns the
-    previous value so callers can restore it."""
-    global _ATTN_IMPL
-
-    if impl is None:
-        impl = _read_attn_impl()
-    if impl not in VALID_ATTN_IMPLS:
-        raise ValueError(f"attention impl {impl!r} not in "
-                         + "|".join(VALID_ATTN_IMPLS))
-    prior, _ATTN_IMPL = _ATTN_IMPL, impl
-    return prior
+# Query rows from which `attention` takes the kernel on a TPU.
+_KERNEL_MIN_ROWS = 1024
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
-    """Backend-dispatching exact attention for [B, H, S, D].
+    """Exact attention for [B, H, S, D], the path read off the call: on
+    a TPU from `_KERNEL_MIN_ROWS` query rows the pallas flash kernel,
+    else `sp_attention_reference` (XLA's einsum, the only compiled form
+    off the TPU).
 
-    TPU + long sequences → the pallas flash kernel; otherwise the XLA
-    einsum path (which XLA already fuses well at short S, and which is
-    the only compiled option off-TPU).
-
-    The module-level pinned impl (ARBIUS_ATTN_IMPL at import, or an
-    explicit set_attention_impl) overrides the dispatch for on-chip A/B
-    measurement (tools/tpu_profile.py drives the FULL UNet step under
-    each value): "flash" | "flash_nopad" | "einsum" | "auto" (default).
-    All three are exact attention; they differ in reduction order
-    (ULP-class output drift), so a fleet pins ONE impl per determinism
-    class — changing the production dispatch re-records the platform
-    goldens, and a node booting with a non-default impl must prove its
-    goldens still hold (node.py boot check).
-    """
+    Both are exact attention and differ in reduction order, so each side
+    of the rule is a determinism class: moving the constant re-records
+    both image models' TPU goldens. What is measured: the kernel wins at
+    2,304 rows (ledger, PR 29: `k2-768-backlog` 1,843.6 → 2,175.5 sol/h
+    when those calls left XLA's two fusions) and at 9,216 the einsum's
+    float32 scores do not fit beside the weights. What is not: no cell
+    calls it between 1,024 and 2,303 rows, so the 1,024 itself rests on
+    no measurement, and the 576-row level is measured (the kernel 0.33-
+    0.53 ms against 0.70) and not acted on (PERF.md section 7)."""
     from arbius_tpu.ops.ring import sp_attention_reference
 
-    impl = _ATTN_IMPL
-    if impl == "einsum":
-        return sp_attention_reference(q, k, v)
-    on_tpu = jax.default_backend() == "tpu"
-    if impl == "flash" and on_tpu:
-        return _flash(q, k, v)
-    if impl == "flash_nopad" and on_tpu:
-        return _flash(q, k, v, pad_d=False)
-    # flash impls requested off-TPU fall through here: einsum is the only
-    # compiled option off-TPU, so a fleet pinning "flash" still boots on
-    # CPU dev hosts (the profiler only labels non-auto impls on TPU)
-    if on_tpu and q.shape[2] >= 1024:
+    if jax.default_backend() == "tpu" and q.shape[2] >= _KERNEL_MIN_ROWS:
         return _flash(q, k, v)
     return sp_attention_reference(q, k, v)

@@ -27,10 +27,10 @@ The sharded probe runners at the bottom are this module's executable
 spec: tiny real XLA programs (GSPMD image-shaped, shard_map video-shaped)
 whose math is layout-invariant BY CONSTRUCTION (per-sample PRNG keyed on
 global indices, concatenation-only collectives, integer cross-shard
-reductions — exact in any order). The byte-equality suite, simnet's
-mesh scenarios, and bench's `mesh_ab` stage all drive the node path
-through them, so the machinery (bucketing, chunking, placement, gather
-order) is tested separately from any one model's float behavior.
+reductions — exact in any order). The byte-equality suite and simnet's
+mesh scenarios drive the node path through them, so the machinery
+(bucketing, chunking, placement, gather order) is tested separately
+from any one model's float behavior.
 """
 # detlint: enforce[DET101,DET102,DET103,DET104,DET105]
 from __future__ import annotations
@@ -341,9 +341,8 @@ def record_collective_bytes(est: dict[str, int],
 # surface (node/solver.py), used as layout-invariance oracles: the node
 # path must produce byte-identical CIDs at mesh-off / dp-only / dp·tp
 # for these by construction, so any drift is a machinery bug (ordering,
-# padding, gather), never float luck. Bench `mesh_ab` and simnet's mesh
-# scenarios reuse them so their runs measure the same programs the
-# equality tests pin.
+# padding, gather), never float luck. Simnet's mesh scenarios reuse
+# them so their runs solve the same programs the equality tests pin.
 
 _PROBE_DIM = 8
 
@@ -439,9 +438,9 @@ class ShardedImageProbe(_ProbeBase):
     def _get_fn(self, batch: int, aot_args=None):
         """(fn, warm, tag) via the shared jit-cache obs helper
         (docs/observability.md) — the probes report warm-executable
-        reuse exactly like the model pipelines, so bench `sched_ab` and
-        the simnet flood see real jit-cache counters (and, with an AOT
-        cache installed, real disk-tier traffic)."""
+        reuse exactly like the model pipelines, so the simnet flood
+        sees real jit-cache counters (and, with an AOT cache installed,
+        real disk-tier traffic)."""
         from arbius_tpu.obs import jit_cache_get
 
         return jit_cache_get(self._fns, batch,
@@ -675,8 +674,8 @@ SEQ_LAYOUTS: tuple[tuple[str, ...], ...] = ((), ("dp", "sp"))
 
 def trace_specs():
     """graphlint trace specs for the probe programs. The probes are
-    SHIPPED solve programs — bench's `mesh_ab` stage and simnet's mesh
-    scenarios drive the real node path through them — so each (probe,
+    SHIPPED solve programs — simnet's mesh scenarios drive the real
+    node path through them — so each (probe,
     layout) pair gets a golden fingerprint exactly like a model family:
     a schedule or collective change in the machinery shows up as golden
     drift here even before any model's bytes move."""

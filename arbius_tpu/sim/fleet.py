@@ -408,8 +408,8 @@ class FleetFloodHarness:
             feed = LeaseFeed(self.leases, wid, self.fleet_cfg
                              ).attach(node)
             # flood sidecars flush ONLY at close (flood wall time is a
-            # pinned tier-1 budget — the final segment is all the bench
-            # flood stage needs to federate)
+            # pinned tier-1 budget — the final segment is all the flood
+            # report needs to federate)
             self._feeds.append(feed.attach_sidecar(
                 ObsSidecar(sidecar_path(workdir, wid), wid, node.obs),
                 every=10**9))

@@ -2,8 +2,8 @@
 
 Tests and multi-chip dry-runs (SURVEY.md §4: "multi-chip behavior tested
 with jax CPU mesh simulation") run on N virtual CPU devices. Shared by
-tests/conftest.py, bench.py's CPU A/B stages and __graft_entry__.py so
-the XLA_FLAGS edit lives in exactly one place.
+tests/conftest.py and __graft_entry__.py so the XLA_FLAGS edit lives
+in exactly one place.
 """
 from __future__ import annotations
 

@@ -30,24 +30,6 @@ def test_flash_matches_reference(b, h, s, d):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("b,h,s,d", [
-    (2, 3, 256, 40),      # SD-1.5 level-0 head shape, no explicit d pad
-    (1, 2, 200, 33),      # ragged everything
-])
-def test_flash_nopad_matches_reference_and_padded(b, h, s, d):
-    """pad_d=False hands the native head dim to the kernel (Mosaic lane-
-    pads internally); same math as the padded variant to reduction-order
-    ULPs (a K=40 vs K=128 contraction associates differently, so bits
-    may drift — switching the production default therefore re-records
-    the platform goldens, the round-4 discipline)."""
-    q, k, v = (rand((b, h, s, d), i) for i in range(3))
-    got = np.asarray(flash_attention(q, k, v, interpret=True, pad_d=False))
-    want = np.asarray(sp_attention_reference(q, k, v))
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
-    padded = np.asarray(flash_attention(q, k, v, interpret=True))
-    np.testing.assert_allclose(got, padded, rtol=1e-5, atol=1e-6)
-
-
 def test_flash_cross_attention_shape():
     """kv_len ≠ q_len (text cross-attention: 77 context tokens)."""
     q = rand((1, 2, 256, 64), 0)
@@ -76,27 +58,3 @@ def test_flash_extreme_logits():
     np.testing.assert_allclose(
         out, np.asarray(sp_attention_reference(q, k, v)), rtol=1e-5,
         atol=1e-5)
-
-
-def test_attention_impl_pinned_at_import_and_explicitly_settable():
-    """ISSUE satellite: the dispatch is pinned ONCE (env read at import);
-    in-process flips go through set_attention_impl, which validates and
-    returns the prior value for restore."""
-    import pytest
-
-    from arbius_tpu.ops import flash
-
-    assert flash.attention_impl() in flash.VALID_ATTN_IMPLS
-    prior = flash.set_attention_impl("einsum")
-    try:
-        assert flash.attention_impl() == "einsum"
-        with pytest.raises(ValueError, match="bogus"):
-            flash.set_attention_impl("bogus")
-        assert flash.attention_impl() == "einsum"  # rejected = unchanged
-    finally:
-        flash.set_attention_impl(prior)
-    assert flash.attention_impl() == prior
-    # None restores the env-pinned import-time value
-    flash.set_attention_impl("flash")
-    flash.set_attention_impl(None)
-    assert flash.attention_impl() == flash._read_attn_impl()

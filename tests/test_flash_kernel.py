@@ -6,6 +6,8 @@ the kernel states too — operands as handed, float32 scores and
 softmax, probabilities in v's type."""
 from __future__ import annotations
 
+import importlib.util
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -204,3 +206,40 @@ def test_vmem_limit_is_stated_from_the_blocks():
     # D=40 is held lane-padded to 128 whether or not the caller padded it
     assert flash._vmem_bytes(256, 1024, 9216, 40, 2) \
         == flash._vmem_bytes(256, 1024, 9216, 128, 2)
+
+
+# -- the rule at the kernel's door (`flash.attention`) ----------------------
+
+@pytest.mark.parametrize("sq,sk,d,kernel", [
+    (9216, 9216, 40, True),     # anythingv3 level 0 at 768 x 768
+    (2304, 2314, 64, True),     # Kandinsky's added-KV level 1
+    (1024, 1034, 64, True),     # the first row count the kernel takes
+    (1023, 1033, 64, False),
+    (576, 586, 64, False),      # added-KV level 2: measured, not acted on
+    (144, 154, 64, False),
+])
+def test_on_a_tpu_the_kernel_serves_from_1024_query_rows(sq, sk, d, kernel,
+                                                         monkeypatch):
+    """Traced only: nothing is lowered, so the CPU host never meets the
+    Mosaic call it could not compile."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((2, 4, sq, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 4, sk, d), jnp.bfloat16)
+    names = [e.primitive.name
+             for e in _eqns(jax.make_jaxpr(flash.attention)(q, kv, kv).jaxpr)]
+    assert names.count("pallas_call") == int(kernel)
+
+
+@pytest.mark.parametrize("value", ["einsum", "bogus"])
+def test_the_environment_selects_no_path(value, monkeypatch):
+    """`ARBIUS_ATTN_IMPL` chose among four paths once and an unknown
+    value failed the import; the module imported afresh under it reads
+    nothing, and off the TPU every call is the reference, bit for bit."""
+    monkeypatch.setenv("ARBIUS_ATTN_IMPL", value)
+    spec = importlib.util.spec_from_file_location("flash_afresh",
+                                                  flash.__file__)
+    afresh = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(afresh)
+    q, k, v = qkv(1, 2, 1024, 1034, 64, jnp.bfloat16)
+    assert np.array_equal(f32(afresh.attention(q, k, v)),
+                          f32(sp_attention_reference(q, k, v)))

@@ -1,9 +1,9 @@
 """healthwatch tier-1 suite (docs/healthwatch.md): the alert state
 machine's hysteresis edges, the rule catalog's config plumbing, the
 engine over a fake node, the /debug/alerts + /debug/journal surfaces,
-and the offline tools (tools/healthwatch.py, tools/benchkeeper.py)
-against their fixture goldens. The simnet coverage invariant (SIM113)
-and the CID on-vs-off pins live in tests/test_sim.py."""
+and the offline tool (tools/healthwatch.py) against its fixture
+goldens. The simnet coverage invariant (SIM113) and the CID on-vs-off
+pins live in tests/test_sim.py."""
 from __future__ import annotations
 
 import json
@@ -472,89 +472,3 @@ def test_healthwatch_tool_clean_fleet_exits_0(tmp_path, capsys):
     side.close()
     assert hw_tool.main(["--eval", str(tmp_path)]) == 0
     assert "0 firing alert(s)" in capsys.readouterr().out
-
-
-# -- tools/benchkeeper.py (fixture-goldened) --------------------------------
-
-BENCHDIR = os.path.join(FIXDIR, "benchkeeper")
-
-
-def test_benchkeeper_merges_every_shape_to_the_golden(capsys):
-    import benchkeeper
-
-    rc = benchkeeper.main(["--dir", BENCHDIR, "--json"])
-    out = capsys.readouterr().out
-    want = open(os.path.join(BENCHDIR, "trajectory.golden.json")).read()
-    assert out == want
-    assert rc == 0
-    doc = json.loads(out)
-    # all three historical shapes landed: driver-era parsed (r02),
-    # single-stage (r03), multi-stage (r04); the rc=124 round skipped
-    assert doc["rounds"] == [2, 3, 4]
-    assert [s["round"] for s in doc["skipped"]] == [1]
-    assert sorted(doc["stages"]) == ["coldboot", "sched_ab",
-                                     "sustained"]
-    assert [e["round"] for e in doc["stages"]["sched_ab"]] == [3, 4]
-
-
-def test_benchkeeper_write_and_check_roundtrip(tmp_path, capsys):
-    import shutil
-
-    import benchkeeper
-
-    for f in os.listdir(BENCHDIR):
-        if f.startswith("BENCH_r"):
-            shutil.copy(os.path.join(BENCHDIR, f), tmp_path / f)
-    assert benchkeeper.main(["--dir", str(tmp_path)]) == 0
-    capsys.readouterr()
-    assert (tmp_path / "BENCH_TRAJECTORY.json").exists()
-    assert benchkeeper.main(["--dir", str(tmp_path), "--check"]) == 0
-    capsys.readouterr()
-    # drift (a landed bench round without regeneration) fails closed
-    (tmp_path / "BENCH_r09.json").write_text(json.dumps({
-        "ok": True, "stage": "flood",
-        "result": {"metric": "m", "value": 1.0, "unit": "u",
-                   "stage": "flood"}}))
-    assert benchkeeper.main(["--dir", str(tmp_path), "--check"]) == 1
-    assert "BENCH802" in capsys.readouterr().out
-
-
-def test_benchkeeper_schema_violations_are_findings(tmp_path, capsys):
-    import benchkeeper
-
-    (tmp_path / "BENCH_r05.json").write_text(json.dumps({
-        "ok": True, "stage": "x",
-        "result": {"metric": "m", "value": "NOT A NUMBER",
-                   "unit": "u", "stage": "x"}}))
-    (tmp_path / "BENCH_r06.json").write_text("{not json")
-    (tmp_path / "BENCH_r08.json").write_text(json.dumps({
-        "ok": True, "round": 4, "stages": {}}))   # misnamed round
-    rc = benchkeeper.main(["--dir", str(tmp_path), "--json"])
-    err = capsys.readouterr().err
-    assert rc == 1
-    assert err.count("BENCH801") == 3
-    assert "BENCH_r08.json" in err and "misnamed" in err
-
-
-def test_repo_trajectory_covers_the_committed_bench_rounds():
-    """The committed BENCH_TRAJECTORY.json agrees with a regeneration
-    from the repo's BENCH_r*.json set for every round it covers — the
-    trajectory can no longer silently drift from the files it
-    aggregates. Deliberately TOLERANT of bench rounds newer than the
-    committed trajectory (the bench driver lands BENCH files between
-    sessions; `tools/benchkeeper.py --check` is the strict CI gate):
-    coverage of new rounds is the next regeneration's job, agreement
-    on covered rounds is this pin's."""
-    import benchkeeper
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    regen, _findings = benchkeeper.merge_bench_files(repo)
-    committed = json.load(open(os.path.join(repo,
-                                            "BENCH_TRAJECTORY.json")))
-    covered = set(committed["rounds"])
-    assert covered, "the committed trajectory is empty"
-    assert covered <= set(regen["rounds"])
-    for stage, series in committed["stages"].items():
-        regen_series = [e for e in regen["stages"].get(stage, ())
-                        if e["round"] in covered]
-        assert series == regen_series, stage

@@ -461,28 +461,6 @@ def test_stake_heartbeat_survives_chain_fault():
     drain(node)
 
 
-# -- attention-impl boot gate (ISSUE satellite: ops/flash.py) --------------
-
-def test_boot_gates_nondefault_attention_impl():
-    from arbius_tpu.ops import flash
-
-    eng, tok, chain, node, mid = build_world()
-    m = node.registry.get(mid)
-    node.registry.register(RegisteredModel(
-        id=mid, template=m.template, runner=m.runner,
-        golden=({"prompt": "g", "negative_prompt": ""}, 1,
-                "0x1220" + "00" * 32)))
-    prior = flash.set_attention_impl("einsum")
-    try:
-        # a non-default reduction order may only mine if the self-test
-        # proves the goldens still hold — skipping it must fail the boot
-        with pytest.raises(BootError, match="ARBIUS_ATTN_IMPL"):
-            node.boot(skip_self_test=True)
-    finally:
-        flash.set_attention_impl(prior)
-    assert flash.attention_impl() == prior
-
-
 def test_get_jobs_orders_priority_desc_then_id_asc():
     """The fleet reclaim path leans on this ordering (docs/fleet.md):
     priority DESC, insertion id ASC on ties — a re-queued job never
