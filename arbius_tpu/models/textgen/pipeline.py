@@ -136,6 +136,12 @@ class TextGenPipeline:
         rows = self.config.layers * (prompt_bucket + decode_bucket)
         return rows, rows
 
+    def attn_kernel(self, batch: int, prompt_bucket: int) -> tuple:
+        """(prefill attention calls a bucket serves with a Pallas kernel,
+        key blocks those calls walk, blocks of their unmasked grids at
+        the same tiles): none here, prefill attention is XLA's."""
+        return 0, 0, 0
+
     # -- bucket policy ---------------------------------------------------
     def prompt_bucket_for(self, prompt: str) -> int:
         """Smallest configured prompt edge that fits bos+bytes+eos;

@@ -32,7 +32,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from arbius_tpu.ops.blockwise import blockwise_attention
+from arbius_tpu.ops.causal_flash import causal_attention
 
 _NEG = -1e30
 F32 = jnp.float32
@@ -410,7 +410,11 @@ def _prefill_piece(params, ids, total: int, cfg: TrinityConfig):
         lp = params[f"layer_{i}"]
         h = rms_norm(x, lp["input_norm"]["scale"], cfg.eps)
         q, k, v, g = _qkvg(h, lp["attn"], cfg, pos, attn_kind)
-        o = blockwise_attention(
+        # the path is read off the call (ops/causal_flash.py): the Pallas
+        # kernel on a TPU at long prompts, else the XLA walk. No GSPMD
+        # program reaches it — trinity ships no mesh layout; one would
+        # need `ops.flash.on_mesh`'s treatment (ROADMAP D10)
+        o = causal_attention(
             q, k, v, window=cfg.window if attn_kind == "sliding" else None)
         a = _attn_out(o, g, lp["attn"], cfg)
         x = x + rms_norm(a, lp["post_attn_norm"]["scale"], cfg.eps)

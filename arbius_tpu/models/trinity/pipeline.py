@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from arbius_tpu.models.textgen.pipeline import TextGenPipeline
 from arbius_tpu.models.trinity import model as trinity
 from arbius_tpu.models.trinity.model import TrinityConfig
+from arbius_tpu.ops import causal_flash
 
 
 class TrinityPipeline(TextGenPipeline):
@@ -86,6 +87,22 @@ class TrinityPipeline(TextGenPipeline):
 
     def kv_rows(self, prompt_bucket: int, decode_bucket: int) -> tuple:
         return self.config.kv_rows(prompt_bucket + decode_bucket)
+
+    def attn_kernel(self, batch: int, prompt_bucket: int) -> tuple:
+        """Static, from the rule `ops.causal_flash.causal_attention`
+        reads off the same shapes: one call a layer a sequence (prefill
+        walks the batch a sequence at a time), each over every KV head."""
+        cfg = self.config
+        if not causal_flash.kernel_serves(prompt_bucket):
+            return 0, 0, 0
+        walked = dense = 0
+        for _, attn in cfg.layers:
+            w, n = causal_flash.walk_blocks(
+                prompt_bucket, cfg.window if attn == "sliding" else None,
+                cfg.group)
+            walked, dense = walked + w, dense + n
+        heads = batch * cfg.kv_heads
+        return batch * len(cfg.layers), heads * walked, heads * dense
 
     def _init_fn(self):
         return lambda key: trinity.init_params(self.config, key)

@@ -603,10 +603,13 @@ class TextGenRunner:
         pb, db = self._buckets_of(first)
         batch = len(items)
         held, full = self.pipeline.kv_rows(pb, db)
+        calls, blocks, dense = self.pipeline.attn_kernel(batch, pb)
         count_text_tokens(prefill=batch * pb, decode=batch * db)
         with span("text.bucket", model=self.pipeline.FAMILY,
                   prompt_bucket=pb, decode_bucket=db, batch=batch,
-                  kv_rows=held, kv_rows_full=full):
+                  kv_rows=held, kv_rows_full=full,
+                  attn_kernel_calls=calls, attn_blocks=blocks,
+                  attn_blocks_dense=dense):
             out = self.pipeline.generate(
                 self.params,
                 prompts=[str(h.get("prompt", "")) for h, _ in items],

@@ -10,11 +10,15 @@ key block is never read). Scores and the softmax are float32; the
 probabilities go back to the operands' dtype for the value product
 (the zoo's f32-softmax convention, models/common.py).
 
-This is NOT the Pallas flash kernel (ops/flash.py), which knows no
-mask and no grouped heads and whose program must stay what the image
-families' goldens pin. A causal/windowed block-skipping kernel is a
-later perf_opt change (ROADMAP); this walk is the exact baseline it
-would be measured against.
+This is the side of `ops.causal_flash.causal_attention`'s rule that
+serves every call off the TPU and, on it, prompts under 2,048 positions
+— every tier-1 shape and the `goldens/graph/trinity.*` programs — and
+the exact reference the Pallas kernel of `ops/causal_flash.py` (the
+other side: long prompts on a TPU) is tested and measured against. The
+two share the mathematics and the precision policy and differ in the
+order of the softmax's sums. The unmasked flash kernel (ops/flash.py)
+knows no mask and no grouped heads; its program stays what the image
+families' goldens pin.
 """
 from __future__ import annotations
 

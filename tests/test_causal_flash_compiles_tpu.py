@@ -1,0 +1,64 @@
+"""The causal / sliding-window flash kernel at the text cell's two shapes,
+compiled for a described v5e chip (no chip attached): what Mosaic refuses
+here would cost chip time there. Topology inside a fixture
+(`on-chip-measurement` section 2), as tests/perfbench/
+test_flash_compiles_tpu.py does for the unmasked kernel."""
+from __future__ import annotations
+
+import os
+
+import pytest
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+# trinity-large-ep8 prefill, one sequence at the 8192 edge: 48 query heads
+# over 8 KV heads of 128; the full layer and a window-4096 layer
+@pytest.mark.parametrize("window", [None, 4096])
+def test_causal_flash_kernel_compiles_at_the_cells_shapes(
+        one_chip, no_persistent_cache, window):
+    import jax
+    import jax.numpy as jnp
+
+    from arbius_tpu.ops.causal_flash import causal_flash_attention
+
+    s, kv, g, d = 8192, 8, 6, 128
+    q = jax.ShapeDtypeStruct((1, s, kv, g, d), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, s, kv, d), jnp.bfloat16, sharding=one_chip)
+    lowered = jax.jit(
+        lambda q, k, v: causal_flash_attention(q, k, v, window=window)
+    ).lower(q, k, k)
+    assert "tpu_custom_call" in lowered.as_text()
+    compiled = lowered.compile()
+    assert compiled.memory_analysis().output_size_in_bytes \
+        == s * kv * g * d * 2

@@ -168,6 +168,27 @@ def test_runner_prepare_hydrated_stamps_buckets(pipe, params):
     assert "_prompt_bucket" not in {"prompt": "hi"}
 
 
+def test_text_bucket_span_carries_the_attention_kernels_counts(pipe, params):
+    """`text.bucket` (docs/observability.md) states what a Pallas prefill
+    attention kernel serves of the bucket: this family has none, so the
+    three counts are there and read 0, as trinity's do off the TPU."""
+    from arbius_tpu.obs import Obs, use_obs
+
+    assert pipe.attn_kernel(2, 8) == (0, 0, 0)
+    r = TextGenRunner(pipe, params)
+    obs = Obs()
+    h = r.prepare_hydrated({"prompt": "hi", "max_new_tokens": 4})
+    with use_obs(obs):
+        r.dispatch([(h, 1), (h, 2)])
+    (ev,) = [e for e in obs.journal.events()
+             if e.get("kind") == "span" and e["name"] == "text.bucket"]
+    a = ev["attrs"]
+    assert (a["batch"], a["prompt_bucket"], a["decode_bucket"]) == (2, 8, 4)
+    assert a["kv_rows"] == a["kv_rows_full"]
+    assert (a["attn_kernel_calls"], a["attn_blocks"],
+            a["attn_blocks_dense"]) == (0, 0, 0)
+
+
 def test_chunk_items_ragged_bucket_padding():
     items = [({"i": n}, n) for n in range(5)]
     chunks = chunk_items(items, 2)

@@ -347,6 +347,10 @@ def test_greedy_cids_equal_with_the_staged_executor_on_and_off():
         assert (a["model"], a["prompt_bucket"], a["decode_bucket"],
                 a["batch"]) == ("trinity", 32, T, 2)
         assert (a["kv_rows"], a["kv_rows_full"]) == cfg.kv_rows(32 + T)
+        # off the TPU the walk serves every prefill attention call: the
+        # kernel's three counts are there and read nothing
+        assert (a["attn_kernel_calls"], a["attn_blocks"],
+                a["attn_blocks_dense"]) == (0, 0, 0)
         made = 2 * (32 + T - 1) * 2 * 4
         assert all(s["attrs"]["assignments"] == s["attrs"]["held"] == made
                    for s in routed)
