@@ -47,8 +47,11 @@ def build(arch: dict, precision: str):
 
 def kernel_calls(attn_calls):
     """The reference's attention calls that the program serves with its
-    flash kernel: `models.common.Attention` without a mask at 1024 query
-    rows or more — here only MOVQ's mid-block self-attention. The
-    decoder's added-KV attention (keys = 10 context tokens + the spatial
-    tokens) and the prior's masked attention are einsum in the program."""
-    return [c for c in attn_calls if c[2] >= 1024 and c[3] == c[2]]
+    flash kernel: every unmasked call of 1024 query rows or more, the
+    rule `ops.flash.attention` itself applies to `AttnAddedKV` and to
+    `models.common.Attention` alike — the decoder's added-KV attention
+    at 2304 queries x 2314 keys (10 context tokens + the spatial tokens)
+    and MOVQ's mid-block self-attention. The decoder's lower levels (576
+    and 144 rows) and the prior (81 tokens, masked) are einsum in the
+    program."""
+    return [c for c in attn_calls if c[2] >= 1024]

@@ -84,7 +84,45 @@ def gaps(ref: np.ndarray, ids: np.ndarray) -> dict:
 
 
 def kernel_calls(attn_calls):
-    """The program's prefill attention walks query blocks in XLA and its
-    decode attention is an einsum over the caches: the unmasked flash
-    kernel is not called."""
+    """The reference makes no unmasked attention call, and the program
+    never calls the unmasked flash kernel (`ops/flash.py`): its prefill
+    attention is the causal kernel below, its decode attention an einsum
+    over the caches."""
     return []
+
+
+# query positions from which the program's prefill takes its causal /
+# sliding-window kernel on the TPU (`ops/causal_flash.py`, PR 31)
+CAUSAL_KERNEL_MIN_ROWS = 2048
+
+
+def causal_kernel_calls(masked_attn_calls, arch: dict, task: dict):
+    """The reference's masked attention that the program serves with its
+    causal kernel (`causal_flash_attention`): prefill's — the query rows
+    of the prompt bucket, where that has CAUSAL_KERNEL_MIN_ROWS positions
+    or more — as one call a layer, (b, h, s, s, d, pairs), `pairs` being
+    the (query, key) pairs the layer's mask leaves there. The reference
+    walks a layer's positions, prompt bucket and decode bucket in one
+    pass, in blocks of query rows from the first; a block that ends
+    inside the prompt bucket is prefill's, the rows after it are decode's
+    (an einsum over the caches in the program, no kernel). The work is
+    the algorithm's: neither the blocks the kernel visits, which would
+    read a tile choice as work, nor s x s, which counts what the mask
+    takes away."""
+    edge = reference.prompt_bucket(arch, task.get("prompt", ""))
+    if edge < CAUSAL_KERNEL_MIN_ROWS:
+        return []
+    total = edge + reference.decode_bucket(
+        arch, int(task["max_new_tokens"])) - 1
+    out, row, pairs = [], 0, 0
+    for b, h, sq, _sk, d, n in masked_attn_calls:
+        row += sq
+        if row <= edge:
+            pairs += n
+        if row == total:        # the layer's last block
+            out.append((b, h, edge, edge, d, pairs))
+            row = pairs = 0
+    if row:
+        raise ValueError("the masked calls do not add up to whole layers "
+                         f"of {total} positions")
+    return out

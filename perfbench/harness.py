@@ -35,6 +35,7 @@ class Run:
         self.setup_s = 0.0
         self.flops_per_solution: dict = {}
         self.parts: dict = {}            # flops.count_parts per model
+        self.first_task: dict = {}       # ... and the task counted, hydrated
 
 
 def _note(t0):
@@ -228,7 +229,7 @@ def run_cell(args, t_start: float) -> tuple[int, dict | None]:
                               if t["model"] == m.template), None)
                 if first is None:
                     continue
-                task = m.hydrated(first)
+                task = run.first_task[m.template] = m.hydrated(first)
                 run.parts[m.template] = {
                     b: flops.count_parts(m.family.reference, m.arch, task,
                                          m.params, batch=b)
@@ -260,6 +261,20 @@ def run_cell(args, t_start: float) -> tuple[int, dict | None]:
                 else:
                     value = cell.reader(m["name"])(run)
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        # An untraced line says where a lost tick went, under a key the
+        # driver does not read: each tick's seconds, and what the readers
+        # of the program's own spans and counts give over the whole window
+        # (no device metric: the trace is not taken; off the chip only
+        # counts, as in the traced line)
+        detail = None
+        if not args.trace:
+            detail = {"tick_s": win["tick_s"]}
+            for m in cell.per_layer():
+                if m["source"] == "program_counter" or (
+                        on_chip and m["source"] == "program_span"):
+                    value = cell.reader(m["name"])(run)
+                    if value is not None:
+                        detail[m["name"]] = value
 
         # ---- correct: the reference has the chip now --------------------
         sysm.free_program()
@@ -309,6 +324,8 @@ def run_cell(args, t_start: float) -> tuple[int, dict | None]:
             result["trace_aligned_by"] = run.trace["aligned_by"]
         if missing:
             result["not_compared"] = missing
+        if detail is not None:
+            result["window_detail"] = detail
         result["compared"] = compared
         for name, c in compared.items():
             print(f"compared {name}: {c['value']:.6g} (limit {c['limit']:g})"

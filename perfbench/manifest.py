@@ -25,8 +25,13 @@ What a family file defines (a configuration's model names its family):
                   task `rec` against the reference on `model.params`;
                   with `control`, the reference in that lower precision
                   stands in the served answer's place
-    kernel_calls(attn_calls) -> those of the reference's attention calls
-                  that the program serves with its kernel
+    kernel_calls(attn_calls) -> those of the reference's unmasked
+                  attention calls that the program serves with its flash
+                  kernel (`flash_roofline_pct`)
+    causal_kernel_calls(masked_attn_calls, arch, task)   (optional) ->
+                  the reference's masked attention that the program
+                  serves with its causal kernel, a call a layer with the
+                  pairs its mask leaves (`causal_flash_roofline_pct`)
 """
 from __future__ import annotations
 

@@ -2,7 +2,8 @@
 
 `MiningConfig` (from the configuration file's `node` block, through the
 program's own `load_config`) -> `ModelRegistry` of the program's runners
-over weights the benchmark made from `--seed` -> `MinerNode(LocalChain(
+over weights the benchmark made from the configuration's `weights.seed`
+(`--seed` where the file states none) -> `MinerNode(LocalChain(
 Engine))` -> `boot()` -> `tick()`. Tasks enter by `Engine.submit_task`
 and leave as commitments and revealed solutions on the engine. Copied in
 pattern from `chip_smoke.py` (which later PRs may change); imports the
@@ -116,7 +117,12 @@ class System:
             shapes = jax.eval_shape(
                 lambda p=pipe: p.init_params(seed=0, dtype=dtype))
             m.n_params = weights.count(shapes)
-            m.params = weights.make(shapes, self.seed * 16 + i,
+            # one stated draw where the file gives `weights.seed` (a text
+            # model's expert load is drawn with its weights: PERF.md
+            # section 4), else a draw a run; `--seed` drives the traffic
+            # and the sample compared either way
+            draw = self.config["weights"].get("seed", self.seed)
+            m.params = weights.make(shapes, int(draw) * 16 + i,
                                     self.config["weights"]["init"])
             jax.block_until_ready(m.params)
             registry.register(RegisteredModel(
@@ -195,26 +201,32 @@ class System:
     def window(self, gen: traffic.Traffic, seconds: float,
                on_first_tick=None) -> dict:
         """The measured window: the backlog stands at `t0`; the window
-        closes at the first moment at or after `seconds` at which no
-        dispatched bucket is in flight (a tick has returned)."""
+        closes at the first moment at or after `seconds`, and after the
+        traffic's `min_ticks` ticks, at which no dispatched bucket is in
+        flight (a tick has returned). A traced window (`on_first_tick`)
+        is not held open for `min_ticks`: its readers see the first tick
+        alone. `tick_s`: the seconds from each tick's backlog standing to
+        its last solution landed."""
+        least = gen.min_ticks if on_first_tick is None else 1
         pending = [self.submit(gen) for _ in range(gen.outstanding)]
         first = len(self.submitted) - len(pending)
         t0 = time.perf_counter()
-        ticks = 0
+        tick_s = []
         while True:
             for rec in pending:
-                rec["tick"] = ticks
+                rec["tick"] = len(tick_s)
+            began = time.perf_counter()
             pending = self.drain(pending)
-            ticks += 1
             t1 = time.perf_counter()
-            if ticks == 1 and on_first_tick is not None:
+            tick_s.append(t1 - began)
+            if len(tick_s) == 1 and on_first_tick is not None:
                 on_first_tick()
-            if t1 - t0 >= seconds or pending:
+            if pending or (t1 - t0 >= seconds and len(tick_s) >= least):
                 break
             pending = [self.submit(gen) for _ in range(gen.outstanding)]
         tasks = self.submitted[first:]
-        return {"t0": t0, "t1": t1, "ticks": ticks, "tasks": tasks,
-                "unsolved": pending}
+        return {"t0": t0, "t1": t1, "ticks": len(tick_s), "tick_s": tick_s,
+                "tasks": tasks, "unsolved": pending}
 
     def failed_jobs(self) -> list:
         return [m for m, _ in self.node.db.failed_jobs()]

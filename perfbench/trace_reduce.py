@@ -133,6 +133,30 @@ def kernel_events(events, pattern: str):
     return [ev for ev in events if rx.search(base_name(ev[0]))]
 
 
+def kernel_roofline_pct(run, pattern: str, bucket_floor_s):
+    """A kernel's share of its roofline in the traced window, in percent:
+    the least seconds the chip could take for the calls the kernel serves
+    — `bucket_floor_s(model, parts)` for one bucket program of a model at
+    the canonical batch (`parts`: flops.count_parts), once for each
+    `bench.dispatch` span of that model — over the device time of the
+    events `pattern` finds. Nothing to read (no trace, no such event, no
+    such call): None."""
+    if run.trace is None or run.peaks is None:
+        return None
+    spent = sum(d for _, _, d in kernel_events(run.trace["events"], pattern))
+    if not spent:
+        return None
+    batch = run.system.canonical_batch
+    per_model = {}
+    for m in run.system.models:
+        parts = run.parts.get(m.template, {}).get(batch)
+        if parts is not None:
+            per_model[m.template] = bucket_floor_s(m, parts)
+    floor = sum(per_model.get(s["attrs"].get("model"), 0.0)
+                for s in run.spans if s["name"] == "bench.dispatch")
+    return 100.0 * floor / spent if floor else None
+
+
 def name_gaps(gaps, host_spans: list[dict], to_host_clock, n: int = 10):
     """[[span name, seconds]]: the longest idle gaps, each named by the
     innermost host span that covers the gap's middle (`to_host_clock`

@@ -17,6 +17,15 @@ A traffic file states (all keys required unless marked):
                  model works in: the same word stream, cut to a length in
                  bytes drawn uniformly from the range (lo at least
                  MIN_PROMPT_BYTES, so the task's index is never cut)
+    min_ticks    (optional, 1 where absent) the least number of ticks —
+                 of times the backlog is solved whole — the measured
+                 window holds: it closes at the first `tick()` return at
+                 or after `--seconds` and after this many ticks, so a cell
+                 whose tick is about as long as `--seconds` measures the
+                 same number of ticks in every run, and one tick that
+                 loses seconds cannot halve its window. A `--trace 1`
+                 run, whose readers see its first tick alone, closes at
+                 `--seconds` whatever this says
     check        {"buckets": {<template>: n}}: how many whole buckets of
                  each model's finished tasks, every slot of each, are
                  compared with the plain reference (perfbench/correct.py)
@@ -43,6 +52,10 @@ class Traffic:
                              "'closed' is implemented")
         self.spec = spec
         self.outstanding = int(spec["outstanding"])
+        self.min_ticks = int(spec.get("min_ticks", 1))
+        if self.min_ticks < 1:
+            raise ValueError(f"traffic min_ticks {spec['min_ticks']!r}: "
+                             "a window holds at least one tick")
         self.cycle = [(c["model"], int(c["count"])) for c in spec["cycle"]]
         for model, t in spec["tasks"].items():
             if ("prompt_words" in t) == ("prompt_bytes" in t):

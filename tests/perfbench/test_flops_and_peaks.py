@@ -170,9 +170,41 @@ def test_full_size_solution_flops(name, lo, hi):
         assert (8, 8, 9216, 9216, 40) in calls and (8, 8, 9216, 77, 40) in calls
         assert (8, 8, 2304, 2304, 80) in calls
     else:
-        assert calls == []      # added-KV attention is einsum in the program
+        # level 1's added-KV attention (10 context tokens beside the 2304
+        # spatial ones) is a kernel call since PR 29: 7 a UNet forward; the
+        # 576- and 144-row levels are under the program's 1024-row rule
+        assert calls == [(8, 12, 2304, 2314, 64)] * 7
         assert fam.kernel_calls(four["movq"]["attn_calls"]) \
             == [(4, 1, 9216, 9216, 512)]
+
+
+@pytest.mark.parametrize("family", ["anythingv3", "kandinsky2", "trinity"])
+def test_a_familys_copied_kernel_threshold_is_the_programs(family):
+    """A family states by hand from how many query rows the program takes
+    its kernel (the benchmark's count imports nothing of the program);
+    this pins each copy to the program's own rule, so that a change of the
+    rule turns a test red and not a roofline share silently wrong."""
+    from arbius_tpu.ops import causal_flash, flash
+
+    from perfbench import manifest
+
+    fam = manifest.family(family)
+    if family == "trinity":
+        assert fam.CAUSAL_KERNEL_MIN_ROWS == causal_flash._KERNEL_MIN_ROWS
+        n = causal_flash._KERNEL_MIN_ROWS
+        arch = {"prompt_buckets": [n - 1, n], "decode_buckets": [2]}
+        task = {"max_new_tokens": 2}
+        assert fam.causal_kernel_calls(
+            [(1, 1, n - 1, n - 1, 8, 5), (1, 1, 1, n, 8, 1)], arch,
+            {**task, "prompt": "x"}) == []
+        assert fam.causal_kernel_calls(
+            [(1, 1, n, n, 8, 5), (1, 1, 1, n + 1, 8, 1)], arch,
+            {**task, "prompt": "x" * (n - 2)}) == [(1, 1, n, n, 8, 5)]
+        assert fam.kernel_calls([(1, 1, 4096, 4096, 8)]) == []
+    else:
+        n = flash._KERNEL_MIN_ROWS
+        under, at = (2, 4, n - 1, n - 1, 64), (2, 4, n, 77, 64)
+        assert fam.kernel_calls([under, at, under]) == [at]
 
 
 def test_attention_floor_says_which_bound_binds():
@@ -226,12 +258,20 @@ def test_flash_roofline_reader_on_the_mix_cells_shapes():
                             ("fusion.12", 47.0, 11.0)]}
     read = cell.reader("flash_roofline_pct")
     # anythingv3: 20 UNet calls and one VAE call of kernel attention a
-    # bucket; kandinsky2: MOVQ's mid-block alone
+    # bucket; kandinsky2: 50 decoder calls of 7 added-KV calls each (the
+    # CFG pair of the batch: b = 2 x 2) and MOVQ's mid-block
     unet = run.parts["anythingv3"][batch]["unet"]
     floor_unet = sum(flops.attention_floor_seconds(*c, run.peaks)[0]
                      for c in unet["attn_calls"] if c[2] >= 1024)
     assert unet["calls"] == 20 and 0.01 < floor_unet < 0.02
+    dec = run.parts["kandinsky2"][batch]["decoder"]
+    added_kv = (2 * batch, 12, 2304, 2314, 64)
+    assert dec["calls"] == 50 and dec["attn_calls"].count(added_kv) == 7
+    floor_dec = 7 * flops.attention_floor_seconds(*added_kv, run.peaks)[0]
+    assert floor_dec == pytest.approx(7 * 4 * 4 * 12 * 2304 * 2314 * 64
+                                      / 197e12)
     value = read(run)
-    assert 7 * 20 * floor_unet / 47.0 < value / 100 < 0.05
+    least = (7 * 20 * floor_unet + 2 * 50 * floor_dec) / 47.0
+    assert least < value / 100 < 1.1 * least
     run.trace = {"events": [("fusion.12", 0.0, 11.0)]}
     assert read(run) is None

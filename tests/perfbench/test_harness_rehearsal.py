@@ -71,11 +71,17 @@ def test_no_device_metric_is_printed_off_the_chip(lines):
         manifest = json.load(f)
     counts = {m["name"] for m in manifest["per_layer"]
               if m["source"] == "program_counter"}
-    assert lines["tiny-k2-backlog"][0]["metrics"] == {}      # --trace 0
+    untraced = lines["tiny-k2-backlog"][0]                   # --trace 0
+    assert untraced["metrics"] == {}
+    # ... whose line says its ticks and, off the chip, the counts alone
+    detail = untraced["window_detail"]
+    assert len(detail["tick_s"]) == untraced["ticks"]
+    assert set(detail) - {"tick_s"} <= counts and "padded_slot_pct" in detail
     for cell in ("tiny-mix-backlog", "tiny-text-backlog"):
         traced = lines[cell][0]
         assert set(traced["metrics"]) == counts
         assert "busy_s" not in traced["device"] and "breakdown" not in traced
+        assert "window_detail" not in traced
     traced = lines["tiny-mix-backlog"][0]
     # the mix's under-filled buckets show in the one count there is
     assert traced["metrics"]["padded_slot_pct"]["value"] == 0.0 \

@@ -79,6 +79,36 @@ def test_every_cell_finds_its_files_by_name(bench):
             for name in ("OUT_NAME", "build", "reference", "decode",
                          "compare", "kernel_calls"):
                 assert hasattr(fam, name), (m["family"], name)
+    # a metric that names its cells is read in those cells alone, and each
+    # of them reports the end-to-end metric it moves
+    cells = {w["name"] for w in bench["workloads"]}
+    for m in bench["per_layer"]:
+        assert set(m.get("workloads", cells)) <= cells
+        assert os.path.exists(os.path.join(ROOT, "perfbench", "metrics",
+                                           m["name"] + ".py"))
+    causal = next(m for m in bench["per_layer"]
+                  if m["name"] == "causal_flash_roofline_pct")
+    assert causal["workloads"] == ["trinity-ep8-8k-backlog"]
+    assert (causal["layer"], causal["moves"], causal["source"]) \
+        == ("kernels", "sol_per_hour", "device_trace")
+    text = mf.Cell(mf.DEFAULT_MANIFEST, "trinity-ep8-8k-backlog")
+    assert callable(text.family("trinity").causal_kernel_calls)
+
+
+@pytest.mark.parametrize("name", ["backlog8-768", "backlog16-13to3-768",
+                                  "backlog32-8k-256"])
+def test_accepted_traffic_files_build_and_state_their_window(name):
+    from perfbench.traffic import Traffic
+
+    with open(os.path.join(ROOT, "perfbench", "traffic",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    gen = Traffic(spec, 2**31 + 77)
+    assert gen.min_ticks == spec.get("min_ticks", 1) >= 1
+    assert set(spec) <= {"loop", "outstanding", "min_ticks", "cycle",
+                         "tasks", "check"}
+    assert sum(n for _, n in gen.cycle) % gen.outstanding == 0 \
+        or gen.outstanding % sum(n for _, n in gen.cycle) == 0
 
 
 def test_a_cell_and_a_metric_are_added_as_files_only(tmp_path, bench):
