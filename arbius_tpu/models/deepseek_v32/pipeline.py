@@ -1,0 +1,68 @@
+"""deepseek_v32 pipeline — TextGenPipeline's bucket policy, decode loop,
+samplers and seed chain over the DeepSeek-V3.2-Exp model.
+
+As with trinity, nothing of the serving discipline is copied: a bucket
+is (batch, prompt edge, decode edge, sampler), ONE jitted program of
+prefill then the `lax.scan` decode loop with the caches as carry,
+prompts padded to the edge with eos and no padding mask, samplers over
+the byte ids alone. What this family brings is the model behind the loop
+(models/deepseek_v32/model.py): a latent cache and an indexer's key
+cache in the carry, a selection of keys in prefill and in every step,
+routed experts under a group limit; the bucket program returns its
+routers' int32 assignment counts beside the tokens, as trinity's does.
+"""
+from __future__ import annotations
+
+from arbius_tpu.models.deepseek_v32 import model as dsv32
+from arbius_tpu.models.deepseek_v32.model import DeepSeekV32Config
+from arbius_tpu.models.trinity.pipeline import (
+    SharePipeline,
+    share_trace_specs,
+)
+
+
+class DeepSeekV32Pipeline(SharePipeline):
+    FAMILY = "deepseek_v32"
+
+    def __init__(self, config: DeepSeekV32Config | None = None, mesh=None,
+                 precision: str = "bf16",
+                 prompt_buckets: tuple = (16384,),
+                 decode_buckets: tuple = (256,), top_k: int = 8):
+        super().__init__(config or DeepSeekV32Config.published(),
+                         mesh=mesh, precision=precision,
+                         prompt_buckets=prompt_buckets,
+                         decode_buckets=decode_buckets, top_k=top_k)
+
+    def _prefill(self, params, ids, total: int):
+        return dsv32.prefill(params, ids, total, self.config)
+
+    def _decode(self, params, tok, carry, pos):
+        return dsv32.decode(params, tok, carry, pos, self.config)
+
+    def bucket_attrs(self, batch: int, prompt_bucket: int,
+                     decode_bucket: int) -> dict:
+        """One sequence's cache bytes beside what per-head K and V rows
+        would take, and the pairs the selection leaves to attention
+        beside the causal mask's — static, from the config."""
+        cfg = self.config
+        held, per_head = cfg.cache_bytes(prompt_bucket + decode_bucket)
+        pairs, causal = cfg.attn_pairs(prompt_bucket, decode_bucket)
+        return {"cache_bytes": held, "cache_bytes_per_head": per_head,
+                "attn_pairs": pairs, "attn_pairs_causal": causal}
+
+    def _init_fn(self):
+        return lambda key: dsv32.init_params(self.config, key)
+
+
+MESH_LAYOUTS: tuple[tuple[str, ...], ...] = ()
+
+
+def trace_specs():
+    """graphlint trace specs at the tiny whole-model config: prefill,
+    the decode loop (greedy and seeded top-k) and the composed bucket
+    program — the prompt edge longer than the tiny `index_topk`, so the
+    selection is in the goldened graphs of both phases."""
+    return share_trace_specs(
+        "deepseek_v32", lambda: DeepSeekV32Pipeline(
+            DeepSeekV32Config.tiny(), prompt_buckets=(12,),
+            decode_buckets=(4,), top_k=4), 12, 4)

@@ -142,6 +142,19 @@ class TextGenPipeline:
         the same tiles): none here, prefill attention is XLA's."""
         return 0, 0, 0
 
+    def bucket_attrs(self, batch: int, prompt_bucket: int,
+                     decode_bucket: int) -> dict:
+        """What the runner's `text.bucket` span says of a bucket beside
+        its edges and batch (docs/observability.md): static, from the
+        shapes — here the cache rows and the prefill kernel's counts; a
+        family whose cache or attention is of another kind states its
+        own quantities."""
+        held, full = self.kv_rows(prompt_bucket, decode_bucket)
+        calls, blocks, dense = self.attn_kernel(batch, prompt_bucket)
+        return {"kv_rows": held, "kv_rows_full": full,
+                "attn_kernel_calls": calls, "attn_blocks": blocks,
+                "attn_blocks_dense": dense}
+
     # -- bucket policy ---------------------------------------------------
     def prompt_bucket_for(self, prompt: str) -> int:
         """Smallest configured prompt edge that fits bos+bytes+eos;

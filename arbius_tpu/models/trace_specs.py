@@ -95,6 +95,7 @@ def all_trace_specs() -> list[TraceSpec]:
     time the model zoo is pulled in — the analysis CLI stays importable
     without jax/flax side effects until it actually audits.
     """
+    from arbius_tpu.models.deepseek_v32 import pipeline as dsv32_pipeline
     from arbius_tpu.models.kandinsky2 import pipeline as kandinsky2_pipeline
     from arbius_tpu.models.rvm import pipeline as rvm_pipeline
     from arbius_tpu.models.sd15 import pipeline as sd15_pipeline
@@ -106,6 +107,6 @@ def all_trace_specs() -> list[TraceSpec]:
     specs: list[TraceSpec] = []
     for mod in (sd15_pipeline, kandinsky2_pipeline, rvm_pipeline,
                 video_pipeline, textgen_pipeline, trinity_pipeline,
-                meshsolve):
+                dsv32_pipeline, meshsolve):
         specs.extend(mod.trace_specs())
     return validate_specs(specs)

@@ -189,10 +189,14 @@ def param_shapes(cfg: TrinityConfig) -> dict:
 
 
 def init_params(cfg: TrinityConfig, key):
-    """Seeded random tree (float32; the pipeline casts): kernels
-    N(0, 1/fan_in), embeddings N(0, 0.02²) as the source initialises
-    them, gains 1, biases 0."""
-    shapes = param_shapes(cfg)
+    """Seeded random tree for this config (`init_tree`)."""
+    return init_tree(param_shapes(cfg), key)
+
+
+def init_tree(shapes: dict, key):
+    """Seeded random tree (float32; the pipeline casts) for a
+    {path: shape} layout: kernels N(0, 1/fan_in), embeddings
+    N(0, 0.02²) as the source initialises them, gains 1, biases 0."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
     leaves = []

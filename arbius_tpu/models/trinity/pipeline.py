@@ -21,28 +21,34 @@ from arbius_tpu.models.trinity.model import TrinityConfig
 from arbius_tpu.ops import causal_flash
 
 
-class TrinityPipeline(TextGenPipeline):
-    FAMILY = "trinity"
+class SharePipeline(TextGenPipeline):
+    """What the families that serve one chip's share of a model have in
+    common (trinity here, models/deepseek_v32): no mesh layout, bf16
+    only, the byte tokenizer's ids inside the vocabulary rows held, the
+    samplers over those ids alone, the model a set of pure functions of
+    the param tree, and the routers' counts beside the tokens. A family
+    names itself, its default config and edges, `_prefill`, `_decode`
+    and `_init_fn`."""
+
     # ids the byte tokenizer turns into text, one byte each. No
     # vocabulary file of the model's is in the tree, so an id past them
     # is no text: the samplers see the byte ids' logits alone, and a
     # task always spends its whole budget (no eos is ever sampled).
     BYTE_IDS = 256
 
-    def __init__(self, config: TrinityConfig | None = None, mesh=None,
-                 precision: str = "bf16",
+    def __init__(self, config, mesh=None, precision: str = "bf16",
                  prompt_buckets: tuple = (8192,),
                  decode_buckets: tuple = (256,), top_k: int = 8):
         if mesh is not None:
             raise ValueError(
-                "trinity ships no mesh layout: its expert axis across "
-                "chips is a determinism class of its own (ROADMAP R4)")
+                f"{self.FAMILY} ships no mesh layout: its expert axis "
+                "across chips is a determinism class of its own "
+                "(ROADMAP R4)")
         if precision != "bf16":
             raise ValueError(
                 f"precision mode {precision!r} is not shipped for the "
-                "trinity family — it serves bf16 only")
-        super().__init__(config or TrinityConfig.published(), mesh=None,
-                         precision=precision,
+                f"{self.FAMILY} family — it serves bf16 only")
+        super().__init__(config, mesh=None, precision=precision,
                          prompt_buckets=prompt_buckets,
                          decode_buckets=decode_buckets, top_k=top_k)
         lo, hi = self.config.vocab_rows
@@ -55,15 +61,8 @@ class TrinityPipeline(TextGenPipeline):
             raise ValueError(f"top_k ({self.top_k}) exceeds the "
                              f"{self.BYTE_IDS} byte ids sampled over")
 
-    # -- the model behind the loop -----------------------------------------
     def _make_model(self):
         return None     # pure functions of the param tree, no module
-
-    def _prefill(self, params, ids, total: int):
-        return trinity.prefill(params, ids, total, self.config)
-
-    def _decode(self, params, tok, carry, pos):
-        return trinity.decode(params, tok, carry, pos, self.config)
 
     def _sampler_fn(self, sampler: str):
         """The shared samplers over the byte ids alone: every other
@@ -84,6 +83,26 @@ class TrinityPipeline(TextGenPipeline):
         """(tokens[B, T], routed int32 [assignments made, on held
         experts]) — the counts are part of the goldened program."""
         return tokens, carry[1]
+
+
+class TrinityPipeline(SharePipeline):
+    FAMILY = "trinity"
+
+    def __init__(self, config: TrinityConfig | None = None, mesh=None,
+                 precision: str = "bf16",
+                 prompt_buckets: tuple = (8192,),
+                 decode_buckets: tuple = (256,), top_k: int = 8):
+        super().__init__(config or TrinityConfig.published(), mesh=mesh,
+                         precision=precision,
+                         prompt_buckets=prompt_buckets,
+                         decode_buckets=decode_buckets, top_k=top_k)
+
+    # -- the model behind the loop -----------------------------------------
+    def _prefill(self, params, ids, total: int):
+        return trinity.prefill(params, ids, total, self.config)
+
+    def _decode(self, params, tok, carry, pos):
+        return trinity.decode(params, tok, carry, pos, self.config)
 
     def kv_rows(self, prompt_bucket: int, decode_bucket: int) -> tuple:
         return self.config.kv_rows(prompt_bucket + decode_bucket)
@@ -111,59 +130,63 @@ class TrinityPipeline(TextGenPipeline):
 MESH_LAYOUTS: tuple[tuple[str, ...], ...] = ()
 
 
-def trace_specs():
-    """graphlint trace specs at the tiny whole-model config: prefill,
-    the decode loop (greedy and seeded top-k) and the composed bucket
-    program — the prompt edge longer than the tiny window, so the ring
-    fill and the ring's write rule are in the goldened graphs."""
+def share_trace_specs(model: str, make_pipe, p: int, t: int):
+    """The four graphlint trace specs of a `SharePipeline` family at its
+    tiny whole-model config: prefill, the decode loop (greedy and seeded
+    top-k) and the composed bucket program, batch 2, edges (p, t)."""
     from arbius_tpu.models.trace_specs import TraceSpec
-
-    P, T = 12, 4
-
-    def make_pipe():
-        return TrinityPipeline(TrinityConfig.tiny(), prompt_buckets=(P,),
-                               decode_buckets=(T,), top_k=4)
 
     def abstract(pipe, batch):
         shapes = jax.eval_shape(
             lambda: pipe.init_params(seed=0, dtype="bfloat16"))
         sds = jax.ShapeDtypeStruct
-        return (shapes, sds((batch, P), jnp.int32),
+        return (shapes, sds((batch, p), jnp.int32),
                 sds((batch,), jnp.uint32), sds((batch,), jnp.uint32))
 
     def build_prefill():
         pipe = make_pipe()
         shapes, ids, _, _ = abstract(pipe, 2)
-        return pipe.prefill_program(2, P, T), (shapes, ids)
+        return pipe.prefill_program(2, p, t), (shapes, ids)
 
     def build_decode(sampler):
         def build():
             pipe = make_pipe()
             shapes, ids, lo, hi = abstract(pipe, 2)
-            _, carry = jax.eval_shape(pipe.prefill_program(2, P, T),
+            _, carry = jax.eval_shape(pipe.prefill_program(2, p, t),
                                       shapes, ids)
             t0 = jax.ShapeDtypeStruct((2,), jnp.int32)
-            return (pipe.decode_program(2, P, T, sampler),
+            return (pipe.decode_program(2, p, t, sampler),
                     (shapes, carry, t0, lo, hi))
 
         return build
 
     def build_generate():
         pipe = make_pipe()
-        return (pipe.compiled_bucket(2, P, T, "greedy"),
+        return (pipe.compiled_bucket(2, p, t, "greedy"),
                 abstract(pipe, 2))
 
-    bucket = f"b2.p{P}.t{T}"
+    bucket = f"b2.p{p}.t{t}"
     return [
-        TraceSpec(model="trinity", entry="prefill", bucket=bucket,
+        TraceSpec(model=model, entry="prefill", bucket=bucket,
                   mesh="single", dtype="bfloat16", build=build_prefill),
-        TraceSpec(model="trinity", entry="decode",
+        TraceSpec(model=model, entry="decode",
                   bucket=f"{bucket}.greedy", mesh="single",
                   dtype="bfloat16", build=build_decode("greedy")),
-        TraceSpec(model="trinity", entry="decode",
+        TraceSpec(model=model, entry="decode",
                   bucket=f"{bucket}.top_k", mesh="single",
                   dtype="bfloat16", build=build_decode("top_k")),
-        TraceSpec(model="trinity", entry="generate",
+        TraceSpec(model=model, entry="generate",
                   bucket=f"{bucket}.greedy", mesh="single",
                   dtype="bfloat16", build=build_generate),
     ]
+
+
+def trace_specs():
+    """graphlint trace specs at the tiny whole-model config: prefill,
+    the decode loop (greedy and seeded top-k) and the composed bucket
+    program — the prompt edge longer than the tiny window, so the ring
+    fill and the ring's write rule are in the goldened graphs."""
+    return share_trace_specs(
+        "trinity", lambda: TrinityPipeline(
+            TrinityConfig.tiny(), prompt_buckets=(12,),
+            decode_buckets=(4,), top_k=4), 12, 4)

@@ -253,29 +253,46 @@ def _textgen(m: ModelConfig, mesh, mode: str, tg):
     return TextGenRunner(pipe, _params_for(pipe, m))
 
 
-def _trinity(m: ModelConfig, mesh, mode: str, tg):
-    """trinity builder — TextGenRunner over the Trinity pipeline; the
-    bucket policy is the template's own entry of cfg.textgen, and
-    `share` says which experts, vocabulary rows and layers this chip
-    holds (nothing: the whole published model)."""
-    from arbius_tpu.models.trinity import TrinityConfig, TrinityPipeline
+def _share_family(m: ModelConfig, mesh, mode: str, tg, config_cls,
+                  pipe_cls):
+    """TextGenRunner over a family that serves one chip's share of a
+    model: the bucket policy is the template's own entry of
+    cfg.textgen, and `share` says which experts, vocabulary rows and
+    layers this chip holds (nothing: the whole published model)."""
     from arbius_tpu.node.solver import TextGenRunner
 
     try:
-        cfg = TrinityConfig.tiny(**tg.share) if m.tiny \
-            else replace(TrinityConfig.published(), **tg.share)
+        cfg = config_cls.tiny(**tg.share) if m.tiny \
+            else replace(config_cls.published(), **tg.share)
     except ValueError as e:
         raise ConfigError(f"textgen.share: {e}") from None
-    pipe = TrinityPipeline(cfg, mesh=mesh, precision=mode,
-                           prompt_buckets=tuple(tg.prompt_buckets),
-                           decode_buckets=tuple(tg.decode_buckets),
-                           top_k=tg.top_k)
+    pipe = pipe_cls(cfg, mesh=mesh, precision=mode,
+                    prompt_buckets=tuple(tg.prompt_buckets),
+                    decode_buckets=tuple(tg.decode_buckets),
+                    top_k=tg.top_k)
     return TextGenRunner(pipe, _params_for(pipe, m))
+
+
+def _trinity(m: ModelConfig, mesh, mode: str, tg):
+    from arbius_tpu.models.trinity import TrinityConfig, TrinityPipeline
+
+    return _share_family(m, mesh, mode, tg, TrinityConfig, TrinityPipeline)
+
+
+def _deepseek_v32(m: ModelConfig, mesh, mode: str, tg):
+    from arbius_tpu.models.deepseek_v32 import (
+        DeepSeekV32Config,
+        DeepSeekV32Pipeline,
+    )
+
+    return _share_family(m, mesh, mode, tg, DeepSeekV32Config,
+                         DeepSeekV32Pipeline)
 
 
 # text templates: builders that take the template's sequence-bucket
 # policy (cfg.textgen.for_template) on top of the common triple
-_TEXT_BUILDERS = {"textgen": _textgen, "trinity": _trinity}
+_TEXT_BUILDERS = {"textgen": _textgen, "trinity": _trinity,
+                  "deepseek_v32": _deepseek_v32}
 
 
 def _rvm(m: ModelConfig, mesh, resolve_file):

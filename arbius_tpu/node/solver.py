@@ -548,8 +548,36 @@ def count_moe_assignments(made: int, held: int) -> None:
         c.inc(made - held, held="no")
 
 
+def count_latent_cache(held: int, per_head: int) -> None:
+    """Bump `arbius_text_cache_bytes_total{form}`: the bytes a latent-
+    attention family's bucket carries as cache (`form="latent"`) and
+    what per-head K and V rows of every head would take for the same
+    positions (`form="per_head"`) — counted at dispatch from the
+    bucket's shape (docs/text-serving.md)."""
+    c = _counter("arbius_text_cache_bytes_total",
+                 "cache bytes of dispatched text buckets, as held and as "
+                 "per-head K/V rows would be", labelnames=("form",))
+    if c is not None:
+        c.inc(held, form="latent")
+        c.inc(per_head, form="per_head")
+
+
+def count_attn_pairs(kept: int, causal: int) -> None:
+    """Bump `arbius_attn_pairs_total{mask}`: the (query, key) pairs a
+    sparse-attention family's bucket leaves to attention's softmax
+    (`mask="selected"`) and the pairs the causal mask alone leaves
+    (`mask="causal"`), prompt rows and decode steps over every layer —
+    counted at dispatch from the bucket's shape."""
+    c = _counter("arbius_attn_pairs_total",
+                 "attention (query, key) pairs of dispatched text "
+                 "buckets, selected and causal", labelnames=("mask",))
+    if c is not None:
+        c.inc(kept, mask="selected")
+        c.inc(causal, mask="causal")
+
+
 class TextGenRunner:
-    """text-template runner (textgen, trinity): decoder-only LM → deterministic UTF-8.
+    """text-template runner (textgen, trinity, deepseek_v32): decoder-only LM → deterministic UTF-8.
 
     Template variables (templates/textgen.json): prompt,
     max_new_tokens, sampler (enum); output out-1.txt. The sequence
@@ -602,14 +630,16 @@ class TextGenRunner:
         first = items[0][0]
         pb, db = self._buckets_of(first)
         batch = len(items)
-        held, full = self.pipeline.kv_rows(pb, db)
-        calls, blocks, dense = self.pipeline.attn_kernel(batch, pb)
+        attrs = self.pipeline.bucket_attrs(batch, pb, db)
         count_text_tokens(prefill=batch * pb, decode=batch * db)
+        if "cache_bytes" in attrs:      # a latent, sparse family's own
+            count_latent_cache(batch * attrs["cache_bytes"],
+                               batch * attrs["cache_bytes_per_head"])
+            count_attn_pairs(batch * attrs["attn_pairs"],
+                             batch * attrs["attn_pairs_causal"])
         with span("text.bucket", model=self.pipeline.FAMILY,
                   prompt_bucket=pb, decode_bucket=db, batch=batch,
-                  kv_rows=held, kv_rows_full=full,
-                  attn_kernel_calls=calls, attn_blocks=blocks,
-                  attn_blocks_dense=dense):
+                  **attrs):
             out = self.pipeline.generate(
                 self.params,
                 prompts=[str(h.get("prompt", "")) for h, _ in items],
@@ -627,7 +657,7 @@ class TextGenRunner:
 
         out, budgets = dev
         # a family with expert layers returns its routers' counts
-        # beside the tokens (models/trinity)
+        # beside the tokens (models/trinity, models/deepseek_v32)
         tokens, routed = out if isinstance(out, tuple) else (out, None)
         with span("solve.encode", n=n_real, codec="text"):
             tokens = gather_canonical(tokens)
