@@ -34,7 +34,9 @@ Two formulations of one attention, read off the call:
            time over the key blocks up to its diagonal, under a running
            max / normaliser / accumulator, so neither a sequence's
            activations nor a whole score matrix ever sit beside the
-           weights;
+           weights — by `ops.selected_flash.selected_attention`, which
+           reads off the backend and the static shape whether the XLA
+           walk or the Pallas kernel attends;
   decode   the LATENT form: `W_UK` folded into the query, scores and the
            weighted sum taken on the cache's 576- and 512-wide rows,
            `W_UV` applied after. Equal in exact arithmetic.
@@ -59,6 +61,7 @@ from arbius_tpu.models.trinity.model import (
     routed_experts,
     swiglu,
 )
+from arbius_tpu.ops.selected_flash import selected_attention
 
 _NEG = -1e30
 F32 = jnp.float32
@@ -457,7 +460,7 @@ def _prefill_layer(x, lp, kind, cfg: DeepSeekV32Config):
     """One block on one sequence x[P, d] → (x', latent[P, 576],
     k_i[P, 128], held)."""
     p = x.shape[0]
-    nh, dn, dv = cfg.heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    nh, dn = cfg.heads, cfg.qk_nope_head_dim
     blk = _block(p, nh)
     n_blk = p // blk
     k_sel = min(cfg.index_topk, p)
@@ -467,10 +470,10 @@ def _prefill_layer(x, lp, kind, cfg: DeepSeekV32Config):
     c = cfg.kv_lora_rank
     # keys and values of every head, expanded from the latents once a
     # layer (the per-head form); the rotary key stays one row for all
-    kv = _dot(latent[:, :c], lp["attn"]["wkv_b"]["kernel"]).reshape(
-        p, nh, dn + dv)
+    attend = selected_attention(
+        _dot(latent[:, :c], lp["attn"]["wkv_b"]["kernel"]), nh, dn,
+        scale=cfg.softmax_scale)
     k_pe = latent[:, c:]
-    scale = cfg.softmax_scale
     rows = jnp.arange(blk)
 
     def block(i):
@@ -499,32 +502,7 @@ def _prefill_layer(x, lp, kind, cfg: DeepSeekV32Config):
         else:
             keep = jnp.ones((blk, p), bool)
 
-        def attend(j, carry):
-            m, l, acc = carry
-            kvb = jax.lax.dynamic_slice_in_dim(kv, j * blk, blk)
-            kpb = jax.lax.dynamic_slice_in_dim(k_pe, j * blk, blk)
-            s = jnp.einsum("qhd,khd->hqk", q_nope, kvb[..., :dn],
-                           preferred_element_type=F32) \
-                + jnp.einsum("qhd,kd->hqk", q_pe, kpb,
-                             preferred_element_type=F32)
-            ok = causal(j) & jax.lax.dynamic_slice_in_dim(keep, j * blk,
-                                                          blk, 1)
-            s = jnp.where(ok[None], s * scale, _NEG)
-            m_new = jnp.maximum(m, s.max(axis=-1))
-            pr = jnp.where(ok[None], jnp.exp(s - m_new[..., None]), 0.0)
-            fix = jnp.exp(m - m_new)
-            l = l * fix + pr.sum(axis=-1)
-            acc = acc * fix[..., None] + jnp.einsum(
-                "hqk,khd->hqd", pr.astype(kvb.dtype), kvb[..., dn:],
-                preferred_element_type=F32)
-            return m_new, l, acc
-
-        m0 = jnp.full((nh, blk), _NEG, F32)
-        _, l, acc = jax.lax.fori_loop(
-            0, i + 1, attend,
-            (m0, jnp.zeros((nh, blk), F32), jnp.zeros((nh, blk, dv), F32)))
-        o = (acc / l[..., None]).astype(x.dtype)
-        o = jnp.moveaxis(o, 0, 1).reshape(blk, nh * dv)
+        o = attend(q_nope, q_pe, k_pe, keep, i, rows, qpos)
         xb = xb + _dot(o, lp["attn"]["wo"]["kernel"])
         return _ffn(xb, lp, kind, cfg)
 

@@ -19,6 +19,7 @@ from arbius_tpu.models.trinity.pipeline import (
     SharePipeline,
     share_trace_specs,
 )
+from arbius_tpu.ops import selected_flash
 
 
 class DeepSeekV32Pipeline(SharePipeline):
@@ -39,16 +40,36 @@ class DeepSeekV32Pipeline(SharePipeline):
     def _decode(self, params, tok, carry, pos):
         return dsv32.decode(params, tok, carry, pos, self.config)
 
+    def attn_kernel(self, batch: int, prompt_bucket: int) -> tuple:
+        """Static, from the rule `ops.selected_flash.selected_attention`
+        reads off the same shapes: one call a block of query rows, a
+        layer, a sequence (prefill walks the batch a sequence at a time
+        and a sequence a block at a time), each over every group of
+        heads."""
+        cfg = self.config
+        if not selected_flash.kernel_serves(
+                prompt_bucket, cfg.qk_nope_head_dim, cfg.v_head_dim):
+            return 0, 0, 0
+        rows = dsv32._block(prompt_bucket, cfg.heads)
+        walked, dense = selected_flash.walk_blocks(prompt_bucket, rows,
+                                                   cfg.heads)
+        each = batch * len(cfg.layers)
+        return each * (prompt_bucket // rows), each * walked, each * dense
+
     def bucket_attrs(self, batch: int, prompt_bucket: int,
                      decode_bucket: int) -> dict:
         """One sequence's cache bytes beside what per-head K and V rows
-        would take, and the pairs the selection leaves to attention
-        beside the causal mask's — static, from the config."""
+        would take, the pairs the selection leaves to attention beside
+        the causal mask's, and the prefill kernel's counts under the
+        names trinity gives its own — static, from the config."""
         cfg = self.config
         held, per_head = cfg.cache_bytes(prompt_bucket + decode_bucket)
         pairs, causal = cfg.attn_pairs(prompt_bucket, decode_bucket)
+        calls, blocks, dense = self.attn_kernel(batch, prompt_bucket)
         return {"cache_bytes": held, "cache_bytes_per_head": per_head,
-                "attn_pairs": pairs, "attn_pairs_causal": causal}
+                "attn_pairs": pairs, "attn_pairs_causal": causal,
+                "attn_kernel_calls": calls, "attn_blocks": blocks,
+                "attn_blocks_dense": dense}
 
     def _init_fn(self):
         return lambda key: dsv32.init_params(self.config, key)

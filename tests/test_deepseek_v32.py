@@ -125,6 +125,48 @@ def test_prefill_then_decode_through_the_latent_cache_matches_full_forward(
         else 0 < int(stats[1]) < made
 
 
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                       ("bfloat16", 0.25)])
+def test_prefill_through_the_kernel_gives_the_walks_logits_and_caches(
+        dtype, tol, small_blocks, monkeypatch):
+    """The other side of `ops.selected_flash.selected_attention`'s rule,
+    forced here (`interpret=True`: the TPU's path on the CPU) at the tiny
+    config with the selection biting and three query blocks to the
+    prompt: the same logits and the same caches as the walk's, to the
+    order of the softmax's sums."""
+    from arbius_tpu.ops import selected_flash
+
+    over = {} if dtype == "float32" else {"layers": ("dense", "moe"),
+                                          "index_topk": 64}
+    cfg = DeepSeekV32Config.tiny(dtype=dtype, **over)
+    params = _params(cfg, dtype=dtype)
+    ids = jax.random.randint(jax.random.PRNGKey(5), (2, P), 0, 256)
+    run = lambda: jax.jit(
+        lambda p, i: dsv32.prefill(p, i, P + T, cfg))(params, ids)
+    want, (caches, stats) = run()
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda p, i: dsv32.prefill(p, i, P + T, cfg))(params, ids))
+    kernel = selected_flash.selected_flash_attention
+    calls = []
+
+    def forced(*a, **kw):
+        calls.append(a[0].shape)
+        return kernel(*a, **kw, interpret=True)
+
+    monkeypatch.setattr(selected_flash, "kernel_serves", lambda *a: True)
+    monkeypatch.setattr(selected_flash, "selected_flash_attention", forced)
+    got, (caches_k, stats_k) = run()
+    # one call a query block of 4 rows, traced once a layer
+    assert calls == [(4, cfg.heads, cfg.qk_nope_head_dim)] * len(cfg.layers)
+    assert float(jnp.abs(got - want).max()) < tol
+    for (lat, k_i), (lat_k, k_i_k) in zip(caches, caches_k):
+        assert float(jnp.abs(lat.astype(jnp.float32)
+                             - lat_k.astype(jnp.float32)).max()) < tol
+        assert float(jnp.abs(k_i.astype(jnp.float32)
+                             - k_i_k.astype(jnp.float32)).max()) < tol
+    assert int(stats_k[0]) == int(stats[0])
+
+
 def test_the_selection_bites_and_keeps_everything_up_to_index_topk():
     """With index_topk 4 the logits differ from the dense model's from
     the fifth position on, and equal them while t + 1 <= index_topk."""
@@ -390,7 +432,12 @@ def test_bucket_program_is_deterministic_and_prefix_stable():
         == f"deepseek_v32.2.{P}.{T}.greedy"
     attrs = pipe.bucket_attrs(2, P, T)
     assert set(attrs) == {"cache_bytes", "cache_bytes_per_head",
-                          "attn_pairs", "attn_pairs_causal"}
+                          "attn_pairs", "attn_pairs_causal",
+                          "attn_kernel_calls", "attn_blocks",
+                          "attn_blocks_dense"}
+    # the prefill kernel's counts: the walk serves every call off the TPU
+    assert (attrs["attn_kernel_calls"], attrs["attn_blocks"],
+            attrs["attn_blocks_dense"]) == (0, 0, 0)
     assert (attrs["cache_bytes"], attrs["cache_bytes_per_head"]) \
         == cfg.cache_bytes(P + T)
     n = P + T - 1
@@ -516,8 +563,11 @@ def test_greedy_cids_equal_with_the_staged_executor_on_and_off():
             == cfg.cache_bytes(32 + T)
         assert (a["attn_pairs"], a["attn_pairs_causal"]) \
             == cfg.attn_pairs(32, T)
-        # this family's quantities, and none of trinity's
-        assert "kv_rows" not in a and "attn_kernel_calls" not in a
+        # this family's quantities and not trinity's rows; the prefill
+        # kernel's counts under trinity's names, 0 on the CPU
+        assert "kv_rows" not in a
+        assert (a["attn_kernel_calls"], a["attn_blocks"],
+                a["attn_blocks_dense"]) == (0, 0, 0)
         made = 2 * (32 + T - 1) * 2 * 4
         assert all(s["attrs"]["assignments"] == s["attrs"]["held"] == made
                    for s in routed)
