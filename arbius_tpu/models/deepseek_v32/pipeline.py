@@ -22,6 +22,24 @@ from arbius_tpu.models.trinity.pipeline import (
 from arbius_tpu.ops import selected_flash
 
 
+def selected_kernel_counts(cfg, batch: int, prompt_bucket: int) -> tuple:
+    """`attn_kernel` of a family whose prefill attention is
+    `ops.selected_flash.selected_attention` (this one, and
+    models/joyai_flash under an all-ones selection). Static, from the
+    rule that function reads off the same shapes: one call a block of
+    query rows, a layer, a sequence (prefill walks the batch a sequence
+    at a time and a sequence a block at a time), each over every group
+    of heads."""
+    if not selected_flash.kernel_serves(
+            prompt_bucket, cfg.qk_nope_head_dim, cfg.v_head_dim):
+        return 0, 0, 0
+    rows = dsv32._block(prompt_bucket, cfg.heads)
+    walked, dense = selected_flash.walk_blocks(prompt_bucket, rows,
+                                               cfg.heads)
+    each = batch * len(cfg.layers)
+    return each * (prompt_bucket // rows), each * walked, each * dense
+
+
 class DeepSeekV32Pipeline(SharePipeline):
     FAMILY = "deepseek_v32"
 
@@ -41,20 +59,7 @@ class DeepSeekV32Pipeline(SharePipeline):
         return dsv32.decode(params, tok, carry, pos, self.config)
 
     def attn_kernel(self, batch: int, prompt_bucket: int) -> tuple:
-        """Static, from the rule `ops.selected_flash.selected_attention`
-        reads off the same shapes: one call a block of query rows, a
-        layer, a sequence (prefill walks the batch a sequence at a time
-        and a sequence a block at a time), each over every group of
-        heads."""
-        cfg = self.config
-        if not selected_flash.kernel_serves(
-                prompt_bucket, cfg.qk_nope_head_dim, cfg.v_head_dim):
-            return 0, 0, 0
-        rows = dsv32._block(prompt_bucket, cfg.heads)
-        walked, dense = selected_flash.walk_blocks(prompt_bucket, rows,
-                                                   cfg.heads)
-        each = batch * len(cfg.layers)
-        return each * (prompt_bucket // rows), each * walked, each * dense
+        return selected_kernel_counts(self.config, batch, prompt_bucket)
 
     def bucket_attrs(self, batch: int, prompt_bucket: int,
                      decode_bucket: int) -> dict:

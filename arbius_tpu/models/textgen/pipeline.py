@@ -291,7 +291,10 @@ class TextGenPipeline:
         and the separately-goldened decode trace: lax.scan over steps
         1..T-1 with (kv, last_token) as carry. Step i embeds t_{i-1}
         at position P+i-1 and samples t_i; t0 (sampled from prefill's
-        logits) rides in as the carry seed."""
+        logits) rides in as the carry seed. One token a step; a family
+        whose step yields more (models/joyai_flash: a drafted token
+        verified beside the one before it) overrides this with a loop
+        of its own over per-row positions."""
         p, t = prompt_bucket, decode_bucket
         sample = self._sampler_fn(sampler)
 

@@ -340,9 +340,10 @@ class TextgenConfig:
     # {"trinity": {"prompt_buckets": [8192], "decode_buckets": [256],
     # "max_new_tokens": 256}}. Fleet-wide like everything here.
     templates: dict = field(default_factory=dict)
-    # the chip's share of a model divided over chips (trinity and
-    # deepseek_v32: `experts_held`, `vocab_rows`, `layers` — the
-    # families' config dataclasses); empty = the whole published model
+    # the chip's share of a model divided over chips (trinity,
+    # deepseek_v32 and joyai_llm_flash: `experts_held`, `vocab_rows`,
+    # `layers` — the families' config dataclasses); empty = the whole
+    # published model
     share: dict = field(default_factory=dict)
 
     def for_template(self, template: str) -> "TextgenConfig":

@@ -289,10 +289,24 @@ def _deepseek_v32(m: ModelConfig, mesh, mode: str, tg):
                          DeepSeekV32Pipeline)
 
 
+def _joyai_llm_flash(m: ModelConfig, mesh, mode: str, tg):
+    """The one program this template is served by is the speculative
+    one (models/joyai_flash/pipeline.py): nothing here or in the config
+    picks another."""
+    from arbius_tpu.models.joyai_flash import (
+        JoyAIFlashConfig,
+        JoyAIFlashPipeline,
+    )
+
+    return _share_family(m, mesh, mode, tg, JoyAIFlashConfig,
+                         JoyAIFlashPipeline)
+
+
 # text templates: builders that take the template's sequence-bucket
 # policy (cfg.textgen.for_template) on top of the common triple
 _TEXT_BUILDERS = {"textgen": _textgen, "trinity": _trinity,
-                  "deepseek_v32": _deepseek_v32}
+                  "deepseek_v32": _deepseek_v32,
+                  "joyai_llm_flash": _joyai_llm_flash}
 
 
 def _rvm(m: ModelConfig, mesh, resolve_file):

@@ -125,12 +125,16 @@ class CostSched(FifoSched):
         static = self.node._static_solve_seconds()
         if len(key) > 7 and key[7] is not None and key[8] is not None:
             # sequence-bucketed family, cold key (docs/text-serving.md):
-            # decode cost is near-linear in total tokens (prompt edge +
-            # decode edge), so scale the static prior by the bucket's
-            # token count relative to a mid-sized reference bucket —
-            # cold-start packing then prefers short sequences at equal
-            # fees instead of pricing a 96-token bucket like a 20-token
-            # one. Ordering-only: the estimate never touches bytes.
+            # a bucket's seconds grow with its edges (prompt edge +
+            # decode edge), so scale the static prior by their sum
+            # relative to a mid-sized reference bucket — cold-start
+            # packing then prefers short sequences at equal fees
+            # instead of pricing a 96-token bucket like a 20-token one.
+            # The edges count TOKENS, not steps: a family whose loop
+            # speculates (joyai_llm_flash) takes fewer steps than
+            # tokens, by its drafts' acceptance, which only a fitted
+            # row knows. Ordering-only: the estimate never touches
+            # bytes.
             tokens = int(key[7]) + int(key[8])
             return static * tokens / _SEQ_BASELINE_TOKENS, "static_seq"
         return static, "static"

@@ -576,8 +576,26 @@ def count_attn_pairs(kept: int, causal: int) -> None:
         c.inc(causal, mask="causal")
 
 
+def count_speculation(steps: int, drafts: int, accepted: int) -> None:
+    """Bump `arbius_text_decode_steps_total` by the steps a speculative
+    bucket program's loop ran, and `arbius_text_spec_drafts_total
+    {outcome}` by the drafts it verified, split by whether the draft was
+    the sampler's own choice — the program's int32 counts
+    (docs/text-serving.md "Speculative decoding")."""
+    c = _counter("arbius_text_decode_steps_total",
+                 "steps speculative text bucket programs' decode loops ran")
+    if c is not None:
+        c.inc(steps)
+    c = _counter("arbius_text_spec_drafts_total",
+                 "drafts speculative text bucket programs verified, by "
+                 "outcome", labelnames=("outcome",))
+    if c is not None:
+        c.inc(accepted, outcome="accepted")
+        c.inc(drafts - accepted, outcome="rejected")
+
+
 class TextGenRunner:
-    """text-template runner (textgen, trinity, deepseek_v32): decoder-only LM → deterministic UTF-8.
+    """text-template runner (textgen, trinity, deepseek_v32, joyai_llm_flash): decoder-only LM → deterministic UTF-8.
 
     Template variables (templates/textgen.json): prompt,
     max_new_tokens, sampler (enum); output out-1.txt. The sequence
@@ -657,8 +675,11 @@ class TextGenRunner:
 
         out, budgets = dev
         # a family with expert layers returns its routers' counts
-        # beside the tokens (models/trinity, models/deepseek_v32)
-        tokens, routed = out if isinstance(out, tuple) else (out, None)
+        # beside the tokens (models/trinity, models/deepseek_v32), one
+        # whose loop speculates its loop's counts after them
+        # (models/joyai_flash)
+        tokens, routed, spec = (*out, None)[:3] \
+            if isinstance(out, tuple) else (out, None, None)
         with span("solve.encode", n=n_real, codec="text"):
             tokens = gather_canonical(tokens)
             if routed is not None:
@@ -666,6 +687,15 @@ class TextGenRunner:
                 count_moe_assignments(made, held)
                 with span("text.routed", model=self.pipeline.FAMILY,
                           assignments=made, held=held):
+                    pass
+            if spec is not None:
+                steps, drafts, accepted, idle = (
+                    int(x) for x in np.asarray(spec))
+                count_speculation(steps, drafts, accepted)
+                with span("text.speculate", model=self.pipeline.FAMILY,
+                          batch=int(tokens.shape[0]), steps=steps,
+                          drafts=drafts, accepted=accepted,
+                          idle_row_steps=idle, tokens=int(tokens.size)):
                     pass
             out = []
             stalls = 0

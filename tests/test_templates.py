@@ -17,7 +17,8 @@ def test_all_reference_templates_parse():
     names = template_names()
     assert names == sorted(
         ["anythingv3", "kandinsky2", "zeroscopev2xl", "damo",
-         "robust_video_matting", "textgen", "trinity", "deepseek_v32"])
+         "robust_video_matting", "textgen", "trinity", "deepseek_v32",
+         "joyai_llm_flash"])
     for n in names:
         t = load_template(n)
         assert t.title
