@@ -237,6 +237,38 @@ class NodeDB:
                         bool(r["concurrent"]), r["method"],
                         json.loads(r["data"])) for r in rows]
 
+    def due_solves_past(self, now: int, held):
+        """Due `solve` jobs outside `held` (job ids), in `get_jobs`'s
+        order, a page of 100 rows at a time — the node's intake top-up
+        (docs/scheduler.md "Solve intake") walks it only as far as it
+        needs. The lock is held per page, never across a yield: the
+        caller reads task inputs between rows."""
+        held = tuple(held)
+        marks = ",".join("?" * len(held))
+        page = 100
+        after = None
+        while True:
+            sql = ("SELECT * FROM jobs WHERE method = 'solve' "
+                   "AND waituntil <= ?")
+            args: tuple = (now,)
+            if held:
+                sql += f" AND id NOT IN ({marks})"
+                args += held
+            if after is not None:
+                sql += " AND (priority < ? OR (priority = ? AND id > ?))"
+                args += (after[0], after[0], after[1])
+            with self._lock:
+                rows = self._conn.execute(
+                    sql + " ORDER BY priority DESC, id ASC LIMIT ?",
+                    args + (page,)).fetchall()
+            for r in rows:
+                yield Job(r["id"], r["priority"], r["waituntil"],
+                          bool(r["concurrent"]), r["method"],
+                          json.loads(r["data"]))
+            if len(rows) < page:
+                return
+            after = (rows[-1]["priority"], rows[-1]["id"])
+
     def delete_job(self, job_id: int) -> None:
         with self._lock:
             self._conn.execute("DELETE FROM jobs WHERE id = ?", (job_id,))

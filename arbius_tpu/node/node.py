@@ -149,6 +149,11 @@ class MinerNode:
             "Tasks skipped by the profitability gate, by model — a "
             "mispriced family shows up as its own series "
             "(docs/scheduler.md)", labelnames=("model",))
+        self._c_topped = reg.counter(
+            "arbius_solve_intake_topped_total",
+            "Solves taken past a tick's job window to make a bucket a "
+            "whole number of canonical batches, by model "
+            "(docs/scheduler.md \"Solve intake\")", labelnames=("model",))
         self._h_stage = reg.histogram(
             "arbius_stage_seconds",
             "Wall-clock seconds per solve stage (infer=model+encode+CID "
@@ -843,6 +848,7 @@ class MinerNode:
                 bucket_key(job.data["model"], hydrated,
                            self.solve_mode(job.data["model"])), []).append(
                 (job, hydrated))
+        topped = self._top_up_solves(by_bucket, jobs)
         # fee SELECTs stay OUTSIDE the state lock (per-task sqlite I/O
         # must not stall the RPC debug views or the device stage's
         # mark_warm); only the pack itself reads/writes packer state
@@ -862,18 +868,59 @@ class MinerNode:
                 buckets = [(self.registry.get(b.key[0]), b.entries, b.key)
                            for b in packed]
                 with span("solve.pipeline",
-                          n=sum(len(e) for _, e, _ in buckets)):
+                          n=sum(len(e) for _, e, _ in buckets),
+                          topped=sum(topped.values())):
                     return self._pipeline.run(buckets)
             done = 0
             for b in packed:
                 m = self.registry.get(b.key[0])
                 taskids = [job.data["taskid"] for job, _ in b.entries]
                 with span("solve.batch", model=b.key[0], n=len(b.entries),
-                          taskids=taskids):
+                          topped=topped.get(b.key, 0), taskids=taskids):
                     done += self._solve_bucket(m, b.entries, b.key, taskids)
             return done
         finally:
             self._ingest_costs()
+
+    def _top_up_solves(self, by_bucket: dict, held: list[Job]) -> dict:
+        """The solve intake's top-up (docs/scheduler.md "Solve
+        intake"): a bucket whose size is not a whole number of
+        canonical batches takes further due solves of its key from past
+        the tick's job window, in queue order, until it is whole or the
+        queue has none left — at most canonical_batch - 1 a key, so a
+        flood's tick stays bounded. It changes chunk composition only:
+        per-item seeds make a task's CID independent of its chunk.
+        Appends to `by_bucket` in place; returns {key: solves taken}."""
+        from arbius_tpu.node.solver import bucket_key
+
+        cb = max(1, self.config.canonical_batch)
+        need = {key: -len(entries) % cb
+                for key, entries in by_bucket.items()
+                if len(entries) % cb}
+        taken: dict[tuple, int] = {}
+        if not need:
+            return taken
+        for job in self.db.due_solves_past(self.chain.now,
+                                           [j.id for j in held]):
+            model = job.data["model"]
+            if not any(key[0] == model for key in need):
+                continue
+            hydrated = self.db.get_task_input(job.data["taskid"])
+            if hydrated is None:
+                continue    # its own tick quarantines it
+            key = bucket_key(model, hydrated, self.solve_mode(model))
+            if key not in need:
+                continue
+            by_bucket[key].append((job, hydrated))
+            taken[key] = taken.get(key, 0) + 1
+            need[key] -= 1
+            if not need[key]:
+                del need[key]
+                if not need:
+                    break
+        for key, n in taken.items():
+            self._c_topped.inc(n, model=key[0])
+        return taken
 
     def _cost_tag(self, key: tuple, n: int) -> str:
         from arbius_tpu.node.costmodel import bucket_str, make_cost_tag

@@ -503,3 +503,204 @@ def test_get_jobs_excludes_future_waituntil():
     # waituntil == now is DUE (<=), one second later is not
     assert [j.id for j in db.get_jobs(now=200)] == [due, edge]
     db.close()
+
+
+# -- solve intake: top-up to whole canonical batches ------------------------
+# docs/scheduler.md "Solve intake": a tick's 100-job window holds 50
+# solves when every task also queued a pinTaskInput; a bucket left short
+# of a whole canonical batch takes further due solves of its key from
+# past the window, at most canonical_batch - 1 a key a tick.
+
+class _SlotRunner:
+    """Batched fake runner: records each dispatch's (slots, distinct
+    seeds) — a padded slot repeats the chunk's last real item."""
+
+    def __init__(self):
+        self.chunks = []
+
+    def __call__(self, hydrated, seed):
+        return self.run_batch([(hydrated, seed)])[0]
+
+    def run_batch(self, items):
+        self.chunks.append((len(items), len({s for _, s in items})))
+        return [fake_runner(h, s) for h, s in items]
+
+
+def _intake_world(tmp_path, cb, runner=None, **cfg):
+    """A node that pins (so each task queues pinTaskInput + solve) at
+    canonical batch `cb`; returns (eng, node, mid, runner)."""
+    eng, _, _, node, mid = build_world(store_dir=str(tmp_path / "store"),
+                                       canonical_batch=cb, **cfg)
+    runner = runner or _SlotRunner()
+    m = node.registry.get(mid)
+    node.registry.register(RegisteredModel(id=mid, template=m.template,
+                                           runner=runner))
+    return eng, node, mid, runner
+
+
+def _submit_shaped(eng, mid, i, width=768):
+    return "0x" + eng.submit_task(
+        USER, 0, USER, bytes.fromhex(mid[2:]), 0,
+        json.dumps({**task_input(f"p{i}"), "width": width}).encode()).hex()
+
+
+def _topped(node):
+    return node.obs.registry.counter(
+        "arbius_solve_intake_topped_total", labelnames=("model",))
+
+
+def _solved(eng, tids):
+    return {t for t in tids if bytes.fromhex(t[2:]) in eng.solutions}
+
+
+def _tops(node):
+    """The `topped` attribute of each solve.batch / solve.pipeline."""
+    return [e["attrs"]["topped"] for e in node.obs.journal.events(kind="span")
+            if e["name"] in ("solve.batch", "solve.pipeline")]
+
+
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_intake_fills_buckets_before_padding(tmp_path, pipelined):
+    """60 due solves of one shape at canonical batch 8: the second tick
+    takes the window's 50 plus 6 from past it (7 whole chunks), so the
+    drain pads one chunk, not two (50 -> 6x8+2, 10 -> 8+2)."""
+    from arbius_tpu.node.config import PipelineConfig
+
+    eng, node, mid, runner = _intake_world(
+        tmp_path, 8, pipeline=PipelineConfig(enabled=pipelined))
+    tids = [submit(eng, mid, prompt=f"p{i}") for i in range(60)]
+    node.tick()                      # task jobs: 60 pins + 60 solves queued
+    node.tick()
+    assert len(_solved(eng, tids)) == 56
+    assert _topped(node).value(model=mid) == 6
+    drain(node)
+    assert _solved(eng, tids) == set(tids)
+    padded = [c for c in runner.chunks if c[0] != c[1]]
+    assert len(padded) == 1 and runner.chunks.count((8, 8)) == 7
+    assert _tops(node) == [6, 0]
+
+
+def test_intake_top_up_keeps_lone_solve_cids(tmp_path):
+    """A task's CID does not depend on the chunk it rides in: every CID
+    of a topped-up drain equals the task's CID solved alone (a jitted
+    per-sample-seeded probe, so batch and lone are different programs)."""
+    from arbius_tpu.l0.commitment import taskid2seed
+    from arbius_tpu.parallel.meshsolve import ShardedImageProbe
+    from arbius_tpu.templates.engine import hydrate_input
+
+    probe = ShardedImageProbe()
+    eng, node, mid, _ = _intake_world(tmp_path, 8, runner=probe,
+                                      compile_cache=False)
+    tids = [submit(eng, mid, prompt=f"p{i}") for i in range(60)]
+    drain(node)
+    assert _topped(node).value(model=mid) == 6
+    template = load_template("anythingv3")
+    for tid in tids:
+        raw = json.loads(eng.task_input_data[bytes.fromhex(tid[2:])])
+        hydrated = hydrate_input(raw, template)
+        hydrated["seed"] = taskid2seed(tid)
+        alone = cid_hex(cid_of_solution_files(probe(hydrated,
+                                                    hydrated["seed"])))
+        assert "0x" + eng.solutions[bytes.fromhex(tid[2:])].cid.hex() \
+            == alone
+
+
+def test_intake_top_up_skips_solves_not_yet_due(tmp_path):
+    """Solves past the window whose waituntil lies ahead stay queued:
+    the short bucket pads rather than take a job before it is due."""
+    eng, node, mid, runner = _intake_world(tmp_path, 8)
+    tids = [submit(eng, mid, prompt=f"p{i}") for i in range(60)]
+    node.tick()
+    window = {j.id for j in node.db.get_jobs(node.chain.now)}
+    later = [j for j in node.db.get_jobs(node.chain.now, limit=1000)
+             if j.method == "solve" and j.id not in window]
+    assert len(later) == 10
+    for j in later:
+        node.db.delete_job(j.id)
+        node.db.queue_job("solve", j.data, waituntil=node.chain.now + 1)
+    node.tick()
+    assert len(_solved(eng, tids)) == 50
+    assert _topped(node).value(model=mid) == 0
+    assert runner.chunks[-1] == (8, 2)
+
+
+@pytest.mark.parametrize("cb", [7, 8, 16])
+def test_intake_top_up_takes_at_most_a_batch_less_one(tmp_path, cb):
+    """Per key a tick the top-up takes -50 % cb solves (the window holds
+    50), never more than cb - 1, though 40 more wait past the window."""
+    eng, node, mid, _ = _intake_world(tmp_path, cb)
+    tids = [submit(eng, mid, prompt=f"p{i}") for i in range(90)]
+    node.tick()
+    node.tick()
+    took = -50 % cb                  # 7 -> 6: the cap itself
+    assert took <= cb - 1
+    assert _topped(node).value(model=mid) == took
+    assert len(_solved(eng, tids)) == 50 + took
+    assert _tops(node) == [took]
+
+
+def test_intake_top_up_takes_only_its_own_bucket_key(tmp_path):
+    """Past the window the queue holds solves of another shape between
+    those of the short bucket's: only the short bucket's are taken."""
+    eng, node, mid, runner = _intake_world(tmp_path, 8)
+    own = [_submit_shaped(eng, mid, i) for i in range(50)]
+    other = [_submit_shaped(eng, mid, 50 + i, width=512) for i in range(3)]
+    own += [_submit_shaped(eng, mid, 53 + i) for i in range(4)]
+    other += [_submit_shaped(eng, mid, 57 + i, width=512) for i in range(3)]
+    node.tick()
+    node.tick()
+    assert _solved(eng, own + other) == set(own)
+    assert _topped(node).value(model=mid) == 4
+    assert runner.chunks[-1] == (8, 6)      # 54 = 6x8 + 6: nothing else
+
+
+def test_intake_top_up_leaves_priority_jobs_in_their_tick(tmp_path,
+                                                          monkeypatch):
+    """A priority-50 contest job due in a topping tick still runs in it
+    (it heads the window, which then holds 49 solves: 7 are taken)."""
+    eng, node, mid, _ = _intake_world(tmp_path, 8)
+    tids = [submit(eng, mid, prompt=f"p{i}") for i in range(60)]
+    node.tick()
+    ran = []
+    monkeypatch.setattr(node, "_process_contest",
+                        lambda data: ran.append(data["taskid"]))
+    node.db.queue_job("contest", {"taskid": tids[0]}, priority=50)
+    node.tick()
+    assert ran == [tids[0]]
+    assert _topped(node).value(model=mid) == 7
+    assert len(_solved(eng, tids)) == 56
+
+
+def test_intake_at_canonical_batch_one_is_the_window(tmp_path):
+    """At canonical batch 1 nothing is short: each tick solves exactly
+    the solves of its 100-job window, as before the top-up existed."""
+    eng, node, mid, _ = _intake_world(tmp_path, 1)
+    tids = [submit(eng, mid, prompt=f"p{i}") for i in range(60)]
+    node.tick()
+    window = [j.data["taskid"] for j in node.db.get_jobs(node.chain.now)
+              if j.method == "solve"]
+    assert len(window) == 50
+    node.tick()
+    assert _solved(eng, tids) == set(window)
+    drain(node)
+    assert _solved(eng, tids) == set(tids)
+    assert _topped(node).value(model=mid) == 0
+    assert _tops(node) == [0, 0]
+
+
+def test_due_solves_past_pages_in_queue_order():
+    """The top-up's reader: due solves only, held ids excluded, in
+    priority DESC, id ASC order across its 100-row pages."""
+    from arbius_tpu.node import NodeDB
+
+    db = NodeDB(":memory:")
+    ids = [db.queue_job("solve", {"n": i}) for i in range(230)]
+    db.queue_job("pinTaskInput", {})
+    db.queue_job("solve", {"n": "late"}, waituntil=10)
+    hot = db.queue_job("solve", {"n": "hot"}, priority=5)
+    held = ids[:40]
+    got = [j.id for j in db.due_solves_past(0, held)]
+    assert got == [hot] + ids[40:]
+    late = [j.data["n"] for j in db.due_solves_past(10, ids)]
+    assert late == ["hot", "late"]
+    db.close()
