@@ -385,8 +385,9 @@ def route(x, p, cfg: DeepSeekV32Config):
 
 def moe(x, p, cfg: DeepSeekV32Config):
     """x[T, d] → (shared(x) + held experts' part, held assignments)."""
-    chosen, w = route(x, p, cfg)
-    y, n_held = routed_experts(x, chosen, w, p["experts"], cfg)
+    with jax.named_scope("routed_experts"):
+        chosen, w = route(x, p, cfg)
+        y, n_held = routed_experts(x, chosen, w, p["experts"], cfg)
     return swiglu(x, p["shared"]) + y, n_held
 
 
@@ -465,45 +466,50 @@ def _prefill_layer(x, lp, kind, cfg: DeepSeekV32Config):
     n_blk = p // blk
     k_sel = min(cfg.index_topk, p)
     pos = jnp.arange(p)
-    h = rms_norm(x, lp["attn_norm"]["scale"], cfg.eps)
-    latent, k_i = _keys(h, lp, pos, cfg)
-    c = cfg.kv_lora_rank
-    # keys and values of every head, expanded from the latents once a
-    # layer (the per-head form); the rotary key stays one row for all
-    attend = selected_attention(
-        _dot(latent[:, :c], lp["attn"]["wkv_b"]["kernel"]), nh, dn,
-        scale=cfg.softmax_scale)
-    k_pe = latent[:, c:]
+    with jax.named_scope("attention"):
+        h = rms_norm(x, lp["attn_norm"]["scale"], cfg.eps)
+        latent, k_i = _keys(h, lp, pos, cfg)
+        c = cfg.kv_lora_rank
+        # keys and values of every head, expanded from the latents once
+        # a layer (the per-head form); the rotary key stays one row for
+        # all
+        attend = selected_attention(
+            _dot(latent[:, :c], lp["attn"]["wkv_b"]["kernel"]), nh, dn,
+            scale=cfg.softmax_scale)
+        k_pe = latent[:, c:]
     rows = jnp.arange(blk)
 
     def block(i):
         r0 = i * blk
-        xb = jax.lax.dynamic_slice_in_dim(x, r0, blk)
-        hb = jax.lax.dynamic_slice_in_dim(h, r0, blk)
-        q_nope, q_pe, q_i, w = _queries(hb, lp, r0 + rows, cfg)
-        qpos = r0 + rows[:, None]
+        with jax.named_scope("attention"):
+            xb = jax.lax.dynamic_slice_in_dim(x, r0, blk)
+            hb = jax.lax.dynamic_slice_in_dim(h, r0, blk)
+            q_nope, q_pe, q_i, w = _queries(hb, lp, r0 + rows, cfg)
+            qpos = r0 + rows[:, None]
 
-        def causal(j):
-            return j * blk + rows[None, :] <= qpos
+            def causal(j):
+                return j * blk + rows[None, :] <= qpos
 
-        if k_sel < p:
-            # the index scores of this block's rows against every key
-            # block up to the diagonal, then the selection over the row
-            def score(j, acc):
-                kb = jax.lax.dynamic_slice_in_dim(k_i, j * blk, blk)
-                sc = jnp.where(causal(j), _index_scores(q_i, w, kb),
-                               -jnp.inf)
-                return jax.lax.dynamic_update_slice_in_dim(acc, sc,
-                                                           j * blk, 1)
+            if k_sel < p:
+                # the index scores of this block's rows against every
+                # key block up to the diagonal, then the selection over
+                # the row
+                def score(j, acc):
+                    kb = jax.lax.dynamic_slice_in_dim(k_i, j * blk, blk)
+                    sc = jnp.where(causal(j), _index_scores(q_i, w, kb),
+                                   -jnp.inf)
+                    return jax.lax.dynamic_update_slice_in_dim(acc, sc,
+                                                               j * blk, 1)
 
-            index = jax.lax.fori_loop(
-                0, i + 1, score, jnp.full((blk, p), -jnp.inf, F32))
-            keep = select_topk(index, k_sel)
-        else:
-            keep = jnp.ones((blk, p), bool)
+                with jax.named_scope("indexer"):
+                    index = jax.lax.fori_loop(
+                        0, i + 1, score, jnp.full((blk, p), -jnp.inf, F32))
+                    keep = select_topk(index, k_sel)
+            else:
+                keep = jnp.ones((blk, p), bool)
 
-        o = attend(q_nope, q_pe, k_pe, keep, i, rows, qpos)
-        xb = xb + _dot(o, lp["attn"]["wo"]["kernel"])
+            o = attend(q_nope, q_pe, k_pe, keep, i, rows, qpos)
+            xb = xb + _dot(o, lp["attn"]["wo"]["kernel"])
         return _ffn(xb, lp, kind, cfg)
 
     out, held = jax.lax.map(block, jnp.arange(n_blk))
@@ -593,20 +599,23 @@ def decode(params, tok, carry, pos, cfg: DeepSeekV32Config):
         lp = params[f"layer_{i}"]
         lat, k_i = caches[i]
         t = lat.shape[1]
-        h = rms_norm(x, lp["attn_norm"]["scale"], cfg.eps)
-        q_nope, q_pe, q_i, w = _queries(h, lp, at, cfg)
-        row, k_row = _keys(h, lp, at, cfg)
-        lat = jax.lax.dynamic_update_slice(
-            lat, row[:, None].astype(lat.dtype), (0, pos, 0))
-        k_i = jax.lax.dynamic_update_slice(
-            k_i, k_row[:, None].astype(k_i.dtype), (0, pos, 0))
-        keep = jnp.broadcast_to(jnp.arange(t) <= pos, (b, t))
-        if cfg.index_topk < t:
-            index = jax.vmap(lambda q, wt, k: _index_scores(
-                q[None], wt[None], k)[0])(q_i, w, k_i)
-            keep &= select_topk(jnp.where(keep, index, -jnp.inf),
-                                cfg.index_topk)
-        x = x + _decode_attention(q_nope, q_pe, lat, keep, lp["attn"], cfg)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"]["scale"], cfg.eps)
+            q_nope, q_pe, q_i, w = _queries(h, lp, at, cfg)
+            row, k_row = _keys(h, lp, at, cfg)
+            lat = jax.lax.dynamic_update_slice(
+                lat, row[:, None].astype(lat.dtype), (0, pos, 0))
+            k_i = jax.lax.dynamic_update_slice(
+                k_i, k_row[:, None].astype(k_i.dtype), (0, pos, 0))
+            keep = jnp.broadcast_to(jnp.arange(t) <= pos, (b, t))
+            if cfg.index_topk < t:
+                with jax.named_scope("indexer"):
+                    index = jax.vmap(lambda q, wt, k: _index_scores(
+                        q[None], wt[None], k)[0])(q_i, w, k_i)
+                    keep &= select_topk(jnp.where(keep, index, -jnp.inf),
+                                        cfg.index_topk)
+            x = x + _decode_attention(q_nope, q_pe, lat, keep, lp["attn"],
+                                      cfg)
         x, n = _ffn(x, lp, kind, cfg)
         held = held + n
         new.append((lat, k_i))

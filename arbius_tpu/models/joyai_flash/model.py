@@ -306,11 +306,12 @@ def _layer_step(x, lp, kind, lat, q, cfg):
     (x', the cache with those rows written, held)."""
     b, s, d = x.shape
     pos = q[:, None] + jnp.arange(s)                          # [B, S]
-    h = rms_norm(x, lp["attn_norm"]["scale"], cfg.eps)
-    q_nope, q_pe = _queries(h, lp["attn"], pos, cfg)
-    lat = _write_rows(lat, _latent(h, lp["attn"], pos, cfg), q)
-    keep = jnp.arange(lat.shape[1]) <= pos[..., None]         # [B, S, T]
-    x = x + latent_attention(q_nope, q_pe, lat, keep, lp["attn"], cfg)
+    with jax.named_scope("attention"):
+        h = rms_norm(x, lp["attn_norm"]["scale"], cfg.eps)
+        q_nope, q_pe = _queries(h, lp["attn"], pos, cfg)
+        lat = _write_rows(lat, _latent(h, lp["attn"], pos, cfg), q)
+        keep = jnp.arange(lat.shape[1]) <= pos[..., None]     # [B, S, T]
+        x = x + latent_attention(q_nope, q_pe, lat, keep, lp["attn"], cfg)
     y, held = _ffn(x.reshape(b * s, d), lp, kind, cfg)
     return y.reshape(b, s, d), lat, held
 
@@ -351,10 +352,11 @@ def draft(params, toks, h, cache, q, cfg: JoyAIFlashConfig):
     hidden states AT them → (logits[B, S, V'] f32 for the tokens two
     past each position, cache, held)."""
     mp = params["mtp"]
-    x, cache, held = _layer_step(_mtp_in(params, toks, h, cfg), mp["layer"],
-                                 "moe", cache, q, cfg)
-    return _logits({"final_norm": mp["norm"], "head": params["head"]}, x,
-                   cfg), cache, held
+    with jax.named_scope("draft"):
+        x, cache, held = _layer_step(_mtp_in(params, toks, h, cfg),
+                                     mp["layer"], "moe", cache, q, cfg)
+        return _logits({"final_norm": mp["norm"], "head": params["head"]},
+                       x, cfg), cache, held
 
 
 # -- prefill -----------------------------------------------------------------
@@ -368,23 +370,27 @@ def _prefill_layer(x, lp, kind, cfg: JoyAIFlashConfig):
     nh, dn = cfg.heads, cfg.qk_nope_head_dim
     blk = _block(p, nh)
     pos = jnp.arange(p)
-    h = rms_norm(x, lp["attn_norm"]["scale"], cfg.eps)
-    latent = _latent(h, lp["attn"], pos, cfg)
-    c = cfg.kv_lora_rank
-    attend = selected_attention(
-        _dot(latent[:, :c], lp["attn"]["wkv_b"]["kernel"]), nh, dn,
-        scale=cfg.softmax_scale)
-    k_pe = latent[:, c:]
+    with jax.named_scope("attention"):
+        h = rms_norm(x, lp["attn_norm"]["scale"], cfg.eps)
+        latent = _latent(h, lp["attn"], pos, cfg)
+        c = cfg.kv_lora_rank
+        attend = selected_attention(
+            _dot(latent[:, :c], lp["attn"]["wkv_b"]["kernel"]), nh, dn,
+            scale=cfg.softmax_scale)
+        k_pe = latent[:, c:]
     rows = jnp.arange(blk)
     keep = jnp.ones((blk, p), bool)
 
     def block(i):
         r0 = i * blk
-        xb = jax.lax.dynamic_slice_in_dim(x, r0, blk)
-        hb = jax.lax.dynamic_slice_in_dim(h, r0, blk)
-        q_nope, q_pe = _queries(hb, lp["attn"], r0 + rows, cfg)
-        o = attend(q_nope, q_pe, k_pe, keep, i, rows, r0 + rows[:, None])
-        return _ffn(xb + _dot(o, lp["attn"]["wo"]["kernel"]), lp, kind, cfg)
+        with jax.named_scope("attention"):
+            xb = jax.lax.dynamic_slice_in_dim(x, r0, blk)
+            hb = jax.lax.dynamic_slice_in_dim(h, r0, blk)
+            q_nope, q_pe = _queries(hb, lp["attn"], r0 + rows, cfg)
+            o = attend(q_nope, q_pe, k_pe, keep, i, rows,
+                       r0 + rows[:, None])
+            xb = xb + _dot(o, lp["attn"]["wo"]["kernel"])
+        return _ffn(xb, lp, kind, cfg)
 
     out, held = jax.lax.map(block, jnp.arange(p // blk))
     return out.reshape(p, cfg.hidden), latent, held.sum(dtype=jnp.int32)
@@ -406,9 +412,10 @@ def _prefill_piece(params, ids, total: int, cfg: JoyAIFlashConfig):
         held = held + n
         caches.append(jnp.pad(latent, ((0, total - p), (0, 0))))
     lp = params["mtp"]["layer"]
-    xm = _mtp_in(params, ids[1:], x[:-1], cfg)
-    latent = _latent(rms_norm(xm, lp["attn_norm"]["scale"], cfg.eps),
-                     lp["attn"], jnp.arange(p - 1), cfg)
+    with jax.named_scope("draft"):
+        xm = _mtp_in(params, ids[1:], x[:-1], cfg)
+        latent = _latent(rms_norm(xm, lp["attn_norm"]["scale"], cfg.eps),
+                         lp["attn"], jnp.arange(p - 1), cfg)
     return x[-1], tuple(caches), \
         jnp.pad(latent, ((0, total - p + 1), (0, 0))), held
 

@@ -207,16 +207,22 @@ class Kandinsky2Pipeline:
                 # int8/fp8 kernels → f32 via their f32 scales (GRAPH407
                 # contract); guarded so bf16 stays byte-identical
                 params = dequantize_tree(params)
-            states = self.text_encoder.apply({"params": params["text"]}, ids)
-            # EOT pooling: hidden state at the first EOS position, then the
-            # projection into embedding space (CLIP *WithProjection heads)
-            first_eos = jnp.argmax((ids == eos_id).astype(jnp.int32), axis=1)
-            pooled_pre = states[jnp.arange(states.shape[0]), first_eos]
-            pooled = self.text_projection.apply(
-                {"params": params["text_proj"]}, pooled_pre)
-            # attention mask: real tokens up to and including the EOT
-            positions = jnp.arange(ids.shape[1])[None, :]
-            mask = (positions <= first_eos[:, None]).astype(jnp.float32)
+            # the program's blocks (obs/blocks.py): names on the HLO's
+            # op_name paths, no change to the program
+            with jax.named_scope("text_tower"):
+                states = self.text_encoder.apply({"params": params["text"]},
+                                                 ids)
+                # EOT pooling: hidden state at the first EOS position, then
+                # the projection into embedding space (CLIP
+                # *WithProjection heads)
+                first_eos = jnp.argmax((ids == eos_id).astype(jnp.int32),
+                                       axis=1)
+                pooled_pre = states[jnp.arange(states.shape[0]), first_eos]
+                pooled = self.text_projection.apply(
+                    {"params": params["text_proj"]}, pooled_pre)
+                # attention mask: real tokens up to and including the EOT
+                positions = jnp.arange(ids.shape[1])[None, :]
+                mask = (positions <= first_eos[:, None]).astype(jnp.float32)
 
             tok = states[:, :text_len]
             keys = jax.vmap(
@@ -224,10 +230,11 @@ class Kandinsky2Pipeline:
             )(seeds_lo, seeds_hi)
             g = guidance.astype(jnp.float32)
 
-            embed = prior_sample(self.prior, params["prior"], tok, pooled,
-                                 keys, g, steps=cfg.prior_steps,
-                                 text_mask=mask[:, :text_len],
-                                 clip_stats=params["prior_stats"])
+            with jax.named_scope("prior"):
+                embed = prior_sample(self.prior, params["prior"], tok, pooled,
+                                     keys, g, steps=cfg.prior_steps,
+                                     text_mask=mask[:, :text_len],
+                                     clip_stats=params["prior_stats"])
 
             x = jax.vmap(lambda k: jax.random.normal(
                 k, lat_shape[1:], jnp.float32))(keys)
@@ -252,10 +259,12 @@ class Kandinsky2Pipeline:
                 x, state = sampler.step(i, x, eps, state, noise)
                 return (x, state), None
 
-            (x, _), _ = jax.lax.scan(body, (x, sampler.init_carry(x)),
-                                     jnp.arange(sampler.num_model_calls))
-            pixels = self.movq.apply({"params": params["movq"]}, x)
-            return decode_to_images(pixels)
+            with jax.named_scope("unet"):
+                (x, _), _ = jax.lax.scan(body, (x, sampler.init_carry(x)),
+                                         jnp.arange(sampler.num_model_calls))
+            with jax.named_scope("movq"):
+                pixels = self.movq.apply({"params": params["movq"]}, x)
+                return decode_to_images(pixels)
 
         if self.mesh is None:
             # the exact pre-mesh program: goldens pin this byte-for-byte

@@ -218,9 +218,14 @@ class SD15Pipeline:
                 # bf16 compute dtype exactly as with f32 checkpoints.
                 # Guarded so the bf16 program stays byte-identical.
                 params = dequantize_tree(params)
-            ctx_c = self.text_encoder.apply({"params": params["text"]}, ids_cond)
-            ctx_u = self.text_encoder.apply({"params": params["text"]}, ids_uncond)
-            context = jnp.concatenate([ctx_u, ctx_c], axis=0)  # [2B, L, D]
+            # the program's blocks (obs/blocks.py): names on the HLO's
+            # op_name paths, no change to the program
+            with jax.named_scope("text_encoder"):
+                ctx_c = self.text_encoder.apply({"params": params["text"]},
+                                                ids_cond)
+                ctx_u = self.text_encoder.apply({"params": params["text"]},
+                                                ids_uncond)
+                context = jnp.concatenate([ctx_u, ctx_c], axis=0)  # [2B, L, D]
 
             # full 53-bit taskid2seed space: low word keys, high word folded in
             keys = jax.vmap(
@@ -243,11 +248,14 @@ class SD15Pipeline:
                 x, state = sampler.step(i, x, eps, state, noise)
                 return (x, state), None
 
-            (x, _), _ = jax.lax.scan(
-                body, (x, sampler.init_carry(x)),
-                jnp.arange(sampler.num_model_calls))
-            pixels = self.vae.apply({"params": params["vae"]}, x / SD_LATENT_SCALE)
-            return decode_to_images(pixels)
+            with jax.named_scope("unet"):
+                (x, _), _ = jax.lax.scan(
+                    body, (x, sampler.init_carry(x)),
+                    jnp.arange(sampler.num_model_calls))
+            with jax.named_scope("vae"):
+                pixels = self.vae.apply({"params": params["vae"]},
+                                        x / SD_LATENT_SCALE)
+                return decode_to_images(pixels)
 
         if self.mesh is None:
             # the exact pre-mesh program: goldens pin this byte-for-byte

@@ -355,9 +355,13 @@ class TextGenPipeline:
                 # byte-identical to a never-quantized build
                 params = dequantize_tree(params)
             keys = _fold_keys(seeds_lo, seeds_hi)
-            logits0, kv = self._prefill(params, ids, total)
-            t0 = sample(logits0, keys, 0)
-            return loop(params, kv, t0, keys)
+            # the program's blocks (obs/blocks.py): names on the HLO's
+            # op_name paths, no change to the program
+            with jax.named_scope("prefill"):
+                logits0, kv = self._prefill(params, ids, total)
+            with jax.named_scope("decode"):
+                t0 = sample(logits0, keys, 0)
+                return loop(params, kv, t0, keys)
 
         if self.mesh is None:
             return jax.jit(run)

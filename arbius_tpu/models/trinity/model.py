@@ -345,8 +345,9 @@ def routed_experts(x, chosen, w, experts, cfg: TrinityConfig):
 
 def moe(x, p, cfg: TrinityConfig):
     """x[T, d] → (shared(x) + held experts' part, held assignments)."""
-    chosen, w = route(x, p, cfg)
-    y, n_held = routed_experts(x, chosen, w, p["experts"], cfg)
+    with jax.named_scope("routed_experts"):
+        chosen, w = route(x, p, cfg)
+        y, n_held = routed_experts(x, chosen, w, p["experts"], cfg)
     return swiglu(x, p["shared"]) + y, n_held
 
 
@@ -412,16 +413,18 @@ def _prefill_piece(params, ids, total: int, cfg: TrinityConfig):
     kv = []
     for i, (mlp_kind, attn_kind) in enumerate(cfg.layers):
         lp = params[f"layer_{i}"]
-        h = rms_norm(x, lp["input_norm"]["scale"], cfg.eps)
-        q, k, v, g = _qkvg(h, lp["attn"], cfg, pos, attn_kind)
-        # the path is read off the call (ops/causal_flash.py): the Pallas
-        # kernel on a TPU at long prompts, else the XLA walk. No GSPMD
-        # program reaches it — trinity ships no mesh layout; one would
-        # need `ops.flash.on_mesh`'s treatment (ROADMAP D10)
-        o = causal_attention(
-            q, k, v, window=cfg.window if attn_kind == "sliding" else None)
-        a = _attn_out(o, g, lp["attn"], cfg)
-        x = x + rms_norm(a, lp["post_attn_norm"]["scale"], cfg.eps)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["input_norm"]["scale"], cfg.eps)
+            q, k, v, g = _qkvg(h, lp["attn"], cfg, pos, attn_kind)
+            # the path is read off the call (ops/causal_flash.py): the
+            # Pallas kernel on a TPU at long prompts, else the XLA walk.
+            # No GSPMD program reaches it — trinity ships no mesh layout;
+            # one would need `ops.flash.on_mesh`'s treatment (ROADMAP D10)
+            o = causal_attention(
+                q, k, v,
+                window=cfg.window if attn_kind == "sliding" else None)
+            a = _attn_out(o, g, lp["attn"], cfg)
+            x = x + rms_norm(a, lp["post_attn_norm"]["scale"], cfg.eps)
         x, n = _mlp(x, lp, mlp_kind, cfg)
         held = held + n
         rows = cfg.cache_rows(attn_kind, total)
@@ -496,21 +499,22 @@ def decode(params, tok, carry, pos, cfg: TrinityConfig):
         lp = params[f"layer_{i}"]
         k_cache, v_cache = kv[i]
         rows = k_cache.shape[1]
-        h = rms_norm(x, lp["input_norm"]["scale"], cfg.eps)
-        q, k, v, g = _qkvg(h, lp["attn"], cfg, pos, attn_kind)
-        slot = pos % rows if attn_kind == "sliding" else pos
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k[:, None].astype(k_cache.dtype), (0, slot, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v[:, None].astype(v_cache.dtype), (0, slot, 0, 0))
-        j = jnp.arange(rows)
-        if attn_kind == "sliding":
-            valid = pos - ((pos - j) % rows) >= 0
-        else:
-            valid = j <= pos
-        o = _decode_attention(q, k_cache, v_cache, valid)
-        a = _attn_out(o, g, lp["attn"], cfg)
-        x = x + rms_norm(a, lp["post_attn_norm"]["scale"], cfg.eps)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["input_norm"]["scale"], cfg.eps)
+            q, k, v, g = _qkvg(h, lp["attn"], cfg, pos, attn_kind)
+            slot = pos % rows if attn_kind == "sliding" else pos
+            k_cache = jax.lax.dynamic_update_slice(
+                k_cache, k[:, None].astype(k_cache.dtype), (0, slot, 0, 0))
+            v_cache = jax.lax.dynamic_update_slice(
+                v_cache, v[:, None].astype(v_cache.dtype), (0, slot, 0, 0))
+            j = jnp.arange(rows)
+            if attn_kind == "sliding":
+                valid = pos - ((pos - j) % rows) >= 0
+            else:
+                valid = j <= pos
+            o = _decode_attention(q, k_cache, v_cache, valid)
+            a = _attn_out(o, g, lp["attn"], cfg)
+            x = x + rms_norm(a, lp["post_attn_norm"]["scale"], cfg.eps)
         x, n = _mlp(x, lp, mlp_kind, cfg)
         held = held + n
         new_kv.append((k_cache, v_cache))

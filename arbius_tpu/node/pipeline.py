@@ -60,7 +60,12 @@ import threading
 import time
 from dataclasses import dataclass
 
-from arbius_tpu.node.solver import chunk_items, device_wait, encode_chunk
+from arbius_tpu.node.solver import (
+    chunk_items,
+    device_wait,
+    encode_chunk,
+    program_attrs,
+)
 from arbius_tpu.obs import span, use_obs
 
 log = logging.getLogger("arbius.pipeline")
@@ -316,7 +321,8 @@ class SolvePipeline:
             with self.node.obs.span(
                     "solve.dispatch", n=ch.real, batch=len(ch.items),
                     chunk=[self._gen, ch.idx], model=ch.model.id,
-                    taskids=taskids) as dsp:
+                    taskids=taskids,
+                    **program_attrs(runner, ch.items)) as dsp:
                 dispatch = getattr(runner, "dispatch", None)
                 finalize = getattr(runner, "finalize", None)
                 if dispatch is not None and finalize is not None:

@@ -579,7 +579,8 @@ class ControlRPC:
         GET /debug/journal[?limit=N&kind=K&taskid=0x…] → raw journal
         events; GET /debug/costmodel → the learned cost table + packer
         state; GET /debug/alerts → the healthwatch engine's snapshot
-        (docs/healthwatch.md)."""
+        (docs/healthwatch.md); GET /debug/blocks → each bucket
+        executable's instruction count per named block."""
         parts = urlsplit(path)
         q = parse_qs(parts.query)
         if parts.path == "/debug/costmodel":
@@ -702,6 +703,17 @@ class ControlRPC:
             if hw is None:
                 return 200, {"enabled": False, "alerts": []}
             return 200, hw.snapshot()
+        if parts.path == "/debug/blocks":
+            # each bucket executable's instructions per named block
+            # (docs/observability.md "Blocks"); a map not built yet is
+            # built here, off the executable the node dispatched.
+            # obs.programs is published copy-on-write by jit_cache_get
+            from arbius_tpu.obs.blocks import block_counts
+
+            obs = self.node.obs
+            return 200, {"programs": {
+                tag: block_counts(obs.blocks(tag))
+                for tag in sorted(obs.programs)}}
         return 404, {"error": "not found"}
 
     def _view_error(self, handler, e: Exception) -> None:

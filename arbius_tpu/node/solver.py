@@ -137,6 +137,21 @@ def chunk_items(items: list[tuple[dict, int]],
 # finalizes. This file reads no clock (DET101 is enforced here): the
 # stamps the schedules reckon chip idle from are the spans' own.
 
+def program_attrs(runner, items: list) -> dict:
+    """`solve.dispatch`'s `program`: the executable-cache tag of the
+    bucket a chunk of `items` runs, from the runner's `cache_tag` (the
+    key `Obs.blocks` reads the program's block map under); nothing for
+    a runner without one, or whose derivation fails (the node's
+    `_bucket_exec_tag` rule: a tag never fails a solve)."""
+    tag = getattr(runner, "cache_tag", None)
+    if tag is None:
+        return {}
+    try:
+        return {"program": tag(items[0][0], len(items))}
+    except Exception:  # noqa: BLE001 — a tag is advisory metadata
+        return {}
+
+
 def device_wait(value, *, chunk, parent: int | None) -> float | None:
     """Block until a dispatched chunk's device result is ready — the
     ONE place either schedule waits on the chip, so `solve.encode`
@@ -190,7 +205,8 @@ def _solve_chunked(model: RegisteredModel, chunks: list, *, gen: int,
     for idx, (chunk, real) in enumerate(chunks):
         with span("solve.dispatch", n=real, batch=len(chunk),
                   chunk=[gen, idx], model=model.id,
-                  taskids=(taskids or [])[idx * b:idx * b + real]) as dsp:
+                  taskids=(taskids or [])[idx * b:idx * b + real],
+                  **program_attrs(runner, chunk)) as dsp:
             dev = runner.dispatch(chunk)
         if pending is not None:
             finish(*pending)

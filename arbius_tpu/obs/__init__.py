@@ -26,6 +26,7 @@ un-instrumented call path slower.
 """
 from __future__ import annotations
 
+import threading
 from contextlib import contextmanager, nullcontext
 from contextvars import ContextVar
 
@@ -74,6 +75,36 @@ class Obs:
         # when cfg.perfscope.enabled, same ambient pattern — None =
         # no capture, the pre-perfscope node bit-for-bit
         self.perfscope = None
+        # each bucket executable this obs saw built or run, by cache tag,
+        # with the thunk of its dispatch arguments (jit_cache_get keeps
+        # both, nothing more; published copy-on-write like jit_warm),
+        # and the block maps `blocks` built from them, under their own
+        # lock: /debug/blocks builds from a request thread
+        self.programs: dict = {}
+        self._block_maps: dict = {}
+        self._blocks_lock = threading.Lock()
+
+    def blocks(self, tag: str) -> dict | None:
+        """{HLO instruction name: (blocks on its op_name path, outermost
+        first)} of the bucket executable cached under `tag`
+        (obs/blocks.py), built the first time it is asked for and kept;
+        None for a tag no executable was kept under. Nothing is built at
+        dispatch or at set-up. The map comes off the executable the node
+        dispatched: `lower(...).compile()` on its own dispatch arguments
+        is answered from jax's in-memory caches of that dispatch, so
+        nothing is traced or compiled again while jax holds them."""
+        with self._blocks_lock:
+            bmap = self._block_maps.get(tag)
+            kept = self.programs.get(tag)
+            if bmap is not None or kept is None:
+                return bmap
+            from arbius_tpu.obs.blocks import block_map
+
+            fn, aot_args = kept
+            compiled = fn if hasattr(fn, "as_text") \
+                else fn.lower(*aot_args()).compile()
+            bmap = self._block_maps[tag] = block_map(compiled.as_text())
+            return bmap
 
     def span(self, name: str, **attrs):
         return self.tracer.span(name, **attrs)
@@ -170,7 +201,11 @@ def jit_cache_get(cache: dict, key, build, tag: str | None = None,
     to time. A `PerfScope` on the active obs (`obs.perfscope`,
     docs/perfscope.md) rides the same `aot_args` opt-in: misses compile
     eagerly so the card can read XLA's cost/memory analyses off the
-    compiled executable — same program, same bytes, warm=True."""
+    compiled executable — same program, same bytes, warm=True.
+
+    With `aot_args`, the active obs keeps the executable and the thunk
+    under `tag` (`Obs.programs`), for `Obs.blocks` to read its block map
+    off when asked: a reference each, nothing called."""
     obs = _ACTIVE.get()
     fn = cache.get(key)
     if fn is not None:
@@ -183,11 +218,15 @@ def jit_cache_get(cache: dict, key, build, tag: str | None = None,
                 # life under perfscope/AOT built it eagerly) still
                 # cards the bucket; lazy callables no-op inside
                 obs.perfscope.adopt(tag, fn)
+            if tag not in obs.programs:
+                # built under another obs (or none)
+                _keep_program(obs, tag, fn, aot_args)
         return fn, True, tag
     aot = obs.aot_cache if obs is not None else None
     if aot is not None and aot_args is not None:
         fn, state = aot.get_or_compile(build, aot_args, tag=tag)
         cache[key] = fn
+        _keep_program(obs, tag, fn, aot_args)
         if state == "disk":
             obs.registry.counter("arbius_jit_cache_hits_total",
                                  _JIT_HITS_HELP,
@@ -239,12 +278,27 @@ def jit_cache_get(cache: dict, key, build, tag: str | None = None,
         except Exception:  # noqa: BLE001 — degrade, never fail
             scope._skip("jit_cache_get")
             cache[key] = fn
+            _keep_program(obs, tag, fn, aot_args)
             return fn, False, tag
         scope.record_executable(tag, compiled, compile_seconds=dt)
         cache[key] = compiled
+        _keep_program(obs, tag, compiled, aot_args)
         return compiled, True, tag
     fn = cache[key] = build()
+    _keep_program(obs, tag, fn, aot_args)
     return fn, False, tag
+
+
+def _keep_program(obs: Obs | None, tag: str | None, fn, aot_args) -> None:
+    """Keep a bucket executable and its arguments' thunk on the obs, for
+    `Obs.blocks` (copy-on-write: /debug/blocks iterates `programs` from
+    a request thread); a new build under a tag drops the map of the
+    old."""
+    if obs is None or tag is None or aot_args is None:
+        return
+    with obs._blocks_lock:
+        obs.programs = {**obs.programs, tag: (fn, aot_args)}
+        obs._block_maps.pop(tag, None)
 
 
 def timed_dispatch(warm: bool, tag: str | None = None):
