@@ -56,6 +56,7 @@ import numpy as np
 from arbius_tpu.models.trinity.model import (
     _dot,
     _logits,
+    expert_tile,
     init_tree,
     rms_norm,
     routed_experts,
@@ -69,6 +70,9 @@ F32 = jnp.float32
 # the most bytes one block of float32 attention scores [heads, rows,
 # keys] may take in prefill; rows = keys = `_block`'s answer
 _SCORE_BYTES = 2 ** 27
+# the most bytes the routed experts' row buffers and combine may take for
+# one prefill FFN chunk; rows = `_ffn_rows`'s answer
+_FFN_BYTES = 2 ** 31
 
 
 @dataclass(frozen=True)
@@ -457,9 +461,35 @@ def _block(p: int, heads: int) -> int:
     return max(b for b in range(1, min(p, most) + 1) if p % b == 0)
 
 
+def _routed_bytes(rows: int, cfg: DeepSeekV32Config) -> int:
+    """Bytes `routed_experts` sets aside for `rows` tokens: its two row
+    buffers, each sized for every assignment held and every held expert
+    a ragged tail, and the gathered [rows, k, d] before the combine."""
+    k = cfg.experts_per_token
+    tile = expert_tile(rows, cfg)
+    buf = (-(-rows * k // tile) + cfg.n_held) * tile
+    return (2 * buf + rows * k) * cfg.hidden * cfg.jdtype.itemsize
+
+
+def _ffn_rows(p: int, cfg: DeepSeekV32Config) -> int:
+    """Rows of a prefill FFN chunk for a prompt of `p` positions, from the
+    static shapes alone: the largest multiple of `_block`'s rows that
+    divides p and whose routed temporaries stay within `_FFN_BYTES` —
+    4,096 at the cell's shapes (16,384 positions, 16 of 256 experts held,
+    8 a token), where calls of 512 rows would read every held expert's
+    kernels 32 times a sequence; `_block`'s rows where none fits."""
+    blk = _block(p, cfg.heads)
+    fits = [r for r in range(blk, p + 1, blk)
+            if p % r == 0 and _routed_bytes(r, cfg) <= _FFN_BYTES]
+    return max(fits, default=blk)
+
+
 def _prefill_layer(x, lp, kind, cfg: DeepSeekV32Config):
     """One block on one sequence x[P, d] → (x', latent[P, 576],
-    k_i[P, 128], held)."""
+    k_i[P, 128], held), in two passes: attention a `_block` of query rows
+    at a time, then the FFN a `_ffn_rows` chunk at a time (a token's FFN
+    reads its own row alone, so the chunk is free of the score blocks'
+    bound, and a larger one routes more rows to each expert's tile)."""
     p = x.shape[0]
     nh, dn = cfg.heads, cfg.qk_nope_head_dim
     blk = _block(p, nh)
@@ -509,10 +539,12 @@ def _prefill_layer(x, lp, kind, cfg: DeepSeekV32Config):
                 keep = jnp.ones((blk, p), bool)
 
             o = attend(q_nope, q_pe, k_pe, keep, i, rows, qpos)
-            xb = xb + _dot(o, lp["attn"]["wo"]["kernel"])
-        return _ffn(xb, lp, kind, cfg)
+            return xb + _dot(o, lp["attn"]["wo"]["kernel"])
 
-    out, held = jax.lax.map(block, jnp.arange(n_blk))
+    x = jax.lax.map(block, jnp.arange(n_blk)).reshape(p, cfg.hidden)
+    n = _ffn_rows(p, cfg)
+    out, held = jax.lax.map(lambda xc: _ffn(xc, lp, kind, cfg),
+                            x.reshape(p // n, n, cfg.hidden))
     return out.reshape(p, cfg.hidden), latent, k_i, held.sum(dtype=jnp.int32)
 
 
@@ -550,9 +582,10 @@ def n_moe(cfg: DeepSeekV32Config) -> int:
 def prefill(params, ids, total: int, cfg: DeepSeekV32Config):
     """ids[B, P] → (logits[B, V'] f32 at the last prompt position, carry).
 
-    The batch is walked a sequence at a time (`lax.map`) and a sequence a
-    block of rows at a time, so one block's temporaries — not a
-    sequence's, not the batch's — sit beside the weights. carry =
+    The batch is walked a sequence at a time (`lax.map`) and a sequence's
+    layer a block of rows at a time through attention and a chunk of rows
+    at a time through the FFN, so one block's or one chunk's temporaries
+    — not a sequence's, not the batch's — sit beside the weights. carry =
     (per-layer (latent [B, T, 576], k_i [B, T, 128]) caches, int32
     [assignments, held])."""
     b, p = ids.shape

@@ -65,16 +65,21 @@ class DeepSeekV32Pipeline(SharePipeline):
                      decode_bucket: int) -> dict:
         """One sequence's cache bytes beside what per-head K and V rows
         would take, the pairs the selection leaves to attention beside
-        the causal mask's, and the prefill kernel's counts under the
-        names trinity gives its own — static, from the config."""
+        the causal mask's, the prefill kernel's counts under the names
+        trinity gives its own, and the rows of a prefill FFN chunk with
+        the chunks a bucket runs (each routes its rows to the held
+        experts in one call) — static, from the config."""
         cfg = self.config
         held, per_head = cfg.cache_bytes(prompt_bucket + decode_bucket)
         pairs, causal = cfg.attn_pairs(prompt_bucket, decode_bucket)
         calls, blocks, dense = self.attn_kernel(batch, prompt_bucket)
+        ffn_rows = dsv32._ffn_rows(prompt_bucket, cfg)
         return {"cache_bytes": held, "cache_bytes_per_head": per_head,
                 "attn_pairs": pairs, "attn_pairs_causal": causal,
                 "attn_kernel_calls": calls, "attn_blocks": blocks,
-                "attn_blocks_dense": dense}
+                "attn_blocks_dense": dense, "ffn_rows": ffn_rows,
+                "ffn_calls": batch * len(cfg.layers)
+                * (prompt_bucket // ffn_rows)}
 
     def _init_fn(self):
         return lambda key: dsv32.init_params(self.config, key)

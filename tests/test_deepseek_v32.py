@@ -76,9 +76,10 @@ def _program_logits(cfg, params, ids):
 
 @pytest.fixture
 def small_blocks(monkeypatch):
-    """Blocks of 4 rows in the program's prefill (3 to the prompt) and in
-    the reference (row blocks of 4, coarse blocks of 8, head groups of
-    2), so that every loop over blocks runs more than once."""
+    """Blocks of 4 rows in the program's prefill attention (3 to the
+    prompt; its FFN one chunk of the 12) and in the reference (row blocks
+    of 4, coarse blocks of 8, head groups of 2), so that every loop over
+    attention blocks runs more than once."""
     monkeypatch.setattr(dsv32, "_SCORE_BYTES", 4 * 4 * 4 * 4)
     monkeypatch.setattr(reference, "ROW_BLOCK", 4)
     monkeypatch.setattr(reference, "COARSE", 2)
@@ -165,6 +166,55 @@ def test_prefill_through_the_kernel_gives_the_walks_logits_and_caches(
         assert float(jnp.abs(k_i.astype(jnp.float32)
                              - k_i_k.astype(jnp.float32)).max()) < tol
     assert int(stats_k[0]) == int(stats[0])
+
+
+@pytest.mark.parametrize("dtype,tol,held", [("float32", 1e-5, (4, 12)),
+                                            ("bfloat16", 0.25, (0, 16))])
+def test_ffn_chunks_give_the_block_wise_logits_caches_and_held_count(
+        dtype, tol, held, small_blocks, monkeypatch):
+    """Prefill's FFN pass over chunks of 8 rows (`_ffn_rows` through its
+    own rule, its byte bound cut to 8 rows' temporaries) against the
+    block-wise form, the FFN on each 4-row attention block (the bound at
+    0: no chunk fits, `_block`'s rows): 24 prompt rows, 6 attention
+    blocks, 3 FFN chunks; the dense layer and every expert layer go
+    through the chunks."""
+    cfg = DeepSeekV32Config.tiny(dtype=dtype, experts_held=held)
+    params = _params(cfg, dtype=dtype)
+    p = 24
+    ids = jax.random.randint(jax.random.PRNGKey(6), (2, p), 0, 256)
+    ffn = dsv32._ffn
+    seen = []
+
+    def traced(x, lp, kind, c):
+        seen.append((kind, x.shape))
+        return ffn(x, lp, kind, c)
+
+    monkeypatch.setattr(dsv32, "_ffn", traced)
+
+    def run(ffn_bytes):
+        monkeypatch.setattr(dsv32, "_FFN_BYTES", ffn_bytes)
+        seen.clear()
+        out = jax.jit(lambda q, i: dsv32.prefill(q, i, p + T, cfg))(
+            params, ids)
+        return out, list(seen)
+
+    assert dsv32._block(p, cfg.heads) == 4
+    (want, (caches, stats)), blocks = run(0)
+    assert dsv32._ffn_rows(p, cfg) == 4
+    (got, (caches_c, stats_c)), chunks = run(dsv32._routed_bytes(8, cfg))
+    assert dsv32._ffn_rows(p, cfg) == 8
+    assert blocks == [(k, (4, cfg.hidden)) for k in cfg.layers]
+    assert chunks == [(k, (8, cfg.hidden)) for k in cfg.layers]
+    assert cfg.layers[0] == "dense" and "moe" in cfg.layers
+    assert float(jnp.abs(got - want).max()) < tol
+    for (lat, k_i), (lat_c, k_i_c) in zip(caches, caches_c):
+        assert float(jnp.abs(lat.astype(jnp.float32)
+                             - lat_c.astype(jnp.float32)).max()) < tol
+        assert float(jnp.abs(k_i.astype(jnp.float32)
+                             - k_i_c.astype(jnp.float32)).max()) < tol
+    assert int(stats_c[0]) == int(stats[0]) == 2 * p * 2 * dsv32.n_moe(cfg)
+    assert int(stats_c[1]) == int(stats[1])
+    assert held == (0, 16) or 0 < int(stats[1]) < int(stats[0])
 
 
 def test_the_selection_bites_and_keeps_everything_up_to_index_topk():
@@ -404,6 +454,18 @@ def test_published_widths_and_the_static_counts_at_the_cells_shapes():
     assert dsv32._block(12000, 128) == 500
     assert dsv32._block(12, 4) == 12
     assert expert_tile(512, share) == 32 and expert_tile(8, share) == 8
+    # the FFN chunk: 4,096 rows (256-row tiles, 1.53 GB of routed
+    # temporaries; 8,192 would take 3.05 GB), 160 chunks a bucket of 8
+    # where 512-row blocks made 1,280; the goldened tiny programs run one
+    # chunk of their 12 rows
+    assert dsv32._ffn_rows(16384, share) == 4096
+    assert expert_tile(4096, share) == 256
+    assert dsv32._routed_bytes(4096, share) <= dsv32._FFN_BYTES \
+        < dsv32._routed_bytes(8192, share)
+    attrs = DeepSeekV32Pipeline(share).bucket_attrs(8, 16384, 256)
+    assert (attrs["ffn_rows"], attrs["ffn_calls"]) == (4096, 160)
+    assert dsv32._ffn_rows(12, DeepSeekV32Config.tiny()) == 12
+    assert dsv32._ffn_rows(12000, share) == 4000
     shapes = jax.eval_shape(
         lambda: dsv32.init_params(share, jax.random.PRNGKey(0)))
     n_params = sum(math.prod(x.shape)
@@ -434,10 +496,12 @@ def test_bucket_program_is_deterministic_and_prefix_stable():
     assert set(attrs) == {"cache_bytes", "cache_bytes_per_head",
                           "attn_pairs", "attn_pairs_causal",
                           "attn_kernel_calls", "attn_blocks",
-                          "attn_blocks_dense"}
+                          "attn_blocks_dense", "ffn_rows", "ffn_calls"}
     # the prefill kernel's counts: the walk serves every call off the TPU
     assert (attrs["attn_kernel_calls"], attrs["attn_blocks"],
             attrs["attn_blocks_dense"]) == (0, 0, 0)
+    # the FFN's chunks run on every backend: one of the 12 rows a layer
+    assert (attrs["ffn_rows"], attrs["ffn_calls"]) == (P, 2 * 5)
     assert (attrs["cache_bytes"], attrs["cache_bytes_per_head"]) \
         == cfg.cache_bytes(P + T)
     n = P + T - 1
@@ -568,6 +632,7 @@ def test_greedy_cids_equal_with_the_staged_executor_on_and_off():
         assert "kv_rows" not in a
         assert (a["attn_kernel_calls"], a["attn_blocks"],
                 a["attn_blocks_dense"]) == (0, 0, 0)
+        assert (a["ffn_rows"], a["ffn_calls"]) == (32, 2 * 5)
         made = 2 * (32 + T - 1) * 2 * 4
         assert all(s["attrs"]["assignments"] == s["attrs"]["held"] == made
                    for s in routed)
