@@ -111,6 +111,12 @@ class DeepSeekV32Config:
     eps: float = 1e-6
     dtype: str = "bfloat16"
 
+    # what the shared attention functions multiply the query (after
+    # `wq_b`) and the normed latent (before `wkv_b`, and as cached) by:
+    # none here; models/dots3's `apply_mla_qkv_lora_rescale`
+    q_scale = None
+    kv_scale = None
+
     def __post_init__(self):
         object.__setattr__(self, "layers", tuple(self.layers))
         for name in ("vocab_rows", "experts_held"):
@@ -407,15 +413,22 @@ def _ffn(x, lp, kind, cfg):
 def _queries(h, lp, pos, cfg):
     """h[T, d] at positions pos[T] → q_nope[T, H, 128], q_pe[T, H, 64]
     (rotated), the indexer's q_i[T, Hi, 128] (first dims rotated) and
-    its head weights w[T, Hi] float32."""
-    ap, ip = lp["attn"], lp["indexer"]
+    its head weights w[T, Hi] float32; (q_nope, q_pe, None, None) for a
+    layer with no indexer. `cfg` is the attention's shape (this model's
+    config, or a `models.dots3.model.MLA`)."""
+    ap = lp["attn"]
     t = h.shape[0]
     c_q = rms_norm(_dot(h, ap["wq_a"]["kernel"]), ap["q_norm"]["scale"],
                    cfg.eps)
     q = _dot(c_q, ap["wq_b"]["kernel"]).reshape(t, cfg.heads,
                                                 cfg.qk_head_dim)
+    if cfg.q_scale is not None:
+        q = q * cfg.q_scale
     q_nope = q[..., :cfg.qk_nope_head_dim]
     q_pe = rope_pairs(q[..., cfg.qk_nope_head_dim:], pos[:, None], cfg)
+    if "indexer" not in lp:
+        return q_nope, q_pe, None, None
+    ip = lp["indexer"]
     q_i = _dot(c_q, ip["wq_b"]["kernel"]).reshape(
         t, cfg.index_heads, cfg.index_head_dim)
     r = cfg.qk_rope_head_dim
@@ -430,13 +443,19 @@ def _queries(h, lp, pos, cfg):
 def _keys(h, lp, pos, cfg):
     """h[T, d] at positions pos[T] → the rows the two caches keep:
     latent[T, 576] = kv_norm(c_kv) | rope(k_pe), and the indexer's
-    k_i[T, 128] = LayerNorm, first dims rotated."""
-    ap, ip = lp["attn"], lp["indexer"]
+    k_i[T, 128] = LayerNorm, first dims rotated (None for a layer with
+    no indexer)."""
+    ap = lp["attn"]
     kv = _dot(h, ap["wkv_a"]["kernel"])
     c = cfg.kv_lora_rank
-    latent = jnp.concatenate(
-        [rms_norm(kv[..., :c], ap["kv_norm"]["scale"], cfg.eps),
-         rope_pairs(kv[..., c:], pos, cfg)], axis=-1)
+    c_kv = rms_norm(kv[..., :c], ap["kv_norm"]["scale"], cfg.eps)
+    if cfg.kv_scale is not None:
+        c_kv = c_kv * cfg.kv_scale
+    latent = jnp.concatenate([c_kv, rope_pairs(kv[..., c:], pos, cfg)],
+                             axis=-1)
+    if "indexer" not in lp:
+        return latent, None
+    ip = lp["indexer"]
     k_i = layer_norm(_dot(h, ip["wk"]["kernel"]), ip["k_norm"], cfg.eps)
     r = cfg.qk_rope_head_dim
     k_i = jnp.concatenate(
@@ -484,12 +503,24 @@ def _ffn_rows(p: int, cfg: DeepSeekV32Config) -> int:
     return max(fits, default=blk)
 
 
-def _prefill_layer(x, lp, kind, cfg: DeepSeekV32Config):
-    """One block on one sequence x[P, d] → (x', latent[P, 576],
-    k_i[P, 128], held), in two passes: attention a `_block` of query rows
-    at a time, then the FFN a `_ffn_rows` chunk at a time (a token's FFN
-    reads its own row alone, so the chunk is free of the score blocks'
-    bound, and a larger one routes more rows to each expert's tile)."""
+def _head_gate(o, h, ap, heads: int):
+    """o[..., H·dv] with each head's output times sigmoid(h·W_g)[..., H]
+    (models/dots3's headwise gate, read off the attention's normed input
+    h), or o itself where the layer has no `gate`."""
+    if "gate" not in ap:
+        return o
+    g = jax.nn.sigmoid(jnp.dot(h, ap["gate"]["kernel"],
+                               preferred_element_type=F32))
+    o = o.reshape(*o.shape[:-1], heads, -1)
+    return (o.astype(F32) * g[..., None]).astype(o.dtype).reshape(
+        *o.shape[:-2], -1)
+
+
+def _prefill_attention(x, lp, cfg):
+    """The attention half of a block on one sequence x[P, d] → (x',
+    latent[P, 576], k_i[P, 128]): a `_block` of query rows at a time over
+    the key blocks up to its diagonal, under the selection. `cfg` is the
+    attention's shape (models/dots3 passes its full layers' `MLA`)."""
     p = x.shape[0]
     nh, dn = cfg.heads, cfg.qk_nope_head_dim
     blk = _block(p, nh)
@@ -539,13 +570,32 @@ def _prefill_layer(x, lp, kind, cfg: DeepSeekV32Config):
                 keep = jnp.ones((blk, p), bool)
 
             o = attend(q_nope, q_pe, k_pe, keep, i, rows, qpos)
+            o = _head_gate(o, hb, lp["attn"], nh)
             return xb + _dot(o, lp["attn"]["wo"]["kernel"])
 
-    x = jax.lax.map(block, jnp.arange(n_blk)).reshape(p, cfg.hidden)
+    x = jax.lax.map(block, jnp.arange(n_blk)).reshape(p, x.shape[1])
+    return x, latent, k_i
+
+
+def _prefill_ffn(x, lp, kind, cfg):
+    """The FFN half of a block on one sequence x[P, d] → (x', held), a
+    `_ffn_rows` chunk at a time (a token's FFN reads its own row alone,
+    so the chunk is free of the score blocks' bound, and a larger one
+    routes more rows to each expert's tile)."""
+    p, d = x.shape
     n = _ffn_rows(p, cfg)
     out, held = jax.lax.map(lambda xc: _ffn(xc, lp, kind, cfg),
-                            x.reshape(p // n, n, cfg.hidden))
-    return out.reshape(p, cfg.hidden), latent, k_i, held.sum(dtype=jnp.int32)
+                            x.reshape(p // n, n, d))
+    return out.reshape(p, d), held.sum(dtype=jnp.int32)
+
+
+def _prefill_layer(x, lp, kind, cfg: DeepSeekV32Config):
+    """One block on one sequence x[P, d] → (x', latent[P, 576],
+    k_i[P, 128], held), in two passes: attention a `_block` of query rows
+    at a time, then the FFN a `_ffn_rows` chunk at a time."""
+    x, latent, k_i = _prefill_attention(x, lp, cfg)
+    x, held = _prefill_ffn(x, lp, kind, cfg)
+    return x, latent, k_i, held
 
 
 def _prefill_piece(params, ids, total: int, cfg: DeepSeekV32Config):
@@ -596,10 +646,11 @@ def prefill(params, ids, total: int, cfg: DeepSeekV32Config):
     return _logits(params, last, cfg), (caches, stats)
 
 
-def _decode_attention(q_nope, q_pe, lat, keep, ap, cfg):
+def _decode_attention(q_nope, q_pe, lat, keep, ap, cfg, h=None):
     """The latent form on the cache lat[B, T, 576] under keep[B, T]:
     W_UK folded into the query, softmax over the kept rows, the weighted
-    sum of latents, W_UV after."""
+    sum of latents, W_UV after, each head gated by the attention's normed
+    input h[B, d] where the layer has a `gate` (`_head_gate`)."""
     nh, dn, dv = cfg.heads, cfg.qk_nope_head_dim, cfg.v_head_dim
     c = cfg.kv_lora_rank
     wkv_b = ap["wkv_b"]["kernel"].reshape(c, nh, dn + dv)
@@ -614,7 +665,36 @@ def _decode_attention(q_nope, q_pe, lat, keep, ap, cfg):
                        preferred_element_type=F32).astype(lat.dtype)
     o = jnp.einsum("bhc,chd->bhd", o_lat, wkv_b[..., dn:],
                    preferred_element_type=F32).astype(lat.dtype)
-    return _dot(o.reshape(o.shape[0], nh * dv), ap["wo"]["kernel"])
+    o = _head_gate(o.reshape(o.shape[0], nh * dv), h, ap, nh)
+    return _dot(o, ap["wo"]["kernel"])
+
+
+def _decode_layer_attention(x, lp, lat, k_i, pos, at, cfg):
+    """The attention half of a decode step on x[B, d] at position `pos`
+    (`at`: it for every row) → (x', lat, k_i): this position's latent and
+    indexer key written at row `pos`, the selection over every row up to
+    it, attention over it in the latent form. `cfg` is the attention's
+    shape, as `_prefill_attention`'s."""
+    b = x.shape[0]
+    t = lat.shape[1]
+    with jax.named_scope("attention"):
+        h = rms_norm(x, lp["attn_norm"]["scale"], cfg.eps)
+        q_nope, q_pe, q_i, w = _queries(h, lp, at, cfg)
+        row, k_row = _keys(h, lp, at, cfg)
+        lat = jax.lax.dynamic_update_slice(
+            lat, row[:, None].astype(lat.dtype), (0, pos, 0))
+        k_i = jax.lax.dynamic_update_slice(
+            k_i, k_row[:, None].astype(k_i.dtype), (0, pos, 0))
+        keep = jnp.broadcast_to(jnp.arange(t) <= pos, (b, t))
+        if cfg.index_topk < t:
+            with jax.named_scope("indexer"):
+                index = jax.vmap(lambda q, wt, k: _index_scores(
+                    q[None], wt[None], k)[0])(q_i, w, k_i)
+                keep &= select_topk(jnp.where(keep, index, -jnp.inf),
+                                    cfg.index_topk)
+        x = x + _decode_attention(q_nope, q_pe, lat, keep, lp["attn"], cfg,
+                                  h)
+    return x, lat, k_i
 
 
 def decode(params, tok, carry, pos, cfg: DeepSeekV32Config):
@@ -630,25 +710,8 @@ def decode(params, tok, carry, pos, cfg: DeepSeekV32Config):
     new = []
     for i, kind in enumerate(cfg.layers):
         lp = params[f"layer_{i}"]
-        lat, k_i = caches[i]
-        t = lat.shape[1]
-        with jax.named_scope("attention"):
-            h = rms_norm(x, lp["attn_norm"]["scale"], cfg.eps)
-            q_nope, q_pe, q_i, w = _queries(h, lp, at, cfg)
-            row, k_row = _keys(h, lp, at, cfg)
-            lat = jax.lax.dynamic_update_slice(
-                lat, row[:, None].astype(lat.dtype), (0, pos, 0))
-            k_i = jax.lax.dynamic_update_slice(
-                k_i, k_row[:, None].astype(k_i.dtype), (0, pos, 0))
-            keep = jnp.broadcast_to(jnp.arange(t) <= pos, (b, t))
-            if cfg.index_topk < t:
-                with jax.named_scope("indexer"):
-                    index = jax.vmap(lambda q, wt, k: _index_scores(
-                        q[None], wt[None], k)[0])(q_i, w, k_i)
-                    keep &= select_topk(jnp.where(keep, index, -jnp.inf),
-                                        cfg.index_topk)
-            x = x + _decode_attention(q_nope, q_pe, lat, keep, lp["attn"],
-                                      cfg)
+        x, lat, k_i = _decode_layer_attention(x, lp, *caches[i], pos, at,
+                                              cfg)
         x, n = _ffn(x, lp, kind, cfg)
         held = held + n
         new.append((lat, k_i))

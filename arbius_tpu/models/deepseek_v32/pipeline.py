@@ -22,21 +22,22 @@ from arbius_tpu.models.trinity.pipeline import (
 from arbius_tpu.ops import selected_flash
 
 
-def selected_kernel_counts(cfg, batch: int, prompt_bucket: int) -> tuple:
+def selected_kernel_counts(cfg, batch: int, prompt_bucket: int,
+                           layers: int | None = None) -> tuple:
     """`attn_kernel` of a family whose prefill attention is
-    `ops.selected_flash.selected_attention` (this one, and
-    models/joyai_flash under an all-ones selection). Static, from the
-    rule that function reads off the same shapes: one call a block of
-    query rows, a layer, a sequence (prefill walks the batch a sequence
-    at a time and a sequence a block at a time), each over every group
-    of heads."""
+    `ops.selected_flash.selected_attention` (this one, models/joyai_flash
+    under an all-ones selection, and models/dots3's `layers` full layers
+    at their shape `cfg`). Static, from the rule that function reads off
+    the same shapes: one call a block of query rows, a layer, a sequence
+    (prefill walks the batch a sequence at a time and a sequence a block
+    at a time), each over every group of heads."""
     if not selected_flash.kernel_serves(
             prompt_bucket, cfg.qk_nope_head_dim, cfg.v_head_dim):
         return 0, 0, 0
     rows = dsv32._block(prompt_bucket, cfg.heads)
     walked, dense = selected_flash.walk_blocks(prompt_bucket, rows,
                                                cfg.heads)
-    each = batch * len(cfg.layers)
+    each = batch * (len(cfg.layers) if layers is None else layers)
     return each * (prompt_bucket // rows), each * walked, each * dense
 
 

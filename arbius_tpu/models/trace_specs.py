@@ -96,6 +96,7 @@ def all_trace_specs() -> list[TraceSpec]:
     without jax/flax side effects until it actually audits.
     """
     from arbius_tpu.models.deepseek_v32 import pipeline as dsv32_pipeline
+    from arbius_tpu.models.dots3 import pipeline as dots3_pipeline
     from arbius_tpu.models.joyai_flash import pipeline as joyai_pipeline
     from arbius_tpu.models.kandinsky2 import pipeline as kandinsky2_pipeline
     from arbius_tpu.models.rvm import pipeline as rvm_pipeline
@@ -108,6 +109,6 @@ def all_trace_specs() -> list[TraceSpec]:
     specs: list[TraceSpec] = []
     for mod in (sd15_pipeline, kandinsky2_pipeline, rvm_pipeline,
                 video_pipeline, textgen_pipeline, trinity_pipeline,
-                dsv32_pipeline, joyai_pipeline, meshsolve):
+                dsv32_pipeline, joyai_pipeline, dots3_pipeline, meshsolve):
         specs.extend(mod.trace_specs())
     return validate_specs(specs)

@@ -302,11 +302,19 @@ def _joyai_llm_flash(m: ModelConfig, mesh, mode: str, tg):
                          JoyAIFlashPipeline)
 
 
+def _dots3_note(m: ModelConfig, mesh, mode: str, tg):
+    from arbius_tpu.models.dots3 import Dots3NoteConfig, Dots3NotePipeline
+
+    return _share_family(m, mesh, mode, tg, Dots3NoteConfig,
+                         Dots3NotePipeline)
+
+
 # text templates: builders that take the template's sequence-bucket
 # policy (cfg.textgen.for_template) on top of the common triple
 _TEXT_BUILDERS = {"textgen": _textgen, "trinity": _trinity,
                   "deepseek_v32": _deepseek_v32,
-                  "joyai_llm_flash": _joyai_llm_flash}
+                  "joyai_llm_flash": _joyai_llm_flash,
+                  "dots3_note": _dots3_note}
 
 
 def _rvm(m: ModelConfig, mesh, resolve_file):

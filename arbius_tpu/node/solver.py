@@ -578,6 +578,20 @@ def count_latent_cache(held: int, per_head: int) -> None:
         c.inc(per_head, form="per_head")
 
 
+def count_window_cache(window: int, full: int) -> None:
+    """Bump `arbius_text_cache_bytes_total{form}` for a family whose
+    sliding-window layers keep a ring of latent rows: `form=
+    "window_latent"` the bytes those rings hold, `form="latent"` what its
+    full layers' latent and indexer caches hold — counted at dispatch
+    from the bucket's shape (docs/text-serving.md)."""
+    c = _counter("arbius_text_cache_bytes_total",
+                 "cache bytes of dispatched text buckets, as held and as "
+                 "per-head K/V rows would be", labelnames=("form",))
+    if c is not None:
+        c.inc(window, form="window_latent")
+        c.inc(full, form="latent")
+
+
 def count_attn_pairs(kept: int, causal: int) -> None:
     """Bump `arbius_attn_pairs_total{mask}`: the (query, key) pairs a
     sparse-attention family's bucket leaves to attention's softmax
@@ -611,7 +625,7 @@ def count_speculation(steps: int, drafts: int, accepted: int) -> None:
 
 
 class TextGenRunner:
-    """text-template runner (textgen, trinity, deepseek_v32, joyai_llm_flash): decoder-only LM → deterministic UTF-8.
+    """text-template runner (textgen, trinity, deepseek_v32, joyai_llm_flash, dots3_note): decoder-only LM → deterministic UTF-8.
 
     Template variables (templates/textgen.json): prompt,
     max_new_tokens, sampler (enum); output out-1.txt. The sequence
@@ -671,6 +685,9 @@ class TextGenRunner:
                                batch * attrs["cache_bytes_per_head"])
             count_attn_pairs(batch * attrs["attn_pairs"],
                              batch * attrs["attn_pairs_causal"])
+        if "cache_bytes_window" in attrs:   # rings beside full caches
+            count_window_cache(batch * attrs["cache_bytes_window"],
+                               batch * attrs["cache_bytes_full"])
         with span("text.bucket", model=self.pipeline.FAMILY,
                   prompt_bucket=pb, decode_bucket=db, batch=batch,
                   **attrs):

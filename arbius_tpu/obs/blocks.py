@@ -33,6 +33,8 @@ VOCABULARY: dict[str, tuple[str, ...]] = {
                      "indexer"),
     "joyai_llm_flash": ("prefill", "decode", "attention", "routed_experts",
                         "draft"),
+    "dots3_note": ("prefill", "decode", "attention", "window_attention",
+                   "routed_experts", "indexer"),
     "kandinsky2": ("text_tower", "prior", "unet", "movq"),
     "sd15": ("text_encoder", "unet", "vae"),
 }

@@ -54,6 +54,14 @@ groups; tests/test_selected_flash.py pins these):
 
 `selected_flash_attention` is a drop-in for `selected_walk`;
 `interpret=True` runs it on the CPU (tests/test_selected_flash.py).
+
+The band (dots3_note's sliding layers, the second half of this file):
+`window_flash_attention` and its walk `window_walk` attend each row to
+the last `window` keys, the mask made from positions in the kernel and
+no `[R, P]` array; a query block walks the key blocks the band reaches
+and no other (`walk_blocks(..., window=)`: 31 of 256 a group of heads at
+P = 8,192, window 513, 512-row tiles). tests/test_dots3.py runs it with
+`interpret=True`.
 """
 from __future__ import annotations
 
@@ -123,17 +131,30 @@ def _last(start, i, block_q: int, block_k: int, n_k: int, least=min):
     return least((start + (i + 1) * block_q - 1) // block_k, n_k - 1)
 
 
+def _first(start, i, block_q: int, block_k: int, window: int | None,
+           most=max):
+    """The first key block query block `i` walks: 0, or under a window
+    of `window` keys (a row's own included) the one that holds its first
+    row's first key. Host and kernel alike, as `_last`."""
+    if window is None:
+        return 0
+    return most(start + i * block_q - window + 1, 0) // block_k
+
+
 @functools.lru_cache(maxsize=None)
-def walk_blocks(p: int, rows: int, heads: int) -> tuple[int, int]:
+def walk_blocks(p: int, rows: int, heads: int,
+                window: int | None = None) -> tuple[int, int]:
     """(key blocks the programs walk over one sequence of `p` positions
     served `rows` query rows a call — a block counts once for each group
     of heads that meets it —, blocks of the unmasked grid at the same
-    tiles)."""
+    tiles); with `window`, the band's walk (`window_flash_attention`,
+    one call of all `p` rows)."""
     block_q, block_k = _tiles(rows, p)
     n_q = _round_up(rows, block_q) // block_q
     n_k = _round_up(p, block_k) // block_k
     groups = heads // _group(heads)
     walked = sum(_last(start, i, block_q, block_k, n_k) + 1
+                 - _first(start, i, block_q, block_k, window)
                  for start in range(0, p, rows) for i in range(n_q))
     return groups * walked, groups * -(-p // rows) * n_q * n_k
 
@@ -357,3 +378,185 @@ def selected_attention(kv, heads: int, dn: int, *, scale: float):
     kv = kv.reshape(p, heads, -1)
     return lambda q_nope, q_pe, k_pe, *at: selected_walk(
         q_nope, q_pe, kv, k_pe, *at, scale=scale)
+
+
+# -- the band: sliding-window attention, the mask made in the kernel ---------
+#
+# dots3_note's sliding layers attend the last `window` keys of each row (513
+# at the published width, the row's own included) with 64 heads of 192 +
+# 64 | 128. There is no selection, so no `keep` array: the band is two
+# comparisons of positions, made in VMEM, and a query block walks only the
+# key blocks the band reaches — 31 of the 256 blocks of the unmasked grid a
+# group of heads at 8,192 positions and 512-row tiles, where the causal walk
+# visits 136. A head's query and key come JOINED, nope | rope (192 + 64 =
+# 256 lanes: one product a key block, whole lanes, where 192 alone is not),
+# the keys with the rotary key broadcast to every head; the values apart.
+# One call a (layer, sequence), all rows: the first row is position 0, so
+# every bound is static but the query block's, which the index maps read
+# off the program id.
+
+
+def _window_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                   scale: float, window: int, block_q: int, block_k: int,
+                   group: int, dk: int, dv: int, n_k: int, steps: int):
+    i, j = pl.program_id(1), pl.program_id(2)
+    kb = _first(0, i, block_q, block_k, window, jnp.maximum) + j
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, F32)
+        l_ref[...] = jnp.zeros(l_ref.shape, F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+    @pl.when(kb <= _last(0, i, block_q, block_k, n_k, jnp.minimum))
+    def _():
+        qpos = i * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        kpos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        ok = (kpos <= qpos) & (kpos > qpos - window)
+        for g in range(group):
+            q = q_ref[:, g * dk:(g + 1) * dk]
+            k = k_ref[:, g * dk:(g + 1) * dk]
+            v = v_ref[:, g * dv:(g + 1) * dv]
+            s = jax.lax.dot_general(q, k, _NT,
+                                    preferred_element_type=F32) * scale
+            s = jnp.where(ok, s, NEG_INF)
+            m = m_ref[g]                  # a row's max, in every lane
+            mb = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.where(ok, jnp.exp(s - _spread(mb, block_k)), 0.0)
+            alpha = jnp.exp(m - mb)
+            l_ref[g] = l_ref[g] * alpha + p.sum(axis=-1, keepdims=True)
+            acc_ref[g] = acc_ref[g] * _spread(alpha, dv) + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=F32)
+            m_ref[g] = mb
+
+    @pl.when(j == steps - 1)
+    def _():
+        for g in range(group):
+            o_ref[:, g * dv:(g + 1) * dv] = \
+                (acc_ref[g] / _spread(l_ref[g], dv)).astype(o_ref.dtype)
+
+
+def _window_vmem_bytes(block_q: int, block_k: int, group: int, dk: int,
+                       dv: int, itemsize: int) -> int:
+    """`_vmem_bytes`' count for the band's blocks: q, k, v and the
+    output, two buffers each; the heads' running state; the scores, the
+    probabilities and their cast a head in flight, two at a time, and
+    the mask."""
+    blocks = 2 * group * (block_q * (dk + dv) + block_k * (dk + dv)) \
+        * itemsize
+    state = group * block_q * (2 * _LANES + dv) * 4
+    work = (2 * 3 + 1) * block_q * block_k * 4
+    return blocks + state + work
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale",
+                                             "interpret"))
+def window_flash_attention(q, k, v, *, window: int, scale: float,
+                           interpret: bool = False):
+    """q[P, H, dk], k[P, H, dk], v[P, H, dv] of one sequence (positions
+    0 .. P-1; dk and dv whole lanes) → [P, H·dv]: row t attends keys
+    t - window < s <= t. `window_walk`'s result but for the order of the
+    softmax's sums. Tiles come from the static shape."""
+    p, heads, dk = q.shape
+    dv = v.shape[-1]
+    block_q, block_k = _tiles(p, p)
+    group = _group(heads)
+    qf = _pad_to(q.reshape(p, heads * dk), 0, block_q)
+    kf = _pad_to(k.reshape(p, heads * dk), 0, block_k)
+    vf = _pad_to(v.reshape(p, heads * dv), 0, block_k)
+    n_q, n_k = qf.shape[0] // block_q, kf.shape[0] // block_k
+    steps = max(_last(0, i, block_q, block_k, n_k) + 1
+                - _first(0, i, block_q, block_k, window)
+                for i in range(n_q))
+
+    def at(i, j):
+        return jnp.minimum(
+            _first(0, i, block_q, block_k, window, jnp.maximum) + j,
+            _last(0, i, block_q, block_k, n_k, jnp.minimum))
+
+    def spec(rows, width, index):
+        return pl.BlockSpec((rows, group * width), index)
+
+    out = pl.pallas_call(
+        functools.partial(_window_kernel, scale=scale, window=window,
+                          block_q=block_q, block_k=block_k, group=group,
+                          dk=dk, dv=dv, n_k=n_k, steps=steps),
+        grid=(heads // group, n_q, steps),
+        in_specs=[spec(block_q, dk, lambda h, i, j: (i, h)),
+                  spec(block_k, dk, lambda h, i, j: (at(i, j), h)),
+                  spec(block_k, dv, lambda h, i, j: (at(i, j), h))],
+        out_specs=spec(block_q, dv, lambda h, i, j: (i, h)),
+        scratch_shapes=[pltpu.VMEM((group, block_q, _LANES), F32),
+                        pltpu.VMEM((group, block_q, _LANES), F32),
+                        pltpu.VMEM((group, block_q, dv), F32)],
+        out_shape=jax.ShapeDtypeStruct((qf.shape[0], heads * dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(
+                _VMEM_DEFAULT,
+                _window_vmem_bytes(block_q, block_k, group, dk, dv,
+                                   kf.dtype.itemsize) + _VMEM_HEADROOM)),
+        interpret=interpret,
+        name="window_flash_attention",
+    )(qf, kf, vf)
+    return out[:p]
+
+
+def window_walk(q, k, v, *, window: int, scale: float, block: int):
+    """XLA's walk of the band, the exact reference and the only compiled
+    form off the TPU: q[P, H, dk], k[P, H, dk], v[P, H, dv] → [P, H·dv],
+    a `block` of query rows at a time (`lax.map`) over the key blocks
+    (`block` rows each) from the one that holds its first row's first
+    key to its diagonal — `walk_blocks`' bounds at tiles (block, block) —
+    under the band's mask, with a running max / normaliser /
+    accumulator. `block` divides P."""
+    p, nh, dk = q.shape
+    dv = v.shape[-1]
+    rows = jnp.arange(block)
+
+    def query_block(i):
+        qb = jax.lax.dynamic_slice_in_dim(q, i * block, block)
+        qpos = i * block + rows[:, None]
+
+        def attend(j, carry):
+            m, l, acc = carry
+            kb = jax.lax.dynamic_slice_in_dim(k, j * block, block)
+            vb = jax.lax.dynamic_slice_in_dim(v, j * block, block)
+            s = jnp.einsum("qhd,khd->hqk", qb, kb,
+                           preferred_element_type=F32)
+            kpos = j * block + rows[None, :]
+            ok = (kpos <= qpos) & (kpos > qpos - window)
+            s = jnp.where(ok[None], s * scale, NEG_INF)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            pr = jnp.where(ok[None], jnp.exp(s - m_new[..., None]), 0.0)
+            fix = jnp.exp(m - m_new)
+            l = l * fix + pr.sum(axis=-1)
+            acc = acc * fix[..., None] + jnp.einsum(
+                "hqk,khd->hqd", pr.astype(vb.dtype), vb,
+                preferred_element_type=F32)
+            return m_new, l, acc
+
+        m0 = jnp.full((nh, block), NEG_INF, F32)
+        _, l, acc = jax.lax.fori_loop(
+            _first(0, i, block, block, window, jnp.maximum), i + 1, attend,
+            (m0, jnp.zeros((nh, block), F32),
+             jnp.zeros((nh, block, dv), F32)))
+        o = (acc / l[..., None]).astype(q.dtype)
+        return jnp.moveaxis(o, 0, 1).reshape(block, nh * dv)
+
+    return jax.lax.map(query_block, jnp.arange(p // block)).reshape(
+        p, nh * dv)
+
+
+def window_attention(q, k, v, *, window: int, scale: float, block: int):
+    """Sliding-window prefill attention of one sequence, the path read
+    off the call by the selection kernel's rule (`kernel_serves`, with
+    the joined nope | rope width for dn): on a TPU from
+    `_KERNEL_MIN_ROWS` positions `window_flash_attention`, else
+    `window_walk` at `block` rows. q[P, H, dk], k[P, H, dk], v[P, H, dv]
+    → [P, H·dv]."""
+    if kernel_serves(q.shape[0], q.shape[-1], v.shape[-1]):
+        return window_flash_attention(q, k, v, window=window, scale=scale)
+    return window_walk(q, k, v, window=window, scale=scale, block=block)
