@@ -41,6 +41,16 @@ def selected_kernel_counts(cfg, batch: int, prompt_bucket: int,
     return each * (prompt_bucket // rows), each * walked, each * dense
 
 
+def ffn_expert_calls(cfg, n_moe: int, batch: int, prompt_bucket: int,
+                     decode_bucket: int) -> tuple:
+    """`expert_calls` of a family whose prefill FFN runs in
+    `_ffn_rows` chunks (this one, models/dots3) and whose decode loop is
+    the scan: a call an expert layer a chunk, and one a step."""
+    rows = dsv32._ffn_rows(prompt_bucket, cfg)
+    return ((rows, batch * n_moe * (prompt_bucket // rows)),
+            (batch, (decode_bucket - 1) * n_moe))
+
+
 class DeepSeekV32Pipeline(SharePipeline):
     FAMILY = "deepseek_v32"
 
@@ -80,7 +90,16 @@ class DeepSeekV32Pipeline(SharePipeline):
                 "attn_kernel_calls": calls, "attn_blocks": blocks,
                 "attn_blocks_dense": dense, "ffn_rows": ffn_rows,
                 "ffn_calls": batch * len(cfg.layers)
-                * (prompt_bucket // ffn_rows)}
+                * (prompt_bucket // ffn_rows),
+                **self.expert_paths(batch, prompt_bucket, decode_bucket)}
+
+    def expert_calls(self, batch: int, prompt_bucket: int,
+                     decode_bucket: int) -> tuple:
+        """((rows routed at once, calls a bucket makes), ...): an expert
+        layer's call a prefill FFN chunk, and one a decode step over the
+        batch."""
+        return ffn_expert_calls(self.config, dsv32.n_moe(self.config),
+                                batch, prompt_bucket, decode_bucket)
 
     def _init_fn(self):
         return lambda key: dsv32.init_params(self.config, key)

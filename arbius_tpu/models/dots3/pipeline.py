@@ -14,7 +14,10 @@ banded prefill attention for the sliding layers.
 from __future__ import annotations
 
 from arbius_tpu.models.deepseek_v32.model import _ffn_rows
-from arbius_tpu.models.deepseek_v32.pipeline import selected_kernel_counts
+from arbius_tpu.models.deepseek_v32.pipeline import (
+    ffn_expert_calls,
+    selected_kernel_counts,
+)
 from arbius_tpu.models.dots3 import model as dots3
 from arbius_tpu.models.dots3.model import Dots3NoteConfig
 from arbius_tpu.models.trinity.pipeline import (
@@ -87,7 +90,15 @@ class Dots3NotePipeline(SharePipeline):
                 "attn_blocks_dense": dense,
                 "ffn_rows": ffn_rows,
                 "ffn_calls": batch * len(cfg.layers)
-                * (prompt_bucket // ffn_rows)}
+                * (prompt_bucket // ffn_rows),
+                **self.expert_paths(batch, prompt_bucket, decode_bucket)}
+
+    def expert_calls(self, batch: int, prompt_bucket: int,
+                     decode_bucket: int) -> tuple:
+        """deepseek_v32's: a call an expert layer a prefill FFN chunk,
+        and one a decode step over the batch."""
+        return ffn_expert_calls(self.config, dots3.n_moe(self.config),
+                                batch, prompt_bucket, decode_bucket)
 
     def _init_fn(self):
         return lambda key: dots3.init_params(self.config, key)

@@ -162,7 +162,23 @@ class JoyAIFlashPipeline(SharePipeline):
         return {"latent_bytes": self.config.cache_bytes(
                     prompt_bucket + decode_bucket),
                 "attn_kernel_calls": calls, "attn_blocks": blocks,
-                "attn_blocks_dense": dense}
+                "attn_blocks_dense": dense,
+                **self.expert_paths(batch, prompt_bucket, decode_bucket)}
+
+    def expert_calls(self, batch: int, prompt_bucket: int,
+                     decode_bucket: int) -> tuple:
+        """((rows routed at once, calls a bucket makes), ...): an expert
+        layer's call a prefill block of query rows; the module's first
+        draft over the batch; then, a step, each expert layer's and the
+        module's call over its two positions a row — counted at one token
+        a step, the loop's most steps (`text.speculate` says how many
+        ran)."""
+        cfg = self.config
+        n = joyai.n_moe(cfg)
+        rows = joyai._block(prompt_bucket, cfg.heads)
+        return ((rows, batch * n * (prompt_bucket // rows)),
+                (batch, 1),
+                (2 * batch, (decode_bucket - 1) * (n + 1)))
 
     def _init_fn(self):
         return lambda key: joyai.init_params(self.config, key)

@@ -32,6 +32,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from arbius_tpu.ops import grouped
 from arbius_tpu.ops.causal_flash import causal_attention
 
 _NEG = -1e30
@@ -275,6 +276,17 @@ def expert_tile(tokens: int, cfg: TrinityConfig) -> int:
     return min(512, max(8, 1 << math.ceil(math.log2(max(1.0, 2 * expect)))))
 
 
+def grouped_walk(tokens: int, cfg: TrinityConfig) -> bool:
+    """Whether a `routed_experts` call of `tokens` rows walks its tiles
+    with the grouped product here (`ops.grouped.kernel_serves`), from the
+    static shape alone: its tile rows, and the held experts it can reach
+    — no more than the held assignments it expects (tokens · k · held /
+    experts), nor than the experts held."""
+    reach = min(cfg.n_held, -(-tokens * cfg.experts_per_token * cfg.n_held
+                              // cfg.num_experts))
+    return grouped.kernel_serves(expert_tile(tokens, cfg), reach)
+
+
 def routed_experts(x, chosen, w, experts, cfg: TrinityConfig):
     """The held experts' part of Σ_i w_i · expert_i(x), and how many of
     the (token, choice) assignments fell on held experts.
@@ -282,9 +294,14 @@ def routed_experts(x, chosen, w, experts, cfg: TrinityConfig):
     Grouped products over the stacked [held, d, f] kernels with work
     proportional to the load, in prefill and in a decode step alike:
     held assignments are sorted by expert, each expert's group padded to
-    whole tiles (`expert_tile` rows, from the token count), and a loop
-    walks the USED tiles only — one expert's three kernels a tile, so an
-    expert no token was sent to is never read.
+    whole tiles (`expert_tile` rows, from the token count), and only the
+    USED tiles are walked, so an expert no token was sent to is never
+    read. The walk follows the static shape (`grouped_walk`): on the TPU,
+    for small tiles in a call that reaches many experts (a decode step
+    or a prefill block of joyai_llm_flash, every expert held), one
+    grouped product a kernel (`ops.grouped.grouped_dot`), each grid step
+    one tile of one expert; elsewhere a `fori_loop`, one expert's three
+    kernels a tile.
     Dispatch and combine are gathers (no scatter at all); the combine
     is float32."""
     t, k = chosen.shape
@@ -327,17 +344,29 @@ def routed_experts(x, chosen, w, experts, cfg: TrinityConfig):
 
     gate, up, down = (experts[n]["kernel"] for n in ("gate", "up", "down"))
 
-    def body(j, out):
-        ex = tile_expert[j]
-        xt = jax.lax.dynamic_slice(xs, (j * tile, 0), (tile, d))
-        p = {n: {"kernel": jax.lax.dynamic_index_in_dim(
-                 kern, ex, 0, keepdims=False)}
-             for n, kern in (("gate", gate), ("up", up), ("down", down))}
-        return jax.lax.dynamic_update_slice(out, swiglu(xt, p),
-                                            (j * tile, 0))
+    if grouped_walk(t, cfg):
+        # every group whole tiles: each grid step is one tile of one
+        # expert. `swiglu`'s roundings; rows past the used tiles are
+        # never written, so the zero row is appended after the products
+        sizes = tiles_e * tile
+        g, u = (grouped.grouped_dot(xs, kern, sizes, tile)
+                for kern in (gate, up))
+        hs = (jax.nn.silu(g.astype(F32)) * u.astype(F32)).astype(x.dtype)
+        out = jnp.concatenate([grouped.grouped_dot(hs, down, sizes, tile),
+                               jnp.zeros((1, d), x.dtype)])
+    else:
+        def body(j, out):
+            ex = tile_expert[j]
+            xt = jax.lax.dynamic_slice(xs, (j * tile, 0), (tile, d))
+            p = {n: {"kernel": jax.lax.dynamic_index_in_dim(
+                     kern, ex, 0, keepdims=False)}
+                 for n, kern in (("gate", gate), ("up", up),
+                                 ("down", down))}
+            return jax.lax.dynamic_update_slice(out, swiglu(xt, p),
+                                                (j * tile, 0))
 
-    out = jax.lax.fori_loop(0, n_tiles, body,
-                            jnp.zeros((rows + 1, d), x.dtype))
+        out = jax.lax.fori_loop(0, n_tiles, body,
+                                jnp.zeros((rows + 1, d), x.dtype))
     y = out[jnp.where(held, row, rows)].reshape(t, k, d)
     y = (y.astype(F32) * w[..., None]).sum(axis=1)
     return y.astype(x.dtype), held.sum(dtype=i32)

@@ -81,6 +81,20 @@ class SharePipeline(TextGenPipeline):
 
         return over_bytes
 
+    def expert_paths(self, batch: int, prompt_bucket: int,
+                     decode_bucket: int) -> dict:
+        """The `routed_experts` calls a bucket makes on each walk
+        (`expert_calls_grouped`, `expert_calls_loop` on `text.bucket`):
+        static, from the family's `expert_calls` and the rule that
+        function reads off the same shapes (`trinity.grouped_walk`)."""
+        paths = {"expert_calls_grouped": 0, "expert_calls_loop": 0}
+        for rows, calls in self.expert_calls(batch, prompt_bucket,
+                                             decode_bucket):
+            paths["expert_calls_grouped"
+                  if trinity.grouped_walk(rows, self.config)
+                  else "expert_calls_loop"] += calls
+        return paths
+
     def _outputs(self, tokens, carry):
         """(tokens[B, T], routed int32 [assignments made, on held
         experts]) — the counts are part of the goldened program."""
@@ -124,6 +138,23 @@ class TrinityPipeline(SharePipeline):
             walked, dense = walked + w, dense + n
         heads = batch * cfg.kv_heads
         return batch * len(cfg.layers), heads * walked, heads * dense
+
+    def expert_calls(self, batch: int, prompt_bucket: int,
+                     decode_bucket: int) -> tuple:
+        """((rows routed at once, calls a bucket makes), ...): an expert
+        layer's call a sequence over the whole prompt, and one a decode
+        step over the batch."""
+        n = trinity.n_moe(self.config)
+        return ((prompt_bucket, batch * n),
+                (batch, (decode_bucket - 1) * n))
+
+    def bucket_attrs(self, batch: int, prompt_bucket: int,
+                     decode_bucket: int) -> dict:
+        """TextGenPipeline's cache rows and prefill kernel's counts, and
+        the routed experts' calls by walk (`expert_paths`)."""
+        return {**super().bucket_attrs(batch, prompt_bucket,
+                                       decode_bucket),
+                **self.expert_paths(batch, prompt_bucket, decode_bucket)}
 
     def _init_fn(self):
         return lambda key: trinity.init_params(self.config, key)

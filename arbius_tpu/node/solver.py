@@ -606,6 +606,19 @@ def count_attn_pairs(kept: int, causal: int) -> None:
         c.inc(causal, mask="causal")
 
 
+def count_expert_calls(grouped: int, loop: int) -> None:
+    """Bump `arbius_text_expert_calls_total{path}`: the `routed_experts`
+    calls an expert family's bucket makes on the grouped product
+    (`path="grouped"`) and on the tile loop (`path="loop"`) — counted at
+    dispatch from the bucket's shape (docs/text-serving.md)."""
+    c = _counter("arbius_text_expert_calls_total",
+                 "routed-expert calls of dispatched text buckets, by the "
+                 "walk over their tiles", labelnames=("path",))
+    if c is not None:
+        c.inc(grouped, path="grouped")
+        c.inc(loop, path="loop")
+
+
 def count_speculation(steps: int, drafts: int, accepted: int) -> None:
     """Bump `arbius_text_decode_steps_total` by the steps a speculative
     bucket program's loop ran, and `arbius_text_spec_drafts_total
@@ -688,6 +701,9 @@ class TextGenRunner:
         if "cache_bytes_window" in attrs:   # rings beside full caches
             count_window_cache(batch * attrs["cache_bytes_window"],
                                batch * attrs["cache_bytes_full"])
+        if "expert_calls_grouped" in attrs:     # expert layers
+            count_expert_calls(attrs["expert_calls_grouped"],
+                               attrs["expert_calls_loop"])
         with span("text.bucket", model=self.pipeline.FAMILY,
                   prompt_bucket=pb, decode_bucket=db, batch=batch,
                   **attrs):

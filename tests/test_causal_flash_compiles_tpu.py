@@ -1,8 +1,10 @@
 """The causal / sliding-window flash kernel at the text cell's two shapes,
-compiled for a described v5e chip (no chip attached): what Mosaic refuses
-here would cost chip time there. Topology inside a fixture
-(`on-chip-measurement` section 2), as tests/perfbench/
-test_flash_compiles_tpu.py does for the unmasked kernel."""
+and the routed experts' grouped product (`ops/grouped.py`, megablox's
+gmm at the tiling `ops.grouped.tiling` picks) at the text cells' decode
+and prefill tiles, compiled for a described v5e chip (no chip attached):
+what Mosaic refuses here would cost chip time there. The topology is
+built inside a fixture, as tests/perfbench/test_flash_compiles_tpu.py
+does for the unmasked kernel."""
 from __future__ import annotations
 
 import os
@@ -62,3 +64,38 @@ def test_causal_flash_kernel_compiles_at_the_cells_shapes(
     compiled = lowered.compile()
     assert compiled.memory_analysis().output_size_in_bytes \
         == s * kv * g * d * 2
+
+
+# (d, f, held experts, tokens routed at once; 8 of 256 experts a token in
+# each): joyai_llm_flash's verify step, dots3_note's decode step (the
+# widest contraction), a deepseek_v32 prefill chunk (256-row tiles)
+@pytest.mark.parametrize("d,f,held,t", [(2048, 768, 256, 64),
+                                        (5120, 1536, 32, 16),
+                                        (7168, 2048, 16, 4096)])
+def test_grouped_product_compiles_at_the_cells_shapes(
+        one_chip, no_persistent_cache, d, f, held, t):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu import megablox
+
+    from arbius_tpu.models.deepseek_v32 import DeepSeekV32Config
+    from arbius_tpu.models.trinity.model import expert_tile
+    from arbius_tpu.ops import grouped
+
+    cfg = DeepSeekV32Config.published()
+    tile = expert_tile(t, cfg)
+    rows = (-(-t * cfg.experts_per_token // tile) + held) * tile
+    for kk, nn in ((d, f), (f, d)):
+        tiling = grouped.tiling(tile, kk, nn, 2)
+        lowered = jax.jit(lambda x, w, g: megablox.gmm(
+            x, w, g, preferred_element_type=jnp.bfloat16, tiling=tiling)
+        ).lower(jax.ShapeDtypeStruct((rows, kk), jnp.bfloat16,
+                                     sharding=one_chip),
+                jax.ShapeDtypeStruct((held, kk, nn), jnp.bfloat16,
+                                     sharding=one_chip),
+                jax.ShapeDtypeStruct((held,), jnp.int32,
+                                     sharding=one_chip))
+        assert "tpu_custom_call" in lowered.as_text()
+        compiled = lowered.compile()
+        assert compiled.memory_analysis().output_size_in_bytes \
+            == rows * nn * 2

@@ -496,7 +496,12 @@ def test_bucket_program_is_deterministic_and_prefix_stable():
     assert set(attrs) == {"cache_bytes", "cache_bytes_per_head",
                           "attn_pairs", "attn_pairs_causal",
                           "attn_kernel_calls", "attn_blocks",
-                          "attn_blocks_dense", "ffn_rows", "ffn_calls"}
+                          "attn_blocks_dense", "ffn_rows", "ffn_calls",
+                          "expert_calls_grouped", "expert_calls_loop"}
+    # off the TPU the tile loop walks every routed call: a chunk an
+    # expert layer a sequence, then one a layer a step
+    assert (attrs["expert_calls_grouped"], attrs["expert_calls_loop"]) \
+        == (0, 2 * 4 + (T - 1) * 4)
     # the prefill kernel's counts: the walk serves every call off the TPU
     assert (attrs["attn_kernel_calls"], attrs["attn_blocks"],
             attrs["attn_blocks_dense"]) == (0, 0, 0)

@@ -415,9 +415,13 @@ def test_the_family_serves_one_program_and_says_so():
     with pytest.raises(NotImplementedError, match="two-position"):
         pipe._decode(None, None, None, 0)
     attrs = pipe.bucket_attrs(2, P, T)
+    # off the TPU the tile loop walks every routed call: prefill's one
+    # block a layer a sequence, the first draft, then at most T - 1
+    # steps of four expert layers and the module
     assert attrs == {"latent_bytes": cfg.cache_bytes(P + T),
                      "attn_kernel_calls": 0, "attn_blocks": 0,
-                     "attn_blocks_dense": 0}
+                     "attn_blocks_dense": 0, "expert_calls_grouped": 0,
+                     "expert_calls_loop": 2 * 4 + 1 + (T - 1) * 5}
     with pytest.raises(ValueError, match="bf16 only"):
         JoyAIFlashPipeline(cfg, precision="int8")
     with pytest.raises(ValueError, match="joyai_llm_flash ships no mesh"):

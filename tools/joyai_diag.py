@@ -20,8 +20,19 @@ standard output and to `chiprun_out/<--out>`.
                           module logits by the family's `gaps`; the main
                           model's ids beside them
 
+  experts --cells a,b,..  one `route` + `routed_experts` call a walk —
+                          the tile loop, the grouped product
+                          (`ops.grouped`), and at this family's decode
+                          shapes `lax.ragged_dot` in gmm's place — at
+                          each `expert_calls` shape of each text cell
+                          (decode steps, drafts, prefill blocks or
+                          chunks), one expert layer of random weights:
+                          microseconds a call, the touched experts'
+                          kernel bytes, GB/s against 819
+
 `--tiny` runs the same code on the CPU rehearsal's configuration
-(tests/perfbench/tiny-joyai).
+(tests/perfbench/tiny-joyai); `experts --tiny` runs the four tiny
+rehearsal cells, the grouped walks in Pallas's interpreter.
 """
 from __future__ import annotations
 
@@ -37,6 +48,16 @@ sys.path.insert(0, ROOT)
 CELL = "joyai-ep1-2k-512-backlog"
 TINY = (os.path.join(ROOT, "tests", "perfbench", "tiny-joyai",
                      "manifest.json"), "tiny-joyai-backlog")
+# the text cells `experts` reads by default, and their CPU rehearsals
+TEXT_CELLS = ("joyai-ep1-2k-512-backlog", "trinity-ep8-8k-backlog",
+              "dsv32-ep16-16k-backlog", "dots3-ep8-8k-1k-backlog")
+TINY_CELLS = {name: (os.path.join(ROOT, "tests", "perfbench", tiny,
+                                  "manifest.json"), f"{tiny}-backlog")
+              for name, tiny in zip(TEXT_CELLS, ("tiny-joyai",
+                                                 "tiny-trinity",
+                                                 "tiny-dsv32",
+                                                 "tiny-dots3"))}
+HBM_GBPS = 819.0          # v5e (perfbench/peaks.py)
 
 
 class Bench:
@@ -232,12 +253,125 @@ def module(b: Bench, n_tasks: int, emit) -> None:
               "loop_counts": [int(x) for x in np.asarray(counts)]})
 
 
+def _walk(path: str):
+    """A context in which `routed_experts` traces the walk `path`:
+    "loop", "grouped" (gmm), or "ragged" (`lax.ragged_dot` in gmm's
+    place, its products rounded as `_dot` rounds them)."""
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from arbius_tpu.ops import grouped
+
+    def ragged(x, w, sizes, tile):
+        return jax.lax.ragged_dot(
+            x, w, sizes, preferred_element_type=jnp.float32).astype(x.dtype)
+
+    @contextlib.contextmanager
+    def walk():
+        serves, dot = grouped.kernel_serves, grouped.grouped_dot
+        grouped.kernel_serves = lambda *_: path != "loop"
+        if path == "ragged":
+            grouped.grouped_dot = ragged
+        try:
+            yield
+        finally:
+            grouped.kernel_serves, grouped.grouped_dot = serves, dot
+
+    return walk()
+
+
+def experts(cells: list[str], tiny: bool, seed: int, emit) -> None:
+    """One expert layer of each cell's configuration, random weights
+    (kernels N(0, 1/fan_in), the router's too, so the load is near
+    uniform), `reps` inputs of a shape in one jitted `fori_loop` (the
+    dispatch paid once): a call's seconds = the loop's ÷ reps, the best
+    of five runs after the compile."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from arbius_tpu.models.deepseek_v32 import model as dsv32
+    from arbius_tpu.models.trinity import model as trinity
+    from perfbench import manifest
+
+    reps = 8
+    for name in cells:
+        path, cell_name = TINY_CELLS[name] if tiny \
+            else (manifest.DEFAULT_MANIFEST, name)
+        cell = manifest.Cell(path, cell_name)
+        entry = cell.config["models"][0]
+        pipe, _ = cell.family(entry["family"]).build(entry["arch"], "bf16")
+        cfg = pipe.config
+        route = trinity.route if isinstance(cfg, trinity.TrinityConfig) \
+            else dsv32.route
+        batch = cell.config["node"]["canonical_batch"]
+        pb, db = pipe.prompt_buckets[-1], pipe.decode_buckets[-1]
+        d, f, h = cfg.hidden, cfg.expert_ff, cfg.n_held
+        key = jax.random.PRNGKey(seed)
+        ks = jax.random.split(key, 5)
+        bf = jnp.bfloat16
+        layer = {
+            "router": {"kernel": (jax.random.normal(
+                ks[0], (d, cfg.num_experts)) / d ** 0.5).astype(bf)},
+            "expert_bias": jnp.zeros((cfg.num_experts,), jnp.float32),
+            "experts": {
+                n: {"kernel": (jax.random.normal(k, shape, bf)
+                               / shape[1] ** 0.5).astype(bf)}
+                for n, k, shape in (("gate", ks[1], (h, d, f)),
+                                    ("up", ks[2], (h, d, f)),
+                                    ("down", ks[3], (h, f, d)))}}
+        kernel_bytes = 3 * d * f * 2
+        for rows, calls in pipe.expert_calls(batch, pb, db):
+            tile = trinity.expert_tile(rows, cfg)
+            xs = jax.random.normal(jax.random.fold_in(ks[4], rows),
+                                   (reps, rows, d), bf)
+            chosen = np.asarray(jax.jit(jax.vmap(
+                lambda x: route(x, layer, cfg)[0]))(xs))
+            lo, hi = cfg.experts_held
+            touched = [len({int(e) for e in c.ravel() if lo <= e < hi})
+                       for c in chosen]
+            walks = ["loop", "grouped"] + (
+                ["ragged"] if tile == 8 and entry["family"]
+                == "joyai_llm_flash" else [])
+            for walk in walks:
+                def call_all(xs, layer):
+                    def body(i, acc):
+                        x = jax.lax.dynamic_index_in_dim(xs, i, 0, False)
+                        c, w = route(x, layer, cfg)
+                        y, n = trinity.routed_experts(
+                            x, c, w, layer["experts"], cfg)
+                        return acc[0] + y.astype(jnp.float32), acc[1] + n
+                    return jax.lax.fori_loop(
+                        0, reps, body, (jnp.zeros((rows, d), jnp.float32),
+                                        jnp.zeros((), jnp.int32)))
+
+                with _walk(walk):
+                    fn = jax.jit(call_all)
+                    out, secs = _timed(fn, xs, layer, runs=5)
+                s = min(secs) / reps
+                gb = kernel_bytes * float(np.mean(touched)) / 1e9
+                emit({"what": "experts", "cell": name, "rows": rows,
+                      "tile": tile, "calls_a_bucket": calls, "walk": walk,
+                      "us_a_call": 1e6 * s, "runs_us": [
+                          1e6 * x / reps for x in secs],
+                      "touched_experts": float(np.mean(touched)),
+                      "held": h, "touched_gb": gb,
+                      "gb_per_s": gb / s, "of_hbm_pct":
+                      100 * gb / s / HBM_GBPS,
+                      "held_assignments": int(out[1]) / reps,
+                      "checksum": float(jnp.abs(out[0]).sum())})
+        del layer
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("mode", choices=("scan", "steps", "module"))
+    ap.add_argument("mode", choices=("scan", "steps", "module", "experts"))
     ap.add_argument("--seed", type=int, default=2147536001)
     ap.add_argument("--draws", default="")
     ap.add_argument("--tasks", type=int, default=4)
+    ap.add_argument("--cells", default=",".join(TEXT_CELLS))
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--out", default="pr36_joyai_diag.jsonl")
     args = ap.parse_args(argv)
@@ -255,7 +389,10 @@ def main(argv=None) -> int:
         with open(out_path, "a") as f:
             f.write(line + "\n")
 
-    if args.mode == "scan":
+    if args.mode == "experts":
+        experts([c for c in args.cells.split(",") if c], args.tiny,
+                args.seed, emit)
+    elif args.mode == "scan":
         scan(b, [int(x) for x in args.draws.split(",") if x], emit)
     elif args.mode == "steps":
         steps(b, emit)
