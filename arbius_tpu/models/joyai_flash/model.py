@@ -294,11 +294,11 @@ def latent_attention(q_nope, q_pe, lat, keep, ap, cfg):
 
 
 def _write_rows(cache, rows, q):
-    """cache[B, T, C] with rows[B, S, C] written at each row's own
+    """cache[B, T, ...] with rows[B, S, ...] written at each row's own
     offset q[B] .. q[B] + S - 1."""
-    return jax.vmap(
-        lambda c, r, at: jax.lax.dynamic_update_slice(c, r, (at, 0)))(
-        cache, rows.astype(cache.dtype), q)
+    return jax.vmap(lambda c, r, at: jax.lax.dynamic_update_slice(
+        c, r, (at,) + (0,) * (c.ndim - 1)))(cache, rows.astype(cache.dtype),
+                                            q)
 
 
 def _layer_step(x, lp, kind, lat, q, cfg):

@@ -4,9 +4,11 @@ and seed chain over JoyAI-LLM-Flash, behind a SPECULATIVE decode loop.
 A bucket is still (batch, prompt edge, decode edge, sampler) and ONE
 jitted program of prefill then a decode loop, prompts padded to the edge
 with eos and no padding mask, samplers over the byte ids alone. The loop
-is the family's own, the second beside TextGenPipeline's `lax.scan`: the
-model's multi-token prediction module drafts one token a step and the
-model verifies it, so a step yields one or two tokens a row.
+is the second beside TextGenPipeline's `lax.scan`, shared by the
+families whose model drafts (`SpeculativePipeline`: this one and
+models/nemotron_h): the model's multi-token prediction module drafts one
+token a step and the model verifies it, so a step yields one or two
+tokens a row.
 
 A row holds n emitted tokens, the last t_{n-1} at position q = P + n - 1,
 and a draft d_n. A step runs the main model on positions q, q + 1 over
@@ -14,7 +16,11 @@ and a draft d_n. A step runs the main model on positions q, q + 1 over
 t_n = sample(L0, key, n) — the shared samplers, the key folded by the
 TOKEN'S index, as the scan folds it. If t_n = d_n the row also takes
 t_{n+1} = sample(L1, key, n + 1); else cache row q + 1 is overwritten by
-the next step. The module then runs the same two positions on
+the next step. A carry that holds recurrent state (models/nemotron_h's
+Mamba-2 layers) cannot be rewound so: its step returns the state after
+each position and the family's `_commit` keeps, per row, the one after
+q + 1 where the draft was taken and after q elsewhere (the identity
+here). The module then runs the same two positions on
 (h_q, t_n), (h_{q+1}, t_{n+1}) and the next draft is read at q +
 accepted. A token is taken only when it IS the sampler's choice at its
 index, so greedy and seeded top-k are both exact: in exact arithmetic
@@ -54,25 +60,41 @@ def accept(t_n, drafted, room):
     return (t_n == drafted) & room
 
 
-class JoyAIFlashPipeline(SharePipeline):
-    FAMILY = "joyai_llm_flash"
-
-    def __init__(self, config: JoyAIFlashConfig | None = None, mesh=None,
-                 precision: str = "bf16",
-                 prompt_buckets: tuple = (2048,),
-                 decode_buckets: tuple = (512,), top_k: int = 8):
-        super().__init__(config or JoyAIFlashConfig.published(),
-                         mesh=mesh, precision=precision,
-                         prompt_buckets=prompt_buckets,
-                         decode_buckets=decode_buckets, top_k=top_k)
-
-    def _prefill(self, params, ids, total: int):
-        return joyai.prefill(params, ids, total, self.config)
+class SpeculativePipeline(SharePipeline):
+    """The speculative loop of the module docstring, shared by the
+    families whose model drafts with a multi-token prediction module
+    (joyai_llm_flash here, models/nemotron_h). A family names `_step`
+    (the main model on S positions a row), `_draft` (the module on the
+    same positions), `_n_moe` (its main model's expert layers) and, where
+    its carry holds state that a next step cannot overwrite, `_commit`.
+    Prefill's carry is (caches, the module's cache, the last prompt
+    position's hidden state, int32 [assignments, held])."""
 
     def _decode(self, params, tok, carry, pos):
         raise NotImplementedError(
-            "joyai_llm_flash has no one-token decode program: its bytes "
+            f"{self.FAMILY} has no one-token decode program: its bytes "
             "are defined by the two-position speculative step")
+
+    def _step(self, params, toks, caches, q):
+        """(logits [B, S, V'], hidden [B, S, d], caches, held) for toks
+        [B, S] at a row's positions q[B] .. q[B] + S - 1."""
+        raise NotImplementedError
+
+    def _draft(self, params, toks, h, cache, q):
+        """(the module's logits [B, S, V'], its cache, held)."""
+        raise NotImplementedError
+
+    def _n_moe(self) -> int:
+        raise NotImplementedError
+
+    def _commit(self, caches, took):
+        """The main model's caches once a step's draft is decided: `took`
+        [B] bool, the rows that took both positions. The identity for a
+        carry of cache rows, which the next step overwrites from the
+        first position it runs (row q + 1 of a rejected draft); a family
+        whose step leaves recurrent state after each position keeps the
+        one after q + 1 where `took`, after q elsewhere."""
+        return caches
 
     # -- the speculative loop ----------------------------------------------
     def _decode_loop(self, prompt_bucket: int, decode_bucket: int,
@@ -98,13 +120,13 @@ class JoyAIFlashPipeline(SharePipeline):
             at = jnp.arange(t, dtype=i32)[None]
             # the module's position P-1 had to wait for t0: its row, and
             # the draft of token 1
-            guess, mtp_cache, held = joyai.draft(
+            guess, mtp_cache, held = self._draft(
                 params, t0[:, None], h_last[:, None], mtp_cache,
-                jnp.full((b,), p - 1, i32), cfg)
+                jnp.full((b,), p - 1, i32))
             routed = routed + jnp.stack(
                 [i32(b * cfg.experts_per_token), held])
             made = i32(2 * b * cfg.experts_per_token
-                       * (joyai.n_moe(cfg) + 1))
+                       * (self._n_moe() + 1))
             state = (caches, mtp_cache,
                      jnp.where(at == 0, t0[:, None], 0).astype(i32),
                      jnp.ones((b,), i32), t0, greedy(guess[:, 0], keys, 0),
@@ -120,19 +142,19 @@ class JoyAIFlashPipeline(SharePipeline):
                 room = live & (n + 1 < t)
                 # a finished row keeps running its last step's positions
                 q = p - 1 + jnp.minimum(n, t - 1)
-                logits, h, caches, held = joyai.step(
-                    params, jnp.stack([last, drafted], axis=1), caches, q,
-                    cfg)
+                logits, h, caches, held = self._step(
+                    params, jnp.stack([last, drafted], axis=1), caches, q)
                 t_n = sample_rows(logits[:, 0], keys, n)
                 t_n1 = sample_rows(logits[:, 1], keys, n + 1)
                 took = accept(t_n, drafted, room)
+                caches = self._commit(caches, took)
                 tokens = jnp.where((at == n[:, None]) & live[:, None],
                                    t_n[:, None], tokens)
                 tokens = jnp.where((at == n[:, None] + 1) & took[:, None],
                                    t_n1[:, None], tokens)
-                guess, mtp_cache, held_m = joyai.draft(
+                guess, mtp_cache, held_m = self._draft(
                     params, jnp.stack([t_n, t_n1], axis=1), h, mtp_cache,
-                    q, cfg)
+                    q)
                 guess = greedy(guess, keys, 0)               # [B, 2]
                 drafted = jnp.where(took, guess[:, 1], guess[:, 0])
                 last = jnp.where(took, t_n1, t_n)
@@ -148,6 +170,31 @@ class JoyAIFlashPipeline(SharePipeline):
             return state[2], state[6], state[7]
 
         return loop
+
+
+class JoyAIFlashPipeline(SpeculativePipeline):
+    FAMILY = "joyai_llm_flash"
+
+    def __init__(self, config: JoyAIFlashConfig | None = None, mesh=None,
+                 precision: str = "bf16",
+                 prompt_buckets: tuple = (2048,),
+                 decode_buckets: tuple = (512,), top_k: int = 8):
+        super().__init__(config or JoyAIFlashConfig.published(),
+                         mesh=mesh, precision=precision,
+                         prompt_buckets=prompt_buckets,
+                         decode_buckets=decode_buckets, top_k=top_k)
+
+    def _prefill(self, params, ids, total: int):
+        return joyai.prefill(params, ids, total, self.config)
+
+    def _step(self, params, toks, caches, q):
+        return joyai.step(params, toks, caches, q, self.config)
+
+    def _draft(self, params, toks, h, cache, q):
+        return joyai.draft(params, toks, h, cache, q, self.config)
+
+    def _n_moe(self) -> int:
+        return joyai.n_moe(self.config)
 
     def attn_kernel(self, batch: int, prompt_bucket: int) -> tuple:
         """deepseek_v32's rule and counts over the main layers (the

@@ -99,6 +99,7 @@ def all_trace_specs() -> list[TraceSpec]:
     from arbius_tpu.models.dots3 import pipeline as dots3_pipeline
     from arbius_tpu.models.joyai_flash import pipeline as joyai_pipeline
     from arbius_tpu.models.kandinsky2 import pipeline as kandinsky2_pipeline
+    from arbius_tpu.models.nemotron_h import pipeline as nemotron_pipeline
     from arbius_tpu.models.rvm import pipeline as rvm_pipeline
     from arbius_tpu.models.sd15 import pipeline as sd15_pipeline
     from arbius_tpu.models.textgen import pipeline as textgen_pipeline
@@ -109,6 +110,7 @@ def all_trace_specs() -> list[TraceSpec]:
     specs: list[TraceSpec] = []
     for mod in (sd15_pipeline, kandinsky2_pipeline, rvm_pipeline,
                 video_pipeline, textgen_pipeline, trinity_pipeline,
-                dsv32_pipeline, joyai_pipeline, dots3_pipeline, meshsolve):
+                dsv32_pipeline, joyai_pipeline, dots3_pipeline,
+                nemotron_pipeline, meshsolve):
         specs.extend(mod.trace_specs())
     return validate_specs(specs)

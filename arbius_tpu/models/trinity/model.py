@@ -250,6 +250,21 @@ def swiglu(x, p):
     return _dot(h, p["down"]["kernel"])
 
 
+def relu2(x, p):
+    """The non-gated expert of models/nemotron_h: (relu(x·W_up)²)·W_down,
+    the square taken in float32 and rounded to the operands' type."""
+    u = _dot(x, p["up"]["kernel"])
+    h = jnp.square(jax.nn.relu(u.astype(F32))).astype(x.dtype)
+    return _dot(h, p["down"]["kernel"])
+
+
+# an expert's form: its kernels, in the order the products read them,
+# and the function of one expert (`ops.grouped.grouped_mlp` is the same
+# form over a call's used tiles)
+EXPERT_FORMS = {"swiglu": (("gate", "up", "down"), swiglu),
+                "relu2": (("up", "down"), relu2)}
+
+
 def route(x, p, cfg: TrinityConfig):
     """x[T, d] → (chosen[T, k] expert ids over ALL experts, w[T, k] f32).
 
@@ -287,9 +302,12 @@ def grouped_walk(tokens: int, cfg: TrinityConfig) -> bool:
     return grouped.kernel_serves(expert_tile(tokens, cfg), reach)
 
 
-def routed_experts(x, chosen, w, experts, cfg: TrinityConfig):
+def routed_experts(x, chosen, w, experts, cfg: TrinityConfig,
+                   form: str = "swiglu"):
     """The held experts' part of Σ_i w_i · expert_i(x), and how many of
-    the (token, choice) assignments fell on held experts.
+    the (token, choice) assignments fell on held experts. `form` is the
+    experts' (`EXPERT_FORMS`): SwiGLU's gate, up and down kernels, or
+    squared ReLU's up and down (models/nemotron_h).
 
     Grouped products over the stacked [held, d, f] kernels with work
     proportional to the load, in prefill and in a decode step alike:
@@ -342,27 +360,25 @@ def routed_experts(x, chosen, w, experts, cfg: TrinityConfig):
     tok = jnp.where(valid, src // k, t)               # t: the zero row
     xs = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[tok]
 
-    gate, up, down = (experts[n]["kernel"] for n in ("gate", "up", "down"))
+    names, mlp = EXPERT_FORMS[form]
+    kernels = {n: experts[n]["kernel"] for n in names}
 
     if grouped_walk(t, cfg):
         # every group whole tiles: each grid step is one tile of one
-        # expert. `swiglu`'s roundings; rows past the used tiles are
-        # never written, so the zero row is appended after the products
-        sizes = tiles_e * tile
-        g, u = (grouped.grouped_dot(xs, kern, sizes, tile)
-                for kern in (gate, up))
-        hs = (jax.nn.silu(g.astype(F32)) * u.astype(F32)).astype(x.dtype)
-        out = jnp.concatenate([grouped.grouped_dot(hs, down, sizes, tile),
-                               jnp.zeros((1, d), x.dtype)])
+        # expert. The expert function's roundings; rows past the used
+        # tiles are never written, so the zero row is appended after the
+        # products
+        out = jnp.concatenate([
+            grouped.grouped_mlp(xs, kernels, tiles_e * tile, tile, form),
+            jnp.zeros((1, d), x.dtype)])
     else:
         def body(j, out):
             ex = tile_expert[j]
             xt = jax.lax.dynamic_slice(xs, (j * tile, 0), (tile, d))
             p = {n: {"kernel": jax.lax.dynamic_index_in_dim(
                      kern, ex, 0, keepdims=False)}
-                 for n, kern in (("gate", gate), ("up", up),
-                                 ("down", down))}
-            return jax.lax.dynamic_update_slice(out, swiglu(xt, p),
+                 for n, kern in kernels.items()}
+            return jax.lax.dynamic_update_slice(out, mlp(xt, p),
                                                 (j * tile, 0))
 
         out = jax.lax.fori_loop(0, n_tiles, body,

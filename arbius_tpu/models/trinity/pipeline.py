@@ -23,14 +23,16 @@ from arbius_tpu.ops import causal_flash
 
 class SharePipeline(TextGenPipeline):
     """What the families that serve one chip's share of a model have in
-    common (trinity here, models/deepseek_v32, models/joyai_flash): no
+    common (trinity here, models/deepseek_v32, models/joyai_flash,
+    models/dots3, models/nemotron_h): no
     mesh layout, bf16 only, the byte tokenizer's ids inside the
     vocabulary rows held, the samplers over those ids alone, the model
     a set of pure functions of the param tree, and the routers' counts
     beside the tokens. A family names itself, its default config and
-    edges, `_prefill`, `_decode` and `_init_fn`; one whose step yields
-    more than one token (joyai_llm_flash) brings its own `_decode_loop`
-    in the scan's place."""
+    edges, `_prefill`, `_decode` and `_init_fn`; those whose step yields
+    more than one token (joyai_llm_flash, nemotron_h) share
+    `models.joyai_flash.pipeline.SpeculativePipeline`'s loop in the
+    scan's place."""
 
     # ids the byte tokenizer turns into text, one byte each. No
     # vocabulary file of the model's is in the tree, so an id past them

@@ -341,9 +341,9 @@ class TextgenConfig:
     # "max_new_tokens": 256}}. Fleet-wide like everything here.
     templates: dict = field(default_factory=dict)
     # the chip's share of a model divided over chips (trinity,
-    # deepseek_v32, joyai_llm_flash and dots3_note: `experts_held`,
-    # `vocab_rows`, `layers` — the families' config dataclasses); empty =
-    # the whole published model
+    # deepseek_v32, joyai_llm_flash, dots3_note and nemotron_h:
+    # `experts_held`, `vocab_rows`, `layers` — the families' config
+    # dataclasses); empty = the whole published model
     share: dict = field(default_factory=dict)
 
     def for_template(self, template: str) -> "TextgenConfig":

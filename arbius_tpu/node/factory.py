@@ -309,12 +309,24 @@ def _dots3_note(m: ModelConfig, mesh, mode: str, tg):
                          Dots3NotePipeline)
 
 
+def _nemotron_h(m: ModelConfig, mesh, mode: str, tg):
+    """Served, as joyai_llm_flash is, by the speculative program alone
+    (models/nemotron_h/pipeline.py)."""
+    from arbius_tpu.models.nemotron_h import (
+        NemotronHConfig,
+        NemotronHPipeline,
+    )
+
+    return _share_family(m, mesh, mode, tg, NemotronHConfig,
+                         NemotronHPipeline)
+
+
 # text templates: builders that take the template's sequence-bucket
 # policy (cfg.textgen.for_template) on top of the common triple
 _TEXT_BUILDERS = {"textgen": _textgen, "trinity": _trinity,
                   "deepseek_v32": _deepseek_v32,
                   "joyai_llm_flash": _joyai_llm_flash,
-                  "dots3_note": _dots3_note}
+                  "dots3_note": _dots3_note, "nemotron_h": _nemotron_h}
 
 
 def _rvm(m: ModelConfig, mesh, resolve_file):

@@ -131,7 +131,7 @@ class CostSched(FifoSched):
             # packing then prefers short sequences at equal fees
             # instead of pricing a 96-token bucket like a 20-token one.
             # The edges count TOKENS, not steps: a family whose loop
-            # speculates (joyai_llm_flash) takes fewer steps than
+            # speculates (joyai_llm_flash, nemotron_h) takes fewer steps than
             # tokens, by its drafts' acceptance, which only a fitted
             # row knows. Ordering-only: the estimate never touches
             # bytes.

@@ -592,6 +592,18 @@ def count_window_cache(window: int, full: int) -> None:
         c.inc(full, form="latent")
 
 
+def count_ssm_state(state: int) -> None:
+    """Bump `arbius_text_cache_bytes_total{form="ssm_state"}` for a family
+    whose state-space layers carry a recurrent state beside K/V rows: the
+    bytes of those states and conv windows a bucket holds — counted at
+    dispatch from the bucket's shape (docs/text-serving.md)."""
+    c = _counter("arbius_text_cache_bytes_total",
+                 "cache bytes of dispatched text buckets, as held and as "
+                 "per-head K/V rows would be", labelnames=("form",))
+    if c is not None:
+        c.inc(state, form="ssm_state")
+
+
 def count_attn_pairs(kept: int, causal: int) -> None:
     """Bump `arbius_attn_pairs_total{mask}`: the (query, key) pairs a
     sparse-attention family's bucket leaves to attention's softmax
@@ -638,7 +650,7 @@ def count_speculation(steps: int, drafts: int, accepted: int) -> None:
 
 
 class TextGenRunner:
-    """text-template runner (textgen, trinity, deepseek_v32, joyai_llm_flash, dots3_note): decoder-only LM → deterministic UTF-8.
+    """text-template runner (textgen, trinity, deepseek_v32, joyai_llm_flash, dots3_note, nemotron_h): decoder-only LM → deterministic UTF-8.
 
     Template variables (templates/textgen.json): prompt,
     max_new_tokens, sampler (enum); output out-1.txt. The sequence
@@ -701,6 +713,8 @@ class TextGenRunner:
         if "cache_bytes_window" in attrs:   # rings beside full caches
             count_window_cache(batch * attrs["cache_bytes_window"],
                                batch * attrs["cache_bytes_full"])
+        if "ssm_state_bytes" in attrs:      # recurrent state beside K/V
+            count_ssm_state(batch * attrs["ssm_state_bytes"])
         if "expert_calls_grouped" in attrs:     # expert layers
             count_expert_calls(attrs["expert_calls_grouped"],
                                attrs["expert_calls_loop"])
@@ -726,7 +740,7 @@ class TextGenRunner:
         # a family with expert layers returns its routers' counts
         # beside the tokens (models/trinity, models/deepseek_v32), one
         # whose loop speculates its loop's counts after them
-        # (models/joyai_flash)
+        # (models/joyai_flash, models/nemotron_h)
         tokens, routed, spec = (*out, None)[:3] \
             if isinstance(out, tuple) else (out, None, None)
         with span("solve.encode", n=n_real, codec="text"):

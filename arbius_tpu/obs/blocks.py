@@ -35,6 +35,8 @@ VOCABULARY: dict[str, tuple[str, ...]] = {
                         "draft"),
     "dots3_note": ("prefill", "decode", "attention", "window_attention",
                    "routed_experts", "indexer"),
+    "nemotron_h": ("prefill", "decode", "attention", "mamba_mixer",
+                   "latent_moe", "routed_experts", "draft"),
     "kandinsky2": ("text_tower", "prior", "unet", "movq"),
     "sd15": ("text_encoder", "unet", "vae"),
 }

@@ -31,6 +31,7 @@ interpreter (tests/test_grouped_experts.py).
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu import megablox
 
 # Tile rows at or below which a call on the TPU may take the grouped
@@ -99,3 +100,23 @@ def grouped_dot(x: jax.Array, w: jax.Array, sizes: jax.Array,
         x, w, sizes, preferred_element_type=x.dtype,
         tiling=tiling(tile, x.shape[1], w.shape[2], x.dtype.itemsize),
         interpret=jax.default_backend() != "tpu")
+
+
+def grouped_mlp(x: jax.Array, kernels: dict, sizes: jax.Array, tile: int,
+                form: str) -> jax.Array:
+    """One expert function by groups over x[rows, K], `grouped_dot`'s
+    layout: `form` "swiglu" is silu(x·gate) · (x·up), "relu2" is
+    relu(x·up)², each in float32 and rounded to x's type, then · down —
+    `models.trinity.model.swiglu` and `relu2`'s roundings, a product a
+    kernel."""
+    f32 = jnp.float32
+    if form == "swiglu":
+        g, u = (grouped_dot(x, kernels[n], sizes, tile)
+                for n in ("gate", "up"))
+        h = (jax.nn.silu(g.astype(f32)) * u.astype(f32)).astype(x.dtype)
+    elif form == "relu2":
+        u = grouped_dot(x, kernels["up"], sizes, tile)
+        h = jnp.square(jax.nn.relu(u.astype(f32))).astype(x.dtype)
+    else:
+        raise ValueError(f"no expert form {form!r}")
+    return grouped_dot(h, kernels["down"], sizes, tile)

@@ -99,3 +99,45 @@ def test_grouped_product_compiles_at_the_cells_shapes(
         compiled = lowered.compile()
         assert compiled.memory_analysis().output_size_in_bytes \
             == rows * nn * 2
+
+
+# nemotron3-super-ep4: the attention layer's prefill at the 2048 edge, 32
+# query heads over 2 KV heads (16 a group: the widest stacked Q tile of
+# any cell), and a verify step's latent experts (64 rows, 22 of 512 a
+# token, 128 held; squared ReLU's up 1024 -> 2688 and down 2688 -> 1024)
+@pytest.mark.parametrize("part", ["causal", "up", "down"])
+def test_nemotron_kernels_compile_at_the_cells_shapes(
+        one_chip, no_persistent_cache, part):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu import megablox
+
+    from arbius_tpu.models.nemotron_h import NemotronHConfig
+    from arbius_tpu.models.trinity.model import expert_tile
+    from arbius_tpu.ops import grouped
+    from arbius_tpu.ops.causal_flash import causal_flash_attention
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if part == "causal":
+        s, kv, g, d = 2048, 2, 16, 128
+        lowered = jax.jit(causal_flash_attention).lower(
+            sds((1, s, kv, g, d)), sds((1, s, kv, d)), sds((1, s, kv, d)))
+        out = s * kv * g * d * 2
+    else:
+        cfg = NemotronHConfig.published()
+        held, t = 128, 64
+        tile = expert_tile(t, cfg)
+        assert tile == 8
+        rows = (-(-t * cfg.experts_per_token // tile) + held) * tile
+        kk, nn = ((cfg.latent, cfg.expert_ff) if part == "up"
+                  else (cfg.expert_ff, cfg.latent))
+        tiling = grouped.tiling(tile, kk, nn, 2)
+        lowered = jax.jit(lambda x, w, gs: megablox.gmm(
+            x, w, gs, preferred_element_type=jnp.bfloat16, tiling=tiling)
+        ).lower(sds((rows, kk)), sds((held, kk, nn)),
+                sds((held,), jnp.int32))
+        out = rows * nn * 2
+    assert "tpu_custom_call" in lowered.as_text()
+    assert lowered.compile().memory_analysis().output_size_in_bytes == out

@@ -18,7 +18,7 @@ def test_all_reference_templates_parse():
     assert names == sorted(
         ["anythingv3", "kandinsky2", "zeroscopev2xl", "damo",
          "robust_video_matting", "textgen", "trinity", "deepseek_v32",
-         "joyai_llm_flash", "dots3_note"])
+         "joyai_llm_flash", "dots3_note", "nemotron_h"])
     for n in names:
         t = load_template(n)
         assert t.title
