@@ -17,10 +17,10 @@ t_n = sample(L0, key, n) — the shared samplers, the key folded by the
 TOKEN'S index, as the scan folds it. If t_n = d_n the row also takes
 t_{n+1} = sample(L1, key, n + 1); else cache row q + 1 is overwritten by
 the next step. A carry that holds recurrent state (models/nemotron_h's
-Mamba-2 layers) cannot be rewound so: its step returns the state after
-each position and the family's `_commit` keeps, per row, the one after
-q + 1 where the draft was taken and after q elsewhere (the identity
-here). The module then runs the same two positions on
+Mamba-2 layers) cannot be rewound so: its step stores the state after q
+with the increment of q + 1 beside it, the family's `_commit` records
+which rows took the draft, and the next step folds their increment in
+(the identity here). The module then runs the same two positions on
 (h_q, t_n), (h_{q+1}, t_{n+1}) and the next draft is read at q +
 accepted. A token is taken only when it IS the sampler's choice at its
 index, so greedy and seeded top-k are both exact: in exact arithmetic
@@ -92,8 +92,9 @@ class SpeculativePipeline(SharePipeline):
         [B] bool, the rows that took both positions. The identity for a
         carry of cache rows, which the next step overwrites from the
         first position it runs (row q + 1 of a rejected draft); a family
-        whose step leaves recurrent state after each position keeps the
-        one after q + 1 where `took`, after q elsewhere."""
+        whose carry holds recurrent state records `took`, so that its next
+        step starts from the state after q + 1 where `took`, after q
+        elsewhere."""
         return caches
 
     # -- the speculative loop ----------------------------------------------

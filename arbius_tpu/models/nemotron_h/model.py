@@ -34,11 +34,14 @@ live side by side: a Mamba layer keeps, per row, a float32 SSM state
 [128, 64, 128] and the conv's window of its last 3 inputs; an attention
 layer keeps K and V rows. Prefill runs the state-space layers in the
 chunked (SSD) form, `chunk_size` positions a chunk, the state passed from
-chunk to chunk; a decode step runs S positions a row and returns the
-state and the conv buffer after EACH position, so that the speculative
-loop (models/joyai_flash/pipeline.py) can keep, per row, the state after
-q or after q + 1 once it knows whether the draft was taken (`commit`): a
-recurrent state cannot be rewound by overwriting a row.
+chunk to chunk; a decode step runs S positions a row and stores the
+state after the FIRST, with the second position's increment beside it
+(`MambaCache`): a recurrent state cannot be rewound by overwriting a
+row, and whether a row keeps the state after q or after q + 1 is known
+only once the speculative loop (models/joyai_flash/pipeline.py) has
+sampled the logits. `commit` records the choice, and the next step folds
+the increment in where it was taken (`settle`), in the same pass as its
+own first update: one state a layer is written a step.
 
 Pure functions of an explicit param tree, every decode-side function
 general in S:
@@ -48,8 +51,9 @@ general in S:
   step(params, toks[B, S], caches, q[B], cfg)  the main model on S
                                               positions a row from its own
                                               q, the Mamba layers' state
-                                              after each
-  commit(caches, took[B], cfg)                the state after q + took
+                                              after the first
+  commit(caches, took[B], cfg)                the state after q + took,
+                                              folded by the next step
   draft(params, toks[B, S], h[B, S, d], cache, q[B], cfg)
                                               the module on the same
                                               positions
@@ -58,6 +62,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -208,12 +213,15 @@ class NemotronHConfig:
 
     def ssm_state_bytes(self) -> int:
         """Bytes the carry holds for one sequence's state-space layers,
-        whatever its length: a float32 state of every head and the conv's
-        window of `conv_kernel` - 1 inputs, a layer."""
-        state = self.mamba_heads * self.mamba_head_dim * self.ssm_state * 4
-        window = (self.conv_kernel - 1) * self.conv_dim \
-            * self.jdtype.itemsize
-        return self.count("mamba") * (state + window)
+        whatever its length (`MambaCache`), a layer: a float32 state of
+        every head; the pending increment — float32 exp(dt·A) a head,
+        dt·x a head's channel, B a group's state —; the byte of `took`;
+        and `conv_kernel` conv inputs (the window and the next one)."""
+        h, pd, n = self.mamba_heads, self.mamba_head_dim, self.ssm_state
+        state = h * pd * n * 4
+        pending = (h + h * pd + self.n_groups * n) * 4 + 1
+        conv = self.conv_kernel * self.conv_dim * self.jdtype.itemsize
+        return self.count("mamba") * (state + pending + conv)
 
     def kv_bytes(self, total: int) -> int:
         """Bytes of K and V rows for one sequence of `total` positions:
@@ -430,53 +438,107 @@ def _mamba_prefill(x, lp, cfg):
                             cfg.ssm_state), buf[p:]
 
 
-def _mamba_step(x, lp, state, window, cfg):
-    """A Mamba layer on x[B, S, d], S positions a row from the state
-    [B, H, Pd, N] and the conv's window [B, K - 1, C] → (x', the state
-    after EACH position [B, S, H, Pd, N] float32, the conv's buffer
-    [B, K - 1 + S, C]: the window after position s is rows s + 1 ..
-    s + K - 1)."""
+class MambaCache(NamedTuple):
+    """A Mamba layer's entry of the carry, per row: the state after the
+    first position the last step ran, and what the row needs to keep the
+    state after the second instead — that position's increment and
+    whether the row took it. The fold waits for the next step.
+
+    state  [B, H, Pd, N] float32, s_q: the state after position q
+    decay  [B, H] float32, exp(dt·A) at position q + 1
+    dtx    [B, H, Pd] float32, dt·x at q + 1
+    b      [B, G, N] float32, B at q + 1
+    took   [B] bool: the row keeps the state after q + 1, not after q
+    conv   [B, K, C]: the conv's window after q, then its input at q + 1
+
+    A one-position step and prefill leave no increment: decay 1, no
+    input, took False."""
+    state: jax.Array
+    decay: jax.Array
+    dtx: jax.Array
+    b: jax.Array
+    took: jax.Array
+    conv: jax.Array
+
+
+def _mamba_cache(state, window, cfg, nxt=None):
+    """A `MambaCache` from the state after q [..., H, Pd, N], the conv's
+    window after q [..., K - 1, C] and, where the step ran a position
+    after it, nxt = (exp(dt·A) [..., G, R], dt·x [..., G, R, Pd], B
+    [..., G, N], its conv input [..., 1, C]), for any leading shape;
+    `took` False."""
+    lead = state.shape[:-3]
+    h, pd = cfg.mamba_heads, cfg.mamba_head_dim
+    if nxt is None:
+        nxt = (jnp.ones((*lead, h), F32), jnp.zeros((*lead, h, pd), F32),
+               jnp.zeros((*lead, cfg.n_groups, cfg.ssm_state), F32),
+               jnp.zeros((*lead, 1, window.shape[-1]), window.dtype))
+    decay, dtx, b, row = nxt
+    return MambaCache(state, decay.reshape(*lead, h),
+                      dtx.reshape(*lead, h, pd), b,
+                      jnp.zeros(lead, bool),
+                      jnp.concatenate([window, row], axis=-2))
+
+
+def settle(cache: MambaCache, cfg):
+    """The state [B, H, Pd, N] and conv window [B, K - 1, C] a row keeps:
+    after q + took. The fold is the second position's own update, the
+    float32 expression the step runs for it."""
+    g, r = cfg.n_groups, cfg.heads_per_group
+    bsz = cache.state.shape[0]
+    st = cache.state.reshape(bsz, g, r, cfg.mamba_head_dim, cfg.ssm_state)
+    folded = cache.decay.reshape(bsz, g, r)[..., None, None] * st \
+        + cache.dtx.reshape(bsz, g, r, -1)[..., None] \
+        * cache.b[:, :, None, None, :]
+    took = cache.took[:, None, None]
+    st = jnp.where(took[..., None, None], folded, st)
+    window = jnp.where(took, cache.conv[:, 1:], cache.conv[:, :-1])
+    return st.reshape(cache.state.shape), window
+
+
+def _mamba_step(x, lp, cache: MambaCache, cfg):
+    """A Mamba layer on x[B, S, d], S positions a row from the state and
+    conv window the cache settles to → (x', the `MambaCache` after the
+    first position, with the second's increment pending). The state
+    after a later position feeds its y and is never stored: one state a
+    layer is written a step."""
     mp = lp["mixer"]
     bsz, s, _ = x.shape
     with jax.named_scope("mamba_mixer"):
+        state, window = settle(cache, cfg)
         z, xbc, dt_raw = _mamba_in(rms_norm(x, lp["norm"]["scale"], cfg.eps),
                                    mp, cfg)
         buf = jnp.concatenate([window, xbc.astype(window.dtype)], axis=1)
         xs, b, c, dt, a = _ssm_inputs(_conv(buf, mp, s, cfg), dt_raw, mp,
                                       cfg)
         xs, b, c = (v.astype(F32) for v in (xs, b, c))
-        st = state.reshape(bsz, *a.shape, cfg.mamba_head_dim, cfg.ssm_state)
-        states, ys = [], []
+        decay = [jnp.exp(dt[:, i] * a) for i in range(s)]
+        dtx = [dt[:, i, ..., None] * xs[:, i] for i in range(s)]
+        sts = [state.reshape(bsz, *a.shape, cfg.mamba_head_dim,
+                             cfg.ssm_state)]
         for i in range(s):
-            st = jnp.exp(dt[:, i] * a)[..., None, None] * st \
-                + (dt[:, i, ..., None] * xs[:, i])[..., None] \
-                * b[:, i, :, None, None, :]
-            ys.append((st * c[:, i, :, None, None, :]).sum(axis=-1))
-            states.append(st.reshape(state.shape))
+            sts.append(decay[i][..., None, None] * sts[-1] + dtx[i][..., None]
+                       * b[:, i, :, None, None, :])
+        ys = [(st * c[:, i, :, None, None, :]).sum(axis=-1)
+              for i, st in enumerate(sts[1:])]
         y = jnp.stack(ys, axis=1) \
             + mp["D"].astype(F32).reshape(a.shape)[..., None] * xs
         x = x + _mamba_out(y.reshape(bsz, s, cfg.d_inner), z, mp, cfg)
-    return x, jnp.stack(states, axis=1), buf
+        k = cfg.conv_kernel
+        nxt = (decay[1], dtx[1], b[:, 1], buf[:, k:k + 1]) if s > 1 else None
+    return x, _mamba_cache(sts[1].reshape(state.shape), buf[:, 1:k], cfg,
+                           nxt)
 
 
 def commit(caches, took, cfg: NemotronHConfig):
     """The caches of a two-position step once its draft is decided: a
-    Mamba layer keeps, per row, the state and the conv window after
-    position q + took (took [B] bool: after q + 1 where the row took both
-    positions, after q elsewhere); attention's rows stand as written."""
-    keep = took.astype(jnp.int32)
-    k = cfg.conv_kernel
-    out = []
-    for kind, c in zip(cfg.layers, caches):
-        if kind == "mamba":
-            states, buf = c
-            out.append((
-                jax.vmap(lambda st, i: st[i])(states, keep),
-                jax.vmap(lambda bf, i: jax.lax.dynamic_slice_in_dim(
-                    bf, i + 1, k - 1))(buf, keep)))
-        else:
-            out.append(c)
-    return tuple(out)
+    Mamba layer records, per row, that it keeps the state and the conv
+    window after position q + took (took [B] bool: after q + 1 where the
+    row took both positions, after q elsewhere) — the next step folds the
+    second position's increment in (`settle`), so nothing is written
+    here; attention's rows stand as written."""
+    return tuple(c._replace(took=took) if kind == "mamba" else c
+                 for kind, c in zip(cfg.layers, caches))
 
 
 # -- attention ---------------------------------------------------------------
@@ -566,17 +628,18 @@ def step(params, toks, caches, q, cfg: NemotronHConfig):
     """toks[B, S] int32, a row's at its positions q[B] .. q[B] + S - 1 →
     (logits[B, S, V'] f32 for the positions after them, the hidden states
     h[B, S, d] before the final norm, caches, held). A Mamba layer's
-    entry of the caches comes back as the state and the conv buffer after
-    EACH position (`_mamba_step`), which `commit` reduces to one; an
-    attention layer's as its rows written."""
+    entry of the caches comes back as a `MambaCache`: the state after the
+    first position, the second's increment pending and `took` False until
+    `commit` says otherwise (`_mamba_step`); an attention layer's as its
+    rows written."""
     x = _embed(params, toks, cfg)
     held = jnp.zeros((), jnp.int32)
     new = []
     for i, kind in enumerate(cfg.layers):
         lp = params[f"layer_{i}"]
         if kind == "mamba":
-            x, states, buf = _mamba_step(x, lp, *caches[i], cfg)
-            new.append((states, buf))
+            x, cache = _mamba_step(x, lp, caches[i], cfg)
+            new.append(cache)
         elif kind == "attention":
             x, kv = _attn_step(x, lp, caches[i], q, cfg)
             new.append(kv)
@@ -618,7 +681,7 @@ def _prefill_piece(params, ids, total: int, cfg: NemotronHConfig):
         lp = params[f"layer_{i}"]
         if kind == "mamba":
             x, state, window = _mamba_prefill(x, lp, cfg)
-            caches.append((state, window))
+            caches.append(_mamba_cache(state, window, cfg))
         elif kind == "attention":
             x, kv = _attn_prefill(x, lp, total, cfg)
             caches.append(kv)
@@ -640,11 +703,11 @@ def prefill(params, ids, total: int, cfg: NemotronHConfig):
     carry). The batch is walked a sequence at a time (`lax.map`), so
     in_proj's output and the chunked scan's temporaries of one sequence
     — not of the batch — sit beside the weights. carry = (the main
-    layers' caches: a Mamba layer's (state [B, H, Pd, N] float32, conv
-    window [B, K - 1, C]), an attention layer's (k, v) [B, T, KV, D], an
-    expert layer's (); the module's (k, v) with rows 0 .. P-2 filled; the
-    last prompt position's hidden state [B, d]; int32 [assignments,
-    held])."""
+    layers' caches: a Mamba layer's `MambaCache` (the state after the
+    last prompt position, no increment pending), an attention layer's
+    (k, v) [B, T, KV, D], an expert layer's (); the module's (k, v) with
+    rows 0 .. P-2 filled; the last prompt position's hidden state [B, d];
+    int32 [assignments, held])."""
     b, p = ids.shape
     last, caches, mtp_cache, held = jax.lax.map(
         lambda row: _prefill_piece(params, row, total, cfg), ids)

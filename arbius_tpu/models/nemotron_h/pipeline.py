@@ -7,9 +7,11 @@ A bucket is (batch, prompt edge, decode edge, sampler) and ONE jitted
 program of prefill then the loop: two positions a row a step, the
 module's draft verified, one or two tokens out. What this family brings
 to the loop is recurrent state: a Mamba layer's state and conv window
-cannot be rewound by overwriting a row, so a step returns them after
-each of its two positions and `_commit` keeps, per row, the pair after
-q (draft rejected) or after q + 1 (taken) — `model.commit`. As for
+cannot be rewound by overwriting a row, so a step stores them after its
+first position with the second position's increment beside them, and
+`_commit` records, per row, whether the row keeps the state after q
+(draft rejected) or after q + 1 (taken) — `model.commit`; the next step
+folds the taken increment into its own first update. As for
 joyai_llm_flash there is no one-token program: the two-position step
 defines the bytes (docs/determinism.md), and prefill's chunked scan is a
 determinism class of its own.
@@ -101,7 +103,7 @@ MESH_LAYOUTS: tuple[tuple[str, ...], ...] = ()
 def trace_specs():
     """graphlint trace specs at the tiny whole-model config: prefill
     (three whole chunks of the scan, the module's K/V rows), the
-    speculative loop with its per-row commit of state (greedy and seeded
+    speculative loop with its per-row fold of state (greedy and seeded
     top-k) and the composed bucket program."""
     return share_trace_specs(
         "nemotron_h", lambda: NemotronHPipeline(

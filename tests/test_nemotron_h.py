@@ -163,14 +163,14 @@ def test_a_mamba_layers_prefill_is_its_step_one_position_at_a_time():
     lp = _params(cfg)["layer_0"]
     x = jax.random.normal(jax.random.PRNGKey(2), (P, cfg.hidden))
     out, state, window = nemotron._mamba_prefill(x, lp, cfg)
-    st = jnp.zeros((1, cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state))
-    win = jnp.zeros((1, cfg.conv_kernel - 1, cfg.conv_dim))
+    cache = nemotron._mamba_cache(
+        jnp.zeros((1, cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state)),
+        jnp.zeros((1, cfg.conv_kernel - 1, cfg.conv_dim)), cfg)
     outs = []
     for t in range(P):
-        o, states, buf = nemotron._mamba_step(x[None, t:t + 1], lp, st, win,
-                                              cfg)
-        st, win = states[:, 0], buf[:, 1:]
+        o, cache = nemotron._mamba_step(x[None, t:t + 1], lp, cache, cfg)
         outs.append(o[0, 0])
+    st, win = nemotron.settle(cache, cfg)
     assert np.allclose(out, jnp.stack(outs), atol=1e-5)
     assert np.allclose(state, st[0], atol=1e-5)
     assert np.array_equal(window, win[0])
@@ -206,10 +206,12 @@ def test_prefill_logits_match_the_reference(p):
     assert got.shape == want.shape == (2, cfg.n_vocab)
     assert float(jnp.abs(got - want).max()) < 1e-4
     caches, mtp_cache, _, stats = carry
-    state, window = caches[0]
-    assert state.dtype == jnp.float32 and state.shape == (
+    mc = caches[0]
+    assert mc.state.dtype == jnp.float32 and mc.state.shape == (
         2, cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state)
-    assert window.shape == (2, cfg.conv_kernel - 1, cfg.conv_dim)
+    # the conv's window, then a row for the input no step has run yet
+    assert mc.conv.shape == (2, cfg.conv_kernel, cfg.conv_dim)
+    assert not mc.took.any()
     assert caches[1] == () and len(caches[3]) == 2
     assert mtp_cache[0].shape == (2, p + 4, cfg.kv_heads, cfg.head_dim)
 
@@ -261,13 +263,134 @@ def test_a_forced_reject_or_accept_leaves_the_state_of_one_position_stepping(
         want = after[int(t)]
         for kind, got, ref in zip(cfg.layers, two, want):
             if kind == "mamba":
-                assert np.allclose(got[0][row], ref[0][row], atol=1e-6)
-                assert np.allclose(got[1][row], ref[1][row], atol=1e-6)
+                for g, r in zip(nemotron.settle(got, cfg),
+                                nemotron.settle(ref, cfg)):
+                    assert np.allclose(g[row], r[row], atol=1e-6)
             elif kind == "attention":
                 for g, r in zip(got, ref):
                     rows = P + 1 + int(t)
                     assert np.allclose(g[row, :rows], r[row, :rows],
                                        atol=1e-6)
+
+
+def _mamba_step_before(x, lp, state, window, cfg):
+    """`_mamba_step` as it was before the fold was deferred: the state
+    after EACH position stacked [B, S, H, Pd, N] and the conv buffer
+    [B, K - 1 + S, C], for `_commit_before` to choose from."""
+    mp = lp["mixer"]
+    bsz, s, _ = x.shape
+    f32 = jnp.float32
+    z, xbc, dt_raw = nemotron._mamba_in(
+        trinity.rms_norm(x, lp["norm"]["scale"], cfg.eps), mp, cfg)
+    buf = jnp.concatenate([window, xbc.astype(window.dtype)], axis=1)
+    xs, b, c, dt, a = nemotron._ssm_inputs(nemotron._conv(buf, mp, s, cfg),
+                                           dt_raw, mp, cfg)
+    xs, b, c = (v.astype(f32) for v in (xs, b, c))
+    st = state.reshape(bsz, *a.shape, cfg.mamba_head_dim, cfg.ssm_state)
+    states, ys = [], []
+    for i in range(s):
+        st = jnp.exp(dt[:, i] * a)[..., None, None] * st \
+            + (dt[:, i, ..., None] * xs[:, i])[..., None] \
+            * b[:, i, :, None, None, :]
+        ys.append((st * c[:, i, :, None, None, :]).sum(axis=-1))
+        states.append(st.reshape(state.shape))
+    y = jnp.stack(ys, axis=1) \
+        + mp["D"].astype(f32).reshape(a.shape)[..., None] * xs
+    x = x + nemotron._mamba_out(y.reshape(bsz, s, cfg.d_inner), z, mp, cfg)
+    return x, jnp.stack(states, axis=1), buf
+
+
+def _commit_before(caches, took, cfg):
+    keep = took.astype(jnp.int32)
+    k = cfg.conv_kernel
+    out = []
+    for kind, c in zip(cfg.layers, caches):
+        if kind == "mamba":
+            states, buf = c
+            out.append((
+                jax.vmap(lambda st, i: st[i])(states, keep),
+                jax.vmap(lambda bf, i: jax.lax.dynamic_slice_in_dim(
+                    bf, i + 1, k - 1))(buf, keep)))
+        else:
+            out.append(c)
+    return tuple(out)
+
+
+def _step_before(params, toks, caches, q, cfg):
+    x = nemotron._embed(params, toks, cfg)
+    new = []
+    for i, kind in enumerate(cfg.layers):
+        lp = params[f"layer_{i}"]
+        if kind == "mamba":
+            x, states, buf = _mamba_step_before(x, lp, *caches[i], cfg)
+            new.append((states, buf))
+        elif kind == "attention":
+            x, kv = nemotron._attn_step(x, lp, caches[i], q, cfg)
+            new.append(kv)
+        else:
+            x = nemotron._moe_rows(x, lp, cfg)[0]
+            new.append(())
+    return nemotron._logits(params, x, cfg), tuple(new)
+
+
+@pytest.mark.parametrize("form", ["op_by_op", "jitted"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_deferred_fold_is_the_stacked_candidates_commit(dtype, form):
+    """Four two-position steps from one prefill, three rows taking their
+    draft in a different pattern each step: `step` + `commit`, the
+    taken increment folded by the next step, against the step that
+    stacks both candidate states and the commit that picks one a row.
+    The fold is the same float32 expression on the same operands, so op
+    by op the logits, the state and conv window a row keeps and the K/V
+    rows are equal to the bit. Jitted whole, XLA's CPU backend contracts
+    a·b + c·d into one fused multiply-add inside a fusion, and which
+    product it contracts follows the fusion, which the two programs draw
+    differently: the float32 state then differs in its last bits (under
+    1e-6 of values of order 0.1) and float32 logits by under 1e-5 (of
+    order 1); bfloat16's logits round those bits away."""
+    cfg = NemotronHConfig.tiny(dtype=dtype)
+    params = _params(cfg, dtype=dtype)
+    ids = jax.random.randint(jax.random.PRNGKey(12), (3, P + 8), 0, 256)
+    _, (caches, _, _, _) = nemotron.prefill(params, ids[:, :P], P + 9, cfg)
+    before = tuple(nemotron.settle(c, cfg) if kind == "mamba" else c
+                   for kind, c in zip(cfg.layers, caches))
+
+    def old(p, t, c, q, took):
+        lg, c = _step_before(p, t, c, q, cfg)
+        return lg, _commit_before(c, took, cfg)
+
+    def new(p, t, c, q, took):
+        lg, _, c, _ = nemotron.step(p, t, c, q, cfg)
+        return lg, nemotron.commit(c, took, cfg)
+
+    def settle(c):
+        return nemotron.settle(c, cfg)
+
+    exact = form == "op_by_op"
+    if not exact:
+        old, new, settle = jax.jit(old), jax.jit(new), jax.jit(settle)
+
+    def same(got, want, atol):
+        if exact:
+            assert np.array_equal(got, want)
+        else:
+            assert np.allclose(got, want, rtol=0, atol=atol)
+
+    q = jnp.full((3,), P, jnp.int32)
+    pattern = [(True, False, True), (False, True, True),
+               (True, True, False), (False, False, True)]
+    with jax.disable_jit(exact):
+        for i, took in enumerate(map(jnp.asarray, pattern)):
+            toks = jax.random.randint(jax.random.PRNGKey(20 + i), (3, 2), 0,
+                                      256)
+            lg, caches = new(params, toks, caches, q, took)
+            lg_b, before = old(params, toks, before, q, took)
+            same(lg, lg_b, 1e-5 if dtype == "float32" else 0.0)
+            for kind, got, want in zip(cfg.layers, caches, before):
+                got = settle(got) if kind == "mamba" else got
+                for g, w in zip(got, want):
+                    same(g, w, 1e-6)
+            q = q + 1 + took.astype(jnp.int32)
 
 
 def test_bfloat16_stays_within_a_bound_that_a_float8_pass_does_not():
@@ -536,7 +659,10 @@ def test_published_widths_and_the_cells_parameter_count():
     assert count(shapes["layer_1"]) == 759_173_632
     assert count(shapes["layer_1"]["moe"]["experts"]) == 128 * 5_505_024
     assert count(shapes["mtp"]) == 828_396_032
-    assert cell.ssm_state_bytes() == 21_278_720
+    # a layer: the state 4,194,304 B, the pending increment (128 + 8,192
+    # + 1,024) × 4 B and took's byte, 4 conv inputs of 10,240 × 2 B
+    assert cell.ssm_state_bytes() == 5 * (4_194_304 + 37_377 + 81_920) \
+        == 21_568_005
     # the attention layer's and the module's K and V, 1 KB a position each
     assert cell.kv_bytes(2048 + 1024) == 2 * 3072 * 1024
     with pytest.raises(ValueError, match="experts_held"):
@@ -654,7 +780,7 @@ def test_two_runs_of_the_tasks_through_the_node_give_one_cid_each():
         a = bucket[0]["attrs"]
         assert (a["model"], a["prompt_bucket"], a["decode_bucket"],
                 a["batch"]) == ("nemotron_h", 32, T, 2)
-        assert a["ssm_state_bytes"] == cfg.ssm_state_bytes() == 2816
+        assert a["ssm_state_bytes"] == cfg.ssm_state_bytes() == 3490
         assert a["kv_bytes"] == cfg.kv_bytes(32 + T)
         state = sum(s["attrs"]["batch"] * s["attrs"]["ssm_state_bytes"]
                     for s in bucket)
